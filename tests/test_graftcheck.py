@@ -31,6 +31,34 @@ def _scan_src(path, src, rules):
 # -- rule fixtures: known violation → finding; clean shape → no finding ------
 
 
+def test_verdict_scripts_are_linted_for_swallows_and_asserts_only():
+    """bench.py / chip_smoke.py exit with a verdict: a swallowed exception or
+    a -O-stripped assert there reads as a pass. Those two rules reach into
+    the corpus for them; no other rule and no other corpus file is linted."""
+    src = (
+        "import threading\n"
+        "def main():\n"
+        "    threading.Thread(target=main).start()\n"  # thread-name: package code only
+        "    try:\n"
+        "        run()\n"
+        "    except Exception:\n"
+        "        pass\n"
+        "    assert ok(), 'phase failed'\n"
+        "    try:\n"
+        "        reap()\n"
+        "    except Exception:  # graftcheck: off=except-swallow\n"
+        "        pass\n"
+    )
+    tree = Tree({}, corpus={"chip_smoke.py": src, "__graft_entry__.py": src, "tests/t.py": src})
+    r = scan(tree, rules=["opt-assert", "except-swallow", "thread-name"])
+    assert sorted((f.path, f.rule, f.line) for f in r.findings) == [
+        ("chip_smoke.py", "except-swallow", 6),
+        ("chip_smoke.py", "opt-assert", 8),
+    ]
+    assert r.suppressed == 1
+    assert "chip_smoke.py" in build_tree(ROOT).scripts and "bench.py" in build_tree(ROOT).scripts
+
+
 def test_opt_assert_flags_load_bearing_and_allows_narrowing():
     bad = "def f(x):\n    assert x > 0, 'must be positive'\n    return x\n"
     r = _scan_src("tidb_tpu/kv/x.py", bad, ["opt-assert"])

@@ -257,6 +257,38 @@ def test_mpp_two_join_chain_full_q3(q3db):
     assert mpp == host and len(mpp) == 10
 
 
+def test_mpp_input_lanes_are_staged_sharded_over_the_mesh(q3db):
+    """Pooled (and whole-reader) input lanes land row-sharded on the mesh's
+    devices when they are staged — one shard per device, once — instead of
+    whole on device 0 for the shard_map program to re-scatter every call."""
+    from tidb_tpu.parallel import gather
+
+    with gather._MPP_CACHE_MU:
+        gather._MPP_DEV_CACHE.clear()
+    s = q3db.session()
+    q = "SELECT o_odate, COUNT(*) FROM lineitem JOIN orders ON l_orderkey = o_orderkey GROUP BY o_odate"
+    lines = "\n".join(r[0] for r in s.execute("EXPLAIN " + q).rows)
+    assert "PhysMPPGather" in lines
+    s.execute(q)
+    ndev = make_mesh().devices.size
+    lanes = []
+    for ent in gather._MPP_DEV_CACHE.values():
+        if isinstance(ent, dict):  # per-column pool of a plain reader
+            lanes.append(ent["live"])
+            lanes += [a for d, v, _b in ent["cols"].values() for a in (d, v)]
+        else:  # whole-reader entry: (lanes, n, bounds)
+            lanes += list(ent[0])
+    assert lanes
+    for a in lanes:
+        assert len(a.sharding.device_set) == ndev
+        shards = a.addressable_shards
+        assert len(shards) == ndev and {sh.data.shape[0] for sh in shards} == {a.shape[0] // ndev}
+    # the cache identity carries the mesh's device ids, so a gather on a
+    # different device set can never be handed lanes committed elsewhere
+    ids = tuple(int(d.id) for d in make_mesh().devices.flat)
+    assert all(ids in k for k in gather._MPP_DEV_CACHE)
+
+
 def test_mpp_non_unique_build_side(q3db):
     """Build side with duplicate keys → expansion join (each probe row fans
     out to its match count), not a host fallback."""

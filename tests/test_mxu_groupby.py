@@ -10,7 +10,19 @@ from tidb_tpu.copr import tpu_engine
 from tidb_tpu.executor.load import bulk_load
 from tidb_tpu.ops import dag_kernel
 from tidb_tpu.ops.mxu_groupby import grouped_sums_dot
-from tidb_tpu.ops.pallas_groupby import np_reference
+
+
+def np_reference(seg, pairs, B):
+    """NumPy oracle: exact grouped COUNT/SUM per (value, weight) lane."""
+    L = len(pairs)
+    counts = np.zeros((B, L), dtype=np.int64)
+    sums = np.zeros((B, L), dtype=np.int64)
+    for k, (vals, w) in enumerate(pairs):
+        for b in range(B):
+            m = (np.asarray(seg) == b) & np.asarray(w)
+            counts[b, k] = int(m.sum())
+            sums[b, k] = int(np.asarray(vals)[m].sum()) if m.any() else 0
+    return counts, sums
 
 
 def test_dot_exact_vs_oracle():
@@ -121,3 +133,53 @@ def test_fused_agg_single_dispatch(dotdb, monkeypatch):
     rows = s.query("SELECT k, COUNT(*) FROM b GROUP BY k ORDER BY k")
     assert len(rows) == 5
     assert calls and all(nb > 1 for nb, _ in calls), "agg did not fuse blocks"
+
+
+def test_mid_cardinality_group_by_sql_parity():
+    # 41*6=246 buckets: past the int8 dot's MAX_B, so the lex-sort path —
+    # the band a pallas kernel owned until it summed wrong on the chip
+    db = tidb_tpu.open()
+    db.execute("CREATE TABLE m (g1 VARCHAR(8), g2 VARCHAR(8), amt DECIMAL(10,2))")
+    rng = np.random.default_rng(3)
+    n = 6000
+    g1s = [f"k{i}".encode() for i in range(40)]
+    g2s = [f"v{i}".encode() for i in range(5)]
+    from tidb_tpu.executor.load import bulk_load
+
+    bulk_load(
+        db,
+        "m",
+        [
+            [g1s[int(i)] for i in rng.integers(0, 40, n)],
+            [None if rng.random() < 0.05 else g2s[int(i)] for i in rng.integers(0, 5, n)],
+            [None if rng.random() < 0.1 else int(rng.integers(0, 100000)) for _ in range(n)],
+        ],
+    )
+    db.execute("ANALYZE TABLE m")
+    s = db.session()
+    q = "SELECT g1, g2, COUNT(*), COUNT(amt), SUM(amt), AVG(amt) FROM m GROUP BY g1, g2 ORDER BY g1, g2"
+    s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    a = s.query(q)
+    s.execute("SET tidb_isolation_read_engines = 'host'")
+    b = s.query(q)
+    assert a == b and len(a) > 200
+
+
+def test_mxu_gate_falls_back_for_minmax():
+    # MIN/MAX have no matmul form: mid-cardinality group-by must still be
+    # correct (sort path)
+    db = tidb_tpu.open()
+    db.execute("CREATE TABLE m2 (g VARCHAR(8), v BIGINT)")
+    from tidb_tpu.executor.load import bulk_load
+
+    rng = np.random.default_rng(5)
+    gs = [f"g{i}".encode() for i in range(60)]
+    n = 3000
+    bulk_load(db, "m2", [[gs[int(i)] for i in rng.integers(0, 60, n)], rng.integers(-(10**12), 10**12, n)])
+    db.execute("ANALYZE TABLE m2")
+    s = db.session()
+    q = "SELECT g, MIN(v), MAX(v), COUNT(*) FROM m2 GROUP BY g ORDER BY g"
+    s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    a = s.query(q)
+    s.execute("SET tidb_isolation_read_engines = 'host'")
+    assert a == s.query(q) and len(a) == 60

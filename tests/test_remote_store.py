@@ -120,6 +120,34 @@ def test_point_get_and_dml_through_the_wire(remote):
     assert s.query("SELECT COUNT(*) FROM kvt") == [(3,)]
 
 
+def test_bulk_load_strings_over_the_wire(remote):
+    """Columnar ingest from the SQL-layer process: the codes it ships index
+    ITS dictionary, and the server must re-encode them into the store's own
+    table dictionary — it used to keep them, and every later read of the
+    column died decoding codes its (empty) dictionary never held."""
+    import numpy as np
+
+    from tidb_tpu.executor.load import bulk_load
+
+    _, db = remote
+    db.execute("CREATE TABLE ws (flag VARCHAR(1), tag VARCHAR(8), v BIGINT)")
+    n = 3000
+    flags = np.array([b"R", b"A", b"N"], dtype="S1")[np.arange(n) % 3]
+    tags = [None if i % 11 == 0 else b"t%d" % (i % 5) for i in range(n)]
+    bulk_load(db, "ws", [flags, tags, np.arange(n)])
+    # a second block whose values arrive in another order (other client codes)
+    bulk_load(db, "ws", [flags[::-1].copy(), tags[::-1], np.arange(n, 2 * n)])
+    s = db.session()
+    q = "SELECT flag, tag, COUNT(*), SUM(v) FROM ws GROUP BY flag, tag ORDER BY flag, tag"
+    s.execute("SET tidb_isolation_read_engines = 'host'")
+    host = s.query(q)
+    s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    assert s.query(q) == host
+    assert sorted({r[0] for r in host}) == ["A", "N", "R"]
+    assert sum(r[2] for r in host) == 2 * n
+    assert s.query("SELECT COUNT(*) FROM ws WHERE flag = 'A'") == [(2 * n // 3,)]
+
+
 MPPQ = (
     "SELECT d.grp, COUNT(*), SUM(li.price) FROM li JOIN d ON li.qty = d.id"
     " GROUP BY d.grp ORDER BY d.grp"

@@ -1181,13 +1181,14 @@ def main(argv=None):
     ap.add_argument("--fuzz-seed", type=int, default=42)
     ap.add_argument("--fuzz-out", default="fuzz_nightly")
     args = ap.parse_args(argv)
-    # scaling-curve lanes need a multi-device mesh: standalone CPU runs get
-    # the virtual 8-device host platform (must be set BEFORE the first lane
-    # initializes jax; inert when a real accelerator platform is preset —
-    # there the lanes use however many real chips exist)
+    # scaling-curve lanes need a multi-device mesh: a run that ASKS for the
+    # CPU platform gets the virtual 8-device host platform (must be set
+    # BEFORE the first lane initializes jax). An unset JAX_PLATFORMS means
+    # jax's own default — the chip where there is one — and is left alone:
+    # there the lanes use however many real chips exist
     import os as _os
 
-    if _os.environ.get("JAX_PLATFORMS", "cpu").startswith("cpu"):
+    if _os.environ.get("JAX_PLATFORMS", "").startswith("cpu"):
         flags = _os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             _os.environ["XLA_FLAGS"] = (

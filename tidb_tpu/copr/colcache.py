@@ -45,6 +45,16 @@ from tidb_tpu.utils.chunk import Dictionary
 DEVICE_BLOCK_ROWS = int(os.environ.get("TIDB_TPU_DEVICE_BLOCK_ROWS", str(1 << 22)))
 
 
+def hbm_budget() -> int:
+    """Bytes of HBM the device column LRU may hold — the ONE definition, kept
+    in this jax-free module because the planner's pressure signal and the
+    hbm-pressure inspection rule read it in processes that own no device.
+    The default is three quarters of a v5e's 16 GB, leaving the rest to
+    kernel temporaries and MPP lanes; chip_smoke.py fails when it exceeds
+    what the device reports (``memory_stats()["bytes_limit"]``)."""
+    return int(float(os.environ.get("TIDB_TPU_HBM_GB", "12")) * (1 << 30))
+
+
 def _delta_limits() -> tuple[int, int, int]:
     """(delta_cap, merge_rows, min_rows) from the effective config:
     ``delta_cap`` is the fixed kernel delta-operand capacity (a query-path

@@ -636,7 +636,6 @@ class StoreServer:
 
             from tidb_tpu.expression.expr import _ft_from_pb
             from tidb_tpu.kv.rowcodec import RowSchema
-            from tidb_tpu.utils.chunk import Dictionary
 
             n = h["n"]
             handles = _np.frombuffer(blobs[0], dtype=_np.int64).copy()
@@ -647,7 +646,7 @@ class StoreServer:
                 valid = _np.frombuffer(blobs[bi + 1], dtype=_np.bool_).copy()
                 cols[slot] = (data, valid)
                 bi += 2
-            dicts = {}
+            shipped = {}
             for slot in h["dict_slots"]:
                 buf = blobs[bi]
                 bi += 1
@@ -658,15 +657,33 @@ class StoreServer:
                     off += 4
                     vals.append(buf[off : off + ln])
                     off += ln
-                dicts[slot] = Dictionary(vals)
+                shipped[slot] = vals
             schema = RowSchema([_ft_from_pb(f) for f in h["schema"]])
-            ts = st.ingest_columnar(
-                h["table_id"], handles[:n], cols, schema, dicts,
-                on_existing=h.get("on_existing"),
-            )
+            # the shipped codes index the CLIENT's dictionary; a stable block
+            # must carry codes of THIS store's table dictionary (the one its
+            # column cache decodes and compacts with — see executor/load.py
+            # _ingest_columnar for the embedded twin), so re-encode under the
+            # same lock that orders ingest against dictionary compaction
+            from tidb_tpu.copr.colcache import cache_for
+
+            cache = cache_for(st)
+            dicts = {slot: cache.dictionary(h["table_id"], slot) for slot in shipped}
+            with cache.ingest_lock():
+                for slot, vals in shipped.items():
+                    data, valid = cols[slot]
+                    if len(vals):
+                        dic = dicts[slot]
+                        remap = _np.fromiter(
+                            (dic.encode(v) for v in vals), dtype=_np.int32, count=len(vals)
+                        )
+                        cols[slot] = (_np.where(valid, remap[data], _np.int32(0)), valid)
+                ts = st.ingest_columnar(
+                    h["table_id"], handles[:n], cols, schema, dicts,
+                    on_existing=h.get("on_existing"),
+                )
             return {"ts": ts}, []
         if cmd == "mpp_ndev":
-            return {"ndev": self._mpp_mgr().ndev()}, []
+            return self._mpp_mgr().devices(), []
         if cmd == "mpp_dispatch":
             # DispatchMPPTask analog (ref: kv/mpp.go:189): the gather spec
             # arrives as table ids + expression pbs; execution starts on a
@@ -1029,7 +1046,7 @@ class RemoteStore:
         # cop fan-out runs on the process-wide shared pool (copr/client.py):
         # its threads (and their pooled per-thread sockets) outlive both
         # individual queries and individual RemoteStore handles
-        self._mpp_ndev: Optional[int] = None
+        self._mpp_devices: Optional[dict] = None
         # fail fast on a bad endpoint: zero retry budget, so a dead/refused
         # address raises on the FIRST dial instead of looping out the full
         # boRPC budget (fleet assembly and liveness probes construct these)
@@ -1296,12 +1313,18 @@ class RemoteStore:
         return h["ts"]
 
     # -- MPP dispatch (ref: kv/mpp.go DispatchMPPTask/EstablishMPPConns) ----
+    def mpp_devices(self) -> dict:
+        """The server's device mesh as ITS jax reports it: ``ndev``,
+        ``platform``, ``device_kind`` (this process never touches jax)."""
+        if self._mpp_devices is None:
+            h = self._call({"cmd": "mpp_ndev"})[0]
+            self._mpp_devices = {k: h[k] for k in ("ndev", "platform", "device_kind")}
+        return self._mpp_devices
+
     def mpp_ndev(self) -> int:
         """Mesh size of the server's device mesh — the remote planner's
         exchange-cost model needs the REAL ndev, not this process's."""
-        if self._mpp_ndev is None:
-            self._mpp_ndev = int(self._call({"cmd": "mpp_ndev"})[0]["ndev"])
-        return self._mpp_ndev
+        return int(self.mpp_devices()["ndev"])
 
     def mpp_dispatch(self, spec: dict, read_ts: int, trace: Optional[dict] = None) -> str:
         hdr = {"cmd": "mpp_dispatch", "spec": spec, "read_ts": read_ts}

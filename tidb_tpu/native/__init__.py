@@ -5,13 +5,16 @@ Reference parity: the reference's storage/compute engines are native
 sit outside XLA — bulk row/key encoding and packed-row decoding — are C++
 behind a ctypes C ABI, compiled on first use with the toolchain's g++.
 
-Falls back to the pure-Python encoders transparently when no compiler is
-available (``lib()`` returns None); all callers must keep working either way.
+Callers keep working on the pure-Python encoders when the library is
+unavailable (``lib()`` returns None) — but never silently: a failed build or
+load leaves a WARN event (``native.unavailable``) with the compiler's words,
+because the Python encoders are a different program at bulk sizes.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,28 +25,49 @@ _tried = False
 
 _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "src", "rowcodec.cc")
 _OUT_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)), "_build")
-_OUT = os.path.join(_OUT_DIR, "libtidbtpu_native.so")
+_FLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
+
+
+def _artifact() -> str:
+    """The .so path for THIS source + these flags: keyed by content hash, so
+    a stale or foreign binary left in ``_build/`` (the directory is copied
+    with the tree but never committed) can never load in its place."""
+    h = hashlib.sha256(" ".join(_FLAGS).encode())
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_OUT_DIR, f"libtidbtpu_native-{h.hexdigest()[:16]}.so")
+
+
+def _unavailable(reason: str) -> None:
+    from tidb_tpu.utils import eventlog as _ev
+
+    lg = _ev.on(_ev.WARN)
+    if lg is not None:
+        lg.emit(_ev.WARN, "native", "unavailable", reason=reason)
 
 
 def _build() -> str | None:
+    out = _artifact()
+    if os.path.exists(out):
+        return out
     os.makedirs(_OUT_DIR, exist_ok=True)
-    # rebuild only when the source is newer than the cached .so
-    if os.path.exists(_OUT) and os.path.getmtime(_OUT) >= os.path.getmtime(_SRC):
-        return _OUT
     # per-process tmp name: concurrent builders each publish a complete .so
     # atomically instead of interleaving writes into one shared tmp file
-    tmp = f"{_OUT}.{os.getpid()}.tmp"
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
+    tmp = f"{out}.{os.getpid()}.tmp"
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _OUT)
-    except Exception:
+        subprocess.run(
+            ["g++", *_FLAGS, _SRC, "-o", tmp], check=True, capture_output=True, timeout=120
+        )
+        os.replace(tmp, out)
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.remove(tmp)
         except OSError:
             pass
+        stderr = getattr(e, "stderr", None) or b""
+        _unavailable(f"g++ build failed: {e!r} {stderr[-400:].decode(errors='replace')}")
         return None
-    return _OUT
+    return out
 
 
 def lib():
@@ -60,7 +84,8 @@ def lib():
             return None
         try:
             lb = ctypes.CDLL(path)
-        except OSError:
+        except OSError as e:
+            _unavailable(f"dlopen failed: {e!r}")
             return None
         lb.tpu_encode_rows_size.restype = ctypes.c_int64
         lb.tpu_encode_rows_size.argtypes = [

@@ -1,10 +1,8 @@
 """Grouped COUNT/SUM as one XLA int8 matmul on the MXU.
 
-The pallas kernel (ops/pallas_groupby.py) tiles a one-hot f32 matmul by hand;
-measured on v5e its per-block grid overhead dominates small-B aggregations
-(~8ms/4M-row block for B=8). This path instead hands XLA ONE
-``dot_general(onehot_i8, limbs_i8) -> int32`` per ≤8M-row chunk — the native
-int8 systolic-array mode — and recombines limbs exactly in int64:
+This path hands XLA ONE ``dot_general(onehot_i8, limbs_i8) -> int32`` per
+≤8M-row chunk — the native int8 systolic-array mode — and recombines limbs
+exactly in int64:
 
 - values bias to non-negative by their proven lower bound (binder bounds, or
   the int32 dtype envelope) and split into 8-bit limbs, each re-biased by

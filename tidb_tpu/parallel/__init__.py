@@ -18,20 +18,4 @@ data plane never leaves the ICI once shards are device-resident.
 
 from tidb_tpu.parallel.mesh import make_mesh
 
-
-def shard_map_compat(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map across jax versions: new releases expose it top-level
-    with ``check_vma``; 0.4.x only has jax.experimental.shard_map with the
-    equivalent ``check_rep`` knob."""
-    import jax
-
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
-        )
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma)
-
-
-__all__ = ["make_mesh", "shard_map_compat"]
+__all__ = ["make_mesh"]

@@ -58,7 +58,8 @@ _MPP_FN_CACHE: dict = {}
 import threading as _threading
 
 _MESH_EXEC_LOCK = _threading.Lock()
-# (store, table, slots, region versions, ndev) → padded device input lanes
+# (store, table, slots, region versions, mesh device ids) → padded device
+# input lanes, row-sharded over that mesh
 _MPP_DEV_CACHE: dict = {}
 # serializes MUTATIONS of the two module caches above/below: lookups stay
 # lock-free (GIL-atomic dict reads; a miss just rebuilds), but the eviction
@@ -845,9 +846,9 @@ def try_mpp_rewrite(plan: PhysicalPlan, vars: dict, stats=None, store=None, heal
     hbm_frac = 0.0
     if health is not None:
         try:
-            from tidb_tpu.copr.tpu_engine import _hbm_budget
+            from tidb_tpu.copr.colcache import hbm_budget
 
-            budget = float(_hbm_budget())
+            budget = float(hbm_budget())
             for ent in health.reports().values():
                 rep = ent.get("report") or {}
                 b = float(rep.get("device_cache_bytes") or 0)
@@ -864,16 +865,16 @@ def try_mpp_rewrite(plan: PhysicalPlan, vars: dict, stats=None, store=None, heal
     _ndev_memo: list = []
 
     def get_ndev() -> int:
+        # a mesh that cannot be built (or a store that cannot say its width)
+        # is an error the statement reports — planning for "1 device" would
+        # hide a broken backend behind a correct single-shard answer
         if not _ndev_memo:
-            try:
-                if store is not None and hasattr(store, "mpp_ndev"):
-                    _ndev_memo.append(int(store.mpp_ndev()))
-                else:
-                    from tidb_tpu.parallel import make_mesh
+            if store is not None and hasattr(store, "mpp_ndev"):
+                _ndev_memo.append(int(store.mpp_ndev()))
+            else:
+                from tidb_tpu.parallel import make_mesh
 
-                    _ndev_memo.append(make_mesh().devices.size)
-            except Exception:
-                _ndev_memo.append(1)
+                _ndev_memo.append(make_mesh().devices.size)
         return _ndev_memo[0]
 
     def _try_agg_below_join(p: PhysFinalAgg, readers: list, joins: list):
@@ -1658,7 +1659,9 @@ class MPPGatherExec:
         return chunk
 
     def _execute_attempt(self, mesh):
+        import jax
         import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec
 
         from tidb_tpu.parallel.mpp import (
             DistAggSpec,
@@ -1669,6 +1672,17 @@ class MPPGatherExec:
 
         p = self.plan
         ndev = mesh.devices.size
+        # input lanes are STAGED row-sharded over the mesh (the program's
+        # in_specs are all P("dp")): each device receives only its shard, once,
+        # instead of everything landing on device 0 and the shard_map program
+        # re-scattering it on every call. Cached lanes are committed to these
+        # devices, so the cache identity is the mesh's device ids, not its width
+        mesh_ids = tuple(int(d.id) for d in mesh.devices.flat)
+        lane_sharding = NamedSharding(mesh, PartitionSpec("dp"))
+
+        def put(a):
+            return jax.device_put(a, lane_sharding)
+
         self._stage_bytes = []  # per-device-stage exchanged bytes (psum)
         self._n_stages = 1 + sum(
             1 for r in p.readers if isinstance(r, SubplanReader) and r.staged
@@ -1770,7 +1784,7 @@ class MPPGatherExec:
                         base.table.id,
                         reader.fingerprint(),
                         vers,
-                        ndev,
+                        mesh_ids,
                         _cache.epoch,
                     )
                 elif reader.pushed_agg is not None:
@@ -1788,7 +1802,7 @@ class MPPGatherExec:
                         reader.table.id,
                         tuple(reader.scan_slots),
                         vers,
-                        ndev,
+                        mesh_ids,
                         agg_fp,
                         _cache.epoch,  # dictionary merges/compactions remap codes
                     )
@@ -1797,7 +1811,7 @@ class MPPGatherExec:
                         self.session.store.nonce,
                         base.table.id,
                         vers,
-                        ndev,
+                        mesh_ids,
                         _cache.epoch,
                     )
             if key is not None:
@@ -1817,7 +1831,7 @@ class MPPGatherExec:
             arrays, n, bounds = pad_side(self._reader_arrays(reader))
             if ckey is not None:
                 if pool is None:
-                    pool = {"n": n, "live": jnp.asarray(arrays[-1]), "cols": {}}
+                    pool = {"n": n, "live": put(arrays[-1]), "cols": {}}
                     with _MPP_CACHE_MU:
                         # a racing gather may have installed the pool first:
                         # adopt the winner so both share one resident copy
@@ -1828,12 +1842,12 @@ class MPPGatherExec:
                     if ent is None:
                         # upload ONLY the columns the pool lacks — the
                         # overlap with earlier queries stays resident
-                        ent = (jnp.asarray(arrays[2 * i]), jnp.asarray(arrays[2 * i + 1]), bounds[i])
+                        ent = (put(arrays[2 * i]), put(arrays[2 * i + 1]), bounds[i])
                         pool["cols"][s] = ent
                     lanes += [ent[0], ent[1]]
                 dev = (lanes + [pool["live"]], pool["n"], [pool["cols"][s][2] for s in want])
             else:
-                dev = ([jnp.asarray(a) for a in arrays], n, bounds)
+                dev = ([put(a) for a in arrays], n, bounds)
             with _MPP_CACHE_MU:
                 if key is not None:
                     _MPP_DEV_CACHE[key] = dev
@@ -2347,8 +2361,6 @@ class MPPGatherExec:
             else:
                 _met.MPP_PROGRAM_CACHE.inc(result="hit")
                 fn, warn_sink = cached
-            import jax
-
             with self.session.span(f"mpp-pipeline[{ndev}dev]"), _MESH_EXEC_LOCK:
                 import time as _t
 

@@ -979,9 +979,7 @@ def build_dist_pipeline(
         extra += (P(None),)  # per-stage exchange-bytes vector
     if warn_sink is not None:
         extra += (P(),)
-    from tidb_tpu.parallel import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         step,
         mesh=mesh,
         in_specs=tuple(P("dp") for _ in range(sum(n_lanes))),

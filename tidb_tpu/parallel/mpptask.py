@@ -288,10 +288,18 @@ class MPPTaskManager:
                 self._db = DB(store=self.store)
             return self._db
 
-    def ndev(self) -> int:
+    def devices(self) -> dict:
+        """The mesh this server owns, as jax reports it: width for the remote
+        planner's exchange-cost model, platform and kind so a client can tell
+        WHICH device answers it (chip_smoke.py refuses anything but a TPU)."""
         from tidb_tpu.parallel import make_mesh
 
-        return int(make_mesh().devices.size)
+        devs = make_mesh().devices.flat
+        return {
+            "ndev": len(devs),
+            "platform": str(devs[0].platform),
+            "device_kind": str(devs[0].device_kind),
+        }
 
     # -- catalog resolution -------------------------------------------------
     def _refresh_tables(self) -> None:

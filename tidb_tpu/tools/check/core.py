@@ -108,6 +108,9 @@ class SourceFile:
         return False
 
 
+VERDICT_SCRIPTS = ("bench.py", "chip_smoke.py")
+
+
 class Tree:
     """The scan unit: repo-relative path → SourceFile. ``targets`` are the
     linted files; ``corpus`` adds reference-only sources (tests, entry
@@ -119,9 +122,20 @@ class Tree:
             p: SourceFile(p, s) for p, s in sorted(files.items())
         }
         self.corpus: dict[str, str] = dict(corpus or {})
+        # the corpus scripts whose EXIT CODE is a verdict: a swallowed
+        # exception or a -O-stripped assert there turns a failed run into
+        # exit 0, so except-swallow and opt-assert lint them too — and only
+        # those rules; they are not package code
+        self.scripts: dict[str, SourceFile] = {
+            p: SourceFile(p, self.corpus[p]) for p in VERDICT_SCRIPTS if p in self.corpus
+        }
 
     def targets(self) -> Iterable[SourceFile]:
         return self.files.values()
+
+    def source(self, path: str) -> Optional[SourceFile]:
+        """The parsed file a finding points into (target or verdict script)."""
+        return self.files.get(path) or self.scripts.get(path)
 
     def get(self, suffix: str) -> Optional[SourceFile]:
         """First target whose path ends with ``suffix`` (rule anchors like
@@ -213,7 +227,7 @@ def build_tree(root: Optional[str] = None) -> Tree:
                     p = os.path.join(base, n)
                     rel = os.path.relpath(p, root).replace(os.sep, "/")
                     corpus[rel] = _read(p)
-    for extra in ("bench.py", "__graft_entry__.py"):
+    for extra in ("bench.py", "chip_smoke.py", "__graft_entry__.py"):
         p = os.path.join(root, extra)
         if os.path.isfile(p):
             corpus[extra] = _read(p)
@@ -233,7 +247,7 @@ class Report:
         def rows(fs):
             out = []
             for f in fs:
-                sf = tree.files.get(f.path)
+                sf = tree.source(f.path)
                 out.append(f.to_pb(sf.line_text(f.line) if sf else ""))
             return out
 
@@ -266,7 +280,7 @@ def scan(
     rep = Report()
     for rid in ids:
         for f in all_rules[rid].check(tree):
-            sf = tree.files.get(f.path)
+            sf = tree.source(f.path)
             text = sf.line_text(f.line) if sf else ""
             if sf is not None and sf.suppressed(f.line, f.rule):
                 rep.suppressed += 1

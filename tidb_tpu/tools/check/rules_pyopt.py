@@ -58,7 +58,7 @@ with the same message; tests/ are exempt (pytest runs them unoptimized).
 )
 def check(tree: Tree) -> list:
     out: list[Finding] = []
-    for sf in tree.targets():
+    for sf in (*tree.targets(), *tree.scripts.values()):
         for node in ast.walk(sf.tree):
             if isinstance(node, ast.Assert) and not _is_narrowing(node.test):
                 out.append(

@@ -191,7 +191,7 @@ best-effort cleanup/advisory path — keep the swallow with an inline
 )
 def check_swallow(tree: Tree) -> list:
     out: list[Finding] = []
-    for sf in tree.targets():
+    for sf in (*tree.targets(), *tree.scripts.values()):
         for node in ast.walk(sf.tree):
             if not isinstance(node, ast.ExceptHandler):
                 continue

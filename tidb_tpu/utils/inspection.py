@@ -23,7 +23,6 @@ own snapshot reads.
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -67,6 +66,7 @@ class InspectionContext:
     @classmethod
     def from_db(cls, db) -> "InspectionContext":
         from tidb_tpu import config as _config
+        from tidb_tpu.copr.colcache import hbm_budget
         from tidb_tpu.utils import metrics as _m
         from tidb_tpu.utils.metricshist import recorder
 
@@ -74,9 +74,7 @@ class InspectionContext:
         ctx = cls(
             skew_ratio=cfg.balancer_skew_ratio,
             delta_merge_rows=cfg.device_delta_merge_rows,
-            hbm_budget=int(
-                float(os.environ.get("TIDB_TPU_HBM_GB", "12")) * (1 << 30)
-            ),
+            hbm_budget=hbm_budget(),
             mpp_shards=_m.MPP_SHARD_SECONDS.snapshot(),
             backoff_rate=recorder().rate("tidb_tpu_backoff_total"),
             delta_rows=float(_m.DEVICE_DELTA_ROWS.get()),
