@@ -1,0 +1,8 @@
+"""Median over all `q6` statements of the window, send to last row."""
+from end_to_end._latency import percentile_ms
+
+UNIT = "ms"
+
+
+def read(ctx):
+    return percentile_ms(ctx.statements, 50, "q6")
