@@ -15,7 +15,6 @@ so a single request cannot monopolize the shared workers.
 
 from __future__ import annotations
 
-import contextlib
 import os
 import threading
 import time
@@ -430,22 +429,24 @@ class CopClient:
 
         from tidb_tpu.utils.memory import QueryKilledError, QueryOOMError
 
-        # sidecar timing baseline + cross-thread span parent, captured in
-        # the requesting thread (queue wait = submit → worker pickup)
+        # sidecar timing baseline, cross-thread span parent and the
+        # statement's id, captured in the requesting thread (queue wait =
+        # submit → worker pickup)
         t_submit = time.perf_counter()
         tracer = _tracing.effective(req.tracer)
         parent_span = tracer.current() if tracer is not None else None
+        stmt = _tracing.current_stmt()
 
         def run(task: CopTask) -> CopResult:
-            det = _ed.CopExecDetails(task.region.region_id)
-            det.queue_ms = (time.perf_counter() - t_submit) * 1000.0
-            span = (
-                tracer.span(f"cop.r{task.region.region_id}", parent=parent_span)
-                if tracer is not None
-                else contextlib.nullcontext()
-            )
+            rid = task.region.region_id
+            det = _ed.CopExecDetails(rid)
             t0 = time.perf_counter()
-            with span, _ed.collecting(det, tracer=tracer):
+            det.queue_ms = (t0 - t_submit) * 1000.0
+            with _ed.collecting(det, tracer=tracer, stmt=stmt), _tracing.region(
+                "cop.task", parent=parent_span, label=f"cop.r{rid}" if tracer is not None else None,
+                region=rid, queue_us=int(det.queue_ms * 1000.0),
+            ) as span:
+                cpu0 = time.thread_time() if span is not None else 0.0
                 chunk = run_task_resilient(
                     bo,
                     run_engine,
@@ -464,6 +465,11 @@ class CopClient:
                     detail=det,
                     trace_id=tracer.trace_id if tracer is not None else None,
                 )
+                if span is not None:
+                    span.note(
+                        cpu_us=int((time.thread_time() - cpu0) * 1e6), h2d=det.h2d_bytes, d2h=det.d2h_bytes,
+                        engine=det.engine,
+                    )
             # processing = task wall minus its own backoff sleeps
             det.proc_ms = max((time.perf_counter() - t0) * 1000.0 - det.backoff_ms, 0.0)
             ring = getattr(self.store, "cop_ring", None)

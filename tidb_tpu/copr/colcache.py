@@ -36,6 +36,7 @@ from tidb_tpu.utils import eventlog as _ev
 from tidb_tpu.utils import execdetails as _ed
 from tidb_tpu.utils import failpoint
 from tidb_tpu.utils import metrics as _metrics
+from tidb_tpu.utils import tracing as _tracing
 from tidb_tpu.utils.chunk import Dictionary
 
 # device block granularity of the merge's dirty-block accounting; MUST match
@@ -192,7 +193,7 @@ class ColumnCache:
         # weak: the cache registry keys off the store; a strong ref here
         # would keep the store alive through the WeakKeyDictionary value
         self._store_ref = __import__("weakref").ref(store)
-        self._mu = threading.Lock()
+        self._mu = _tracing.TracedLock("colcache", threading.Lock())
         self._entries: dict[tuple[int, int], RegionColumns] = {}
         # pending delta overlays + host-materialized base⊕delta views,
         # keyed like entries; both validate against (data_version, built_ts)

@@ -600,6 +600,7 @@ def run_operators(chunk: Chunk, executors: list, output_offsets: list[int], warn
 
 def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn=None) -> Chunk:
     from tidb_tpu.utils import execdetails as _ed
+    from tidb_tpu.utils import tracing as _tracing
 
     det = _ed.current_cop()
     if det is None:
@@ -608,7 +609,7 @@ def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: 
 
     t0 = _t.perf_counter()
     try:
-        with _ed.trace_span("host-exec"):
+        with _tracing.region("host-exec"):
             return _execute_dag(store, dag, region, ranges, read_ts, warn)
     finally:
         # host-engine attribution into the task's ExecDetails sidecar — runs

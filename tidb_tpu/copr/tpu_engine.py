@@ -49,6 +49,7 @@ from tidb_tpu.types import FieldType, TypeKind
 from tidb_tpu.types.field_type import bigint_type
 from tidb_tpu.utils import execdetails as _ed
 from tidb_tpu.utils import metrics as _metrics
+from tidb_tpu.utils import tracing as _tracing
 from tidb_tpu.utils.chunk import Chunk, Column, bucket_size
 
 from tidb_tpu.ops.dag_kernel import _ensure_x64
@@ -111,7 +112,7 @@ class _DeviceLRU:
 
     def __init__(self, budget_bytes: int):
         self.budget = budget_bytes
-        self._mu = threading.Lock()
+        self._mu = _tracing.TracedLock("device_lru", threading.Lock())
         self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()  # key → (pair, nbytes)
         self.total = 0
 
@@ -158,7 +159,7 @@ _DEVICE_LRU = _DeviceLRU(hbm_budget())
 # per task that dominate the fixed cost of cheap queries like COUNT(*). Both
 # are tiny and low-cardinality, so they cache device-resident keyed by value
 # (ranges by their byte image).
-_MISC_MU = threading.Lock()
+_MISC_MU = _tracing.TracedLock("device_misc", threading.Lock())
 _RANGES_DEV: "OrderedDict[bytes, object]" = OrderedDict()
 _NVALID_DEV: "OrderedDict[object, object]" = OrderedDict()
 _MISC_CAP = 512
@@ -421,6 +422,50 @@ def _emit_kernel_warnings(buf, kernel, warn) -> None:
             warn("Warning", code, msg)
 
 
+class _Phases:
+    """One cop task's walk through the device path (``_ed.PHASES``). ``to``
+    ends the phase before and begins the named one, so the phases tile the
+    task and what runs between two of them belongs to the first. Their walls
+    always add to the task's sidecar (EXPLAIN ANALYZE ``phases:``); each is
+    also a span ``exec.<phase>`` when the seam records, which is asked once a
+    task. A phase may come more than once (bind: before the inputs, and at
+    the kernel lookup)."""
+
+    __slots__ = ("_det", "_live", "_attr", "_t0", "_span")
+    _ATTR = {p: p + "_ms" for p in _ed.PHASES}
+
+    def __init__(self):
+        self._det = _ed.current_cop()
+        self._live = _tracing.live()
+        self._attr = self._span = None
+
+    def to(self, phase: str, **meta) -> None:
+        now = _time.perf_counter()
+        if self._attr is not None:
+            self._close(now)
+        self._attr, self._t0 = self._ATTR[phase], now
+        if self._live:
+            self._span = _tracing.region("exec." + phase, **meta).__enter__()
+
+    def note(self, **meta) -> None:
+        """Add to the open phase's span what is only known inside it."""
+        if self._span is not None:
+            self._span.note(**meta)
+
+    def end(self) -> None:
+        if self._attr is not None:
+            self._close(_time.perf_counter())
+            self._attr = None
+
+    def _close(self, now: float) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        det = self._det
+        if det is not None:
+            setattr(det, self._attr, getattr(det, self._attr) + (now - self._t0) * 1000.0)
+
+
 def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn=None):
     det = _ed.current_cop()
     if det is None:
@@ -435,13 +480,14 @@ def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: 
     h0 = det.host_ms
     try:
         try:
-            with _ed.trace_span("device-exec"):
+            with _tracing.region("device-exec"):
                 return _execute_dag_device(store, dag, region, ranges, read_ts, warn)
         except UnsupportedForDevice:
             det.degraded = det.degraded or "unsupported-for-device"
             return host_execute_dag(store, dag, region, ranges, read_ts, warn)
     finally:
-        # device-time attribution: wall of the device path, unless the task
+        # device_ms is the HOST wall of the device path (the chip's own share
+        # is at most the sidecar's fetch_ms), unless the task
         # (or a shape fallback inside _execute_dag_device) ran on the host
         # engine — which attributed itself and claimed the engine label
         host_delta = det.host_ms - h0
@@ -453,6 +499,14 @@ def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: 
 
 
 def _execute_dag_device(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn=None):
+    ph = _Phases()
+    try:
+        return _device_path(ph, store, dag, region, ranges, read_ts, warn)
+    finally:
+        ph.end()
+
+
+def _device_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn):
     scan = dag.executors[0]
     if scan.desc:
         # descending scans are order-sensitive row streams — the sorted-batch
@@ -464,6 +518,7 @@ def _execute_dag_device(store: MemStore, dag: dagpb.DAGRequest, region: Region, 
         # the host engine slices exactly the requested handles from the same
         # column cache — the TiKV-serves-point-reads role
         return host_execute_dag(store, dag, region, ranges, read_ts, warn)
+    ph.to("bind")
     schema = RowSchema(scan.storage_schema)
     slots = [c.column_id for c in scan.columns if not c.is_handle]
     cache = cache_for(store)
@@ -485,6 +540,7 @@ def _execute_dag_device(store: MemStore, dag: dagpb.DAGRequest, region: Region, 
         det = _ed.current_cop()
         if det is not None:
             det.delta_rows += delta.n
+        ph.note(delta_rows=delta.n)
 
     binder_entry = entry if delta is None else _BinderView(entry, delta)
     binder = Binder(cache, scan.table_id, scan.columns, binder_entry)
@@ -500,20 +556,20 @@ def _execute_dag_device(store: MemStore, dag: dagpb.DAGRequest, region: Region, 
     if has_window and entry.n > _BLOCK:
         # windows need every row of a partition in one computation — blocks
         # cannot run independently; fuse them into one multi-block program
-        return _exec_fused_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn)
+        return _exec_fused_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn)
     if _should_fuse_agg(dag, entry):
         # aggregations over big tables fuse every block into ONE kernel
         # dispatch: the per-dispatch cost (~0.6 ms dispatch+sync on a v5e,
         # chip_smoke.py) would otherwise multiply by the block count, and a
         # single program needs no partial-merge pass over block results
-        return _exec_fused_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
+        return _exec_fused_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
     agg_complete = any(
         ex.tp in (dagpb.AGGREGATION, dagpb.STREAM_AGG) and ex.agg_mode == dagpb.AGG_COMPLETE
         for ex in dag.executors[1:]
     )
     if entry.n > _BLOCK and not agg_complete:
-        return _exec_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
-    return _exec_single(store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
+        return _exec_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
+    return _exec_single(ph, store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
 
 
 def _grown_cap(agg_cap: int, ngroups: int, ceiling: int) -> int:
@@ -551,13 +607,11 @@ def _single_device_inputs(store, scan, cache, entry, region, n_pad):
     return handles_pair[0], cols_dev
 
 
-def _exec_single(store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None) -> Chunk:
+def _exec_single(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None) -> Chunk:
     """Small regions (≤ one block) or COMPLETE-mode aggs: one padded array,
     one kernel invocation — the round-1 path, preserved verbatim."""
-    import jax
-    import jax.numpy as jnp
-
     n_pad = bucket_size(max(entry.n, 1))
+    ph.to("inputs")
     handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad)
     dcap = 0
     dargs = ()
@@ -565,47 +619,60 @@ def _exec_single(store, dag, bound, scan, cache, entry, region, rarr, warn=None,
         dcap = _delta_cap()
         dh, dcols, dtomb = _delta_device_inputs(store, scan, cache, delta, region)
         dargs = (dh, dcols, dtomb, _delta_counts(delta.n, 0, delta.n))
+    args = (handles_dev, tuple(cols_dev), _device_ranges(rarr), _device_nvalid(entry.n), *dargs)
 
+    ph.to("bind")
     agg_cap = min(_DEFAULT_AGG_CAP, n_pad + dcap) if kernel_needs_agg(bound) else _DEFAULT_AGG_CAP
     fs = _covers_all(rarr, entry, delta)
     while True:
         kernel = get_kernel(bound, n_pad, agg_cap, full_scan=fs, delta_cap=dcap)
-        packed = kernel.fn(handles_dev, tuple(cols_dev), _device_ranges(rarr), _device_nvalid(entry.n), *dargs)
-        # ONE device→host round trip per task: device_get batches every
-        # buffer of the packed result into a single transfer — two
-        # sequential np.asarray calls would pay the round trip twice.
-        # Exception: large rows-kind buffers spend a second tiny RTT on the
-        # meta row and transfer only the live slice (_probe_slice_rows).
-        fbuf = None
-        if kernel.kind == "rows" and kernel.out_n > 65536:
-            _, (packed,) = _probe_slice_rows([packed], kernel)
-        if isinstance(packed, tuple):
-            buf, fbuf = jax.device_get(packed)
-        else:
-            buf = jax.device_get(packed)
-        count = int(buf[0, 0])
-        ngroups = int(buf[0, 1])
-        if ngroups > kernel.agg_cap:
-            if agg_cap >= n_pad + dcap:
-                # more groups than rows cannot happen; n_pad cap always fits
-                raise RuntimeError("aggregation group overflow beyond row count")
-            agg_cap = _grown_cap(agg_cap, ngroups, n_pad + dcap)
-            continue
-        break
+        buf, fbuf, count = _run_one(ph, kernel, args)
+        if int(buf[0, 1]) <= kernel.agg_cap:
+            break
+        if agg_cap >= n_pad + dcap:
+            # more groups than rows cannot happen; n_pad cap always fits
+            raise RuntimeError("aggregation group overflow beyond row count")
+        agg_cap = _grown_cap(agg_cap, int(buf[0, 1]), n_pad + dcap)
+        ph.to("bind")
+    return _decode_one(ph, buf, fbuf, count, kernel, dag, cache, scan, warn)
+
+
+def _run_one(ph, kernel, args):
+    """Dispatch ``kernel`` once and fetch its result: (buf, fbuf, count) on
+    the host. ONE device→host round trip per task: device_get batches every
+    buffer of the packed result into a single transfer — two sequential
+    np.asarray calls would pay the round trip twice. Exception: large
+    rows-kind buffers spend a second tiny RTT on the meta row and transfer
+    only the live slice (_probe_slice_rows)."""
+    import jax
+
+    ph.to("dispatch", kernel=kernel.family)
+    packed = kernel.fn(*args)
+    ph.to("fetch")
+    if kernel.kind == "rows" and kernel.out_n > 65536:
+        _, (packed,) = _probe_slice_rows([packed], kernel)
+    buf, fbuf = jax.device_get(packed) if isinstance(packed, tuple) else (jax.device_get(packed), None)
+    # the device result ends HERE, inside the phase that waited for it, and
+    # under a span of its own: dropping it lets go of the interpreter lock, and
+    # a task pays to get that back (at the return it was nobody's time)
+    with _tracing.region("exec.release"):
+        del packed
+    return buf, fbuf, int(buf[0, 0])
+
+
+def _decode_one(ph, buf, fbuf, count, kernel, dag, cache, scan, warn) -> Chunk:
+    ph.to("decode")
     _emit_kernel_warnings(buf, kernel, warn)
     return _chunk_from_bufs(buf, fbuf, count, kernel, dag, cache, scan)
 
 
-def _exec_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None):
+def _exec_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None):
     """Large regions: fixed-shape device blocks, one compile per DAG.
 
     Aggs/TopN dispatch every block asynchronously and stack the packed
     buffers on-device → one transfer; LIMIT-last DAGs stream blocks lazily
     with early exit (coprocessor paging).
     """
-    import jax
-    import jax.numpy as jnp
-
     n = entry.n
     bounds = _block_bounds(n)
     cacheable = entry.complete
@@ -617,6 +684,7 @@ def _exec_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn=None,
         lo, hi = bounds[bi]
         return _block_device_inputs(store, scan, cache, entry, region, bi, lo, hi, cacheable)
 
+    ph.to("inputs")
     rarr_j = _device_ranges(rarr)
     nvalids = [hi - lo for lo, hi in bounds]
     limit_last = bool(dag.executors[1:]) and dag.executors[-1].tp == dagpb.LIMIT
@@ -639,30 +707,32 @@ def _exec_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn=None,
     agg_cap = _DEFAULT_AGG_CAP
     fs = _covers_all(rarr, entry, delta)
     while True:
+        ph.to("bind")
         kernel = get_kernel(bound, _BLOCK, agg_cap, full_scan=fs, delta_cap=dcap)
 
         def run_block(bi: int):
+            ph.to("inputs")
             handles_dev, cols_dev = block_inputs(bi)
-            if dinp is None:
-                return kernel.fn(handles_dev, cols_dev, rarr_j, _device_nvalid(nvalids[bi]))
-            # every block masks superseded base rows; each delta row
-            # unions into exactly the block owning its handle span, so rows
-            # never double-count and block outputs concat in handle order
-            dh, dcols, dtomb = dinp
-            dn = _delta_counts(delta.n, dcuts[bi], dcuts[bi + 1])
-            return kernel.fn(handles_dev, cols_dev, rarr_j, _device_nvalid(nvalids[bi]), dh, dcols, dtomb, dn)
+            args = (handles_dev, cols_dev, rarr_j, _device_nvalid(nvalids[bi]))
+            if dinp is not None:
+                # every block masks superseded base rows; each delta row
+                # unions into exactly the block owning its handle span, so rows
+                # never double-count and block outputs concat in handle order
+                args += (*dinp, _delta_counts(delta.n, dcuts[bi], dcuts[bi + 1]))
+            ph.to("dispatch", kernel=kernel.family)
+            return kernel.fn(*args)
 
         if limit_last:
-            out = _blocks_paged_limit(run_block, len(bounds), kernel, dag, cache, scan, warn)
+            out = _blocks_paged_limit(ph, run_block, len(bounds), kernel, dag, cache, scan, warn)
         else:
-            out = _blocks_stacked(run_block, len(bounds), kernel, dag, cache, scan, warn)
+            out = _blocks_stacked(ph, run_block, len(bounds), kernel, dag, cache, scan, warn)
         if out is None:  # agg overflow in some block
             agg_cap = min(agg_cap * 4, _BLOCK + dcap)
             continue
         return out
 
 
-def _blocks_stacked(run_block, nb: int, kernel, dag, cache, scan, warn=None):
+def _blocks_stacked(ph, run_block, nb: int, kernel, dag, cache, scan, warn=None):
     """Dispatch all blocks async; stack results on-device; one transfer.
     Returns None on agg-cap overflow (caller re-runs with a bigger cap)."""
     import jax
@@ -670,26 +740,32 @@ def _blocks_stacked(run_block, nb: int, kernel, dag, cache, scan, warn=None):
 
     packed = [run_block(bi) for bi in range(nb)]  # async dispatches
     tup = isinstance(packed[0], tuple)
+    ph.to("fetch")
     if kernel.kind == "rows" and kernel.out_n > 65536:
         # rows-kind: counts first (one tiny transfer), then live slices only
         counts, gets = _probe_slice_rows(packed, kernel)
         fetched = jax.device_get(gets)
+        with _tracing.region("exec.release"):
+            del packed, gets
+        ph.to("decode")
         chunks = []
         for cnt, got in zip(counts, fetched):
             buf, fbuf = got if tup else (got, None)
             _emit_kernel_warnings(buf, kernel, warn)
             chunks.append(_chunk_from_bufs(buf, fbuf, cnt, kernel, dag, cache, scan))
         return _concat_chunks(chunks)
-    ibufs = [p[0] if tup else p for p in packed]
-    si = jnp.stack(ibufs)
+    stacked = jnp.stack([p[0] if tup else p for p in packed])
     if tup:
-        sf = jnp.stack([p[1] for p in packed])
-        bi_all, bf_all = jax.device_get((si, sf))
+        stacked = (stacked, jnp.stack([p[1] for p in packed]))
+        bi_all, bf_all = jax.device_get(stacked)
     else:
-        bi_all = jax.device_get(si)
+        bi_all = jax.device_get(stacked)
         bf_all = None
+    with _tracing.region("exec.release"):
+        del packed, stacked
     if kernel.kind == "agg" and any(int(b[0, 1]) > kernel.agg_cap for b in bi_all):
         return None
+    ph.to("decode")
     chunks = []
     for b in range(nb):
         buf = bi_all[b]
@@ -699,7 +775,7 @@ def _blocks_stacked(run_block, nb: int, kernel, dag, cache, scan, warn=None):
     return _concat_chunks(chunks)
 
 
-def _exec_fused_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None):
+def _exec_fused_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None):
     """Whole-region DAGs (windows, aggregations) over large regions: ONE
     fused multi-block program, one dispatch.
 
@@ -710,9 +786,7 @@ def _exec_fused_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn
     LRU identities as _exec_blocks — warm tables pay no new H2D transfer).
     For windows the binder's sort bounds make the region sort a single int64
     argsort; unpackable shapes raised UnsupportedForDevice upstream."""
-    import jax
-    import jax.numpy as jnp
-
+    ph.to("inputs")
     handles_blocks, cols_blocks, nvalids, nb = _fused_block_inputs(store, scan, cache, entry, region)
     n_total = nb * _BLOCK
     dcap = 0
@@ -721,37 +795,24 @@ def _exec_fused_blocks(store, dag, bound, scan, cache, entry, region, rarr, warn
         dcap = _delta_cap()
         dh, dcols, dtomb = _delta_device_inputs(store, scan, cache, delta, region)
         dargs = (dh, dcols, dtomb, _delta_counts(delta.n, 0, delta.n))
+    args = (tuple(handles_blocks), tuple(tuple(cb) for cb in cols_blocks), _device_ranges(rarr), nvalids, *dargs)
+
+    ph.to("bind")
     agg_cap = min(_DEFAULT_AGG_CAP, n_total + dcap) if kernel_needs_agg(bound) else _DEFAULT_AGG_CAP
     fs = _covers_all(rarr, entry, delta)
     while True:
         kernel = get_kernel(bound, _BLOCK, agg_cap, nb=nb, full_scan=fs, delta_cap=dcap)
-        packed = kernel.fn(
-            tuple(handles_blocks),
-            tuple(tuple(cb) for cb in cols_blocks),
-            _device_ranges(rarr),
-            nvalids,
-            *dargs,
-        )
-        fbuf = None
-        if kernel.kind == "rows" and kernel.out_n > 65536:
-            _, (packed,) = _probe_slice_rows([packed], kernel)
-        if isinstance(packed, tuple):
-            buf, fbuf = jax.device_get(packed)
-        else:
-            buf = jax.device_get(packed)
-        count = int(buf[0, 0])
-        ngroups = int(buf[0, 1])
-        if ngroups > kernel.agg_cap:
-            if agg_cap >= n_total + dcap:
-                raise RuntimeError("aggregation group overflow beyond row count")
-            agg_cap = _grown_cap(agg_cap, ngroups, n_total + dcap)
-            continue
-        break
-    _emit_kernel_warnings(buf, kernel, warn)
-    return _chunk_from_bufs(buf, fbuf, count, kernel, dag, cache, scan)
+        buf, fbuf, count = _run_one(ph, kernel, args)
+        if int(buf[0, 1]) <= kernel.agg_cap:
+            break
+        if agg_cap >= n_total + dcap:
+            raise RuntimeError("aggregation group overflow beyond row count")
+        agg_cap = _grown_cap(agg_cap, int(buf[0, 1]), n_total + dcap)
+        ph.to("bind")
+    return _decode_one(ph, buf, fbuf, count, kernel, dag, cache, scan, warn)
 
 
-def _blocks_paged_limit(run_block, nb: int, kernel, dag, cache, scan, warn=None):
+def _blocks_paged_limit(ph, run_block, nb: int, kernel, dag, cache, scan, warn=None):
     """LIMIT-last: stream blocks with grow-on-demand lookahead, stop once the
     limit is satisfiable (ref: paging page-size growth, copr/coprocessor.go:368)."""
     import jax
@@ -767,9 +828,13 @@ def _blocks_paged_limit(run_block, nb: int, kernel, dag, cache, scan, warn=None)
         batch = list(range(bi, min(bi + window, nb)))
         packed = [run_block(i) for i in batch]
         tup = isinstance(packed[0], tuple)
+        ph.to("fetch")
         if kernel.out_n > 65536:  # LIMIT-last DAGs are always rows-kind
             counts, packed = _probe_slice_rows(packed, kernel)
         fetched = jax.device_get(packed)
+        with _tracing.region("exec.release"):
+            del packed
+        ph.to("decode")
         for got_b in fetched:
             buf, fbuf = got_b if tup else (got_b, None)
             cnt = int(buf[0, 0])

@@ -41,6 +41,7 @@ from tidb_tpu.kv.kv import (
 from tidb_tpu.kv.detector import DeadlockDetector
 from tidb_tpu.kv import tablecodec
 from tidb_tpu.utils import execdetails as _ed
+from tidb_tpu.utils import tracing as _tracing
 
 OP_PUT = "P"
 OP_DEL = "D"
@@ -508,7 +509,9 @@ class MemStore:
         # distinguishes this store in process-global caches (device arrays):
         # region/table ids restart per store and would otherwise collide
         self.nonce = uuid.uuid4().hex
-        self._mu = threading.RLock()
+        # the store's one lock: 2PC commits under it and the column cache
+        # reads under it, so its waits are counted and traced by name
+        self._mu = _tracing.TracedLock("memstore", threading.RLock())
         self._writes: dict[bytes, list[Write]] = {}
         # stable columnar layer: table_id → ingest-ordered StableBlocks
         # (later blocks override earlier ones on handle collision)

@@ -743,7 +743,7 @@ class StoreServer:
                 tracer = Tracer(trace_id=tctx.trace_id)
             t0 = time.perf_counter()
             with _ed.collecting(det, tracer=tracer):
-                with _ed.trace_span(f"cop.r{h['region_id']}"):
+                with _tracing.region("cop.task", label=f"cop.r{h['region_id']}", region=h["region_id"]):
                     chunk = engine(
                         st, dag, region, ranges, h["read_ts"],
                         warn=lambda lv, code, msg: len(warns) < 64 and warns.append([lv, code, msg]),
@@ -888,6 +888,7 @@ class _RemoteCopClient:
         # side records spans (nor ships the header) — one rule, one home
         tracer = _tracing.effective(req.tracer)
         parent_span = tracer.current() if tracer is not None else None
+        stmt = _tracing.current_stmt()
         t_submit = time.perf_counter()
 
         def one_call(region_id, krs, store_type):
@@ -904,14 +905,15 @@ class _RemoteCopClient:
                 # records spans under it and ships them back (see the server
                 # cop handler); merge grafts them under this RPC's span
                 hdr["trace"] = tracer.context().to_pb()
-                with tracer.span(f"cop-rpc.r{region_id}", parent=parent_span) as sp:
-                    h, blobs = self.store._call(hdr)
-                if h.get("spans"):
-                    tracer.merge_remote(
-                        h["spans"], base_s=sp.start_s, node=store_addr, depth=sp.depth + 1
-                    )
-            else:
+            with _tracing.region(
+                "cop.rpc", tracer=tracer, parent=parent_span, region=region_id,
+                label=f"cop-rpc.r{region_id}" if tracer is not None else None,
+            ) as sp:
                 h, blobs = self.store._call(hdr)
+            if tracer is not None and h.get("spans"):
+                tracer.merge_remote(
+                    h["spans"], base_s=sp.span.start_s, node=store_addr, depth=sp.span.depth + 1
+                )
             d = _ed.current_cop()
             if d is not None and h.get("exec"):
                 d.merge_pb(h["exec"])
@@ -933,7 +935,7 @@ class _RemoteCopClient:
             # server-side engine failures arrive as RuntimeError ("remote
             # store error: ..."); kill/quota verdicts arrive re-typed by
             # _call (the server ships the error kind) and must pass through
-            with _ed.collecting(det, tracer=tracer):
+            with _ed.collecting(det, tracer=tracer, stmt=stmt):
                 chunk = run_task_resilient(
                     bo,
                     run_one,

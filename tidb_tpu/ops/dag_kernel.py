@@ -28,6 +28,7 @@ byte-limb exact accumulation) instead of emulated VPU reductions.
 
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 from dataclasses import dataclass
@@ -38,6 +39,7 @@ import numpy as np
 from tidb_tpu.copr import dagpb
 from tidb_tpu.expression.expr import AggDesc, EvalBatch, _ft_from_pb, eval_expr, expr_from_pb
 from tidb_tpu.types import TypeKind
+from tidb_tpu.utils import tracing as _tracing
 
 MAX_RANGES = 8
 _I64_MAX = np.iinfo(np.int64).max
@@ -106,6 +108,9 @@ class CompiledKernel:
     # traces of the same DAG compute identical values, so last-writer-wins is
     # safe) and read after fn() returns, by which point a trace has completed
     _lanes: dict
+    # what the XLA module is named after (`jit_<family>` on a trace's module
+    # line): the DAG's shape, never its literals — see :func:`kernel_family`
+    family: str = "cop"
 
     @property
     def lane_loc(self):  # per-output ("i"|"f", row index) into packed buffer(s)
@@ -121,7 +126,46 @@ class CompiledKernel:
 
 
 _COMPILE_CACHE: dict[tuple, CompiledKernel] = {}
-_CACHE_MU = threading.Lock()
+_CACHE_MU = _tracing.TracedLock("kernel_cache", threading.Lock())
+
+# executor type → (its short name in a kernel family, its named scope)
+_STAGE = {
+    dagpb.SELECTION: ("sel", "selection"), dagpb.AGGREGATION: ("agg", "agg"), dagpb.STREAM_AGG: ("sagg", "agg"),
+    dagpb.TOPN: ("topn", "topn"), dagpb.LIMIT: ("limit", "limit"), dagpb.PROJECTION: ("proj", "projection"),
+    dagpb.WINDOW: ("win", "window"),
+}
+
+
+class _Stages(contextlib.ExitStack):
+    """A kernel's stages as ``jax.named_scope``s (``scan.mask``,
+    ``delta.fold``, ``selection``, ``agg``, ``topn``, ``window``, ``pack``
+    ...): calling it ends the stage before and begins the named one; leaving
+    the ``with`` ends the last. Scopes are metadata on the operations — a
+    profiler trace names them, the compiler fuses as before."""
+
+    def __call__(self, name: str) -> None:
+        import jax
+
+        self.close()
+        self.enter_context(jax.named_scope(name))
+
+
+def kernel_family(dag: dagpb.DAGRequest, nb: int = 1, delta_cap: int = 0) -> str:
+    """The name a cop program carries in a profiler trace and in the
+    ``exec.dispatch`` span: ``cop_<executors after the scan>_g<group-by
+    keys>[_d][_b<blocks>]`` — ``cop_sel_agg_g0`` (Q6), ``cop_sel_agg_g2``
+    (Q1), ``..._d`` read through a delta, ``..._b4`` four fused blocks. From
+    the DAG's shape only: every literal and padded size of one statement
+    template lands in one family, so a reduction can sum a family's time."""
+    parts = ["cop"] + ([_STAGE.get(ex.tp, ("x",))[0] for ex in dag.executors[1:]] or ["scan"])
+    groups = [len(ex.group_by) for ex in dag.executors[1:] if ex.tp in (dagpb.AGGREGATION, dagpb.STREAM_AGG)]
+    if groups:
+        parts.append(f"g{groups[-1]}")
+    if delta_cap:
+        parts.append("d")
+    if nb > 1:
+        parts.append(f"b{nb}")
+    return "_".join(parts)
 
 
 # persistent XLA compile cache, used when JAX_COMPILATION_CACHE_DIR is unset:
@@ -207,7 +251,7 @@ def _arm_compile_probe(k: "CompiledKernel") -> None:
         from tidb_tpu.utils import metrics as _m
 
         t0 = _t.perf_counter()
-        with _ed.trace_span("jit-compile"):
+        with _tracing.region("jit-compile"):
             out = inner(*args, **kwargs)
         dt = _t.perf_counter() - t0
         k.fn = inner  # warm path: no wrapper left behind
@@ -575,7 +619,7 @@ def _build(
     def _cur_dws():
         return warn_holder[-1] if warn_holder else None
 
-    def _blockwise_dot(handles_blocks, cols_blocks, ranges, nvalid):
+    def _blockwise_dot(handles_blocks, cols_blocks, ranges, nvalid, stage):
         from tidb_tpu.ops.mxu_groupby import dot_acc, dot_plan, dot_recombine
 
         group_exprs, aggs, mode = parsed[-1]
@@ -595,6 +639,7 @@ def _build(
         strides = None
         lane_of_agg = occ_lane = n_pairs = None
         for b in range(nb):
+            stage("scan.mask")
             live = jnp.arange(n_pad, dtype=jnp.int32) < nvalid.astype(jnp.int32)[b]
             if full_scan:
                 mask_b = live
@@ -613,6 +658,7 @@ def _build(
             batch_b = EvalBatch(list(cols64_b), [None] * len(cols64_b), n_pad, warn=_cur_dws())
             batch_nw_b = EvalBatch(list(cols_nw_b), [None] * len(cols_nw_b), n_pad, warn=_cur_dws())
             for ex, pre in zip(executors[1:-1], parsed[:-1]):
+                stage("selection")
                 nok = getattr(ex, "narrow_ok", [])
                 for ci_, cond in enumerate(pre):
                     src = batch_nw_b if ci_ < len(nok) and nok[ci_] else batch_b
@@ -622,6 +668,7 @@ def _build(
                     if v is not None:
                         keep = keep & _vmask(v, n_pad)
                     mask_b = mask_b & keep
+            stage("agg")
             gvals_b = _gvals_for(group_exprs, gnar, batch_b, batch_nw_b, n_pad)
             if rollup_layout is not None:
                 seg = _mxu_rollup_segs(rollup_layout, gvals_b, mask_b, n_pad)
@@ -654,17 +701,19 @@ def _build(
         gslot = jnp.arange(out_len)
         gvalid_slot = gslot < ngroups
         out_valid = [ov & gvalid_slot for ov in out_valid]
+        stage("pack")
         offsets = dag.output_offsets or list(range(len(out_data)))
         outs = [(out_data[i], out_valid[i]) for i in offsets]
         return _pack(outs, ngroups, ngroups)
 
-    def _kernel_body(handles, cols, ranges, nvalid, delta):
+    def _kernel_body(handles, cols, ranges, nvalid, delta, stage):
         n = n_total
         warn_holder.clear()
         warn_holder.append(_DeviceWarnSink())
         if nb > 1 and blockwise_doms is not None:
             # agg-last DAG on the MXU dot: per-block accumulation, no concat
-            return _blockwise_dot(handles, cols, ranges, nvalid)
+            return _blockwise_dot(handles, cols, ranges, nvalid, stage)
+        stage("scan.mask")
         hrank = None
         handles_blocks = handles if nb > 1 else None
         if nb > 1:
@@ -684,6 +733,7 @@ def _build(
             live = jnp.arange(n, dtype=jnp.int32) < nvalid.astype(jnp.int32)
         handles = handles.astype(jnp.int64)
         if delta is not None:
+            stage("delta.fold")
             dh, dcols, dtomb, dn = delta
             dh = dh.astype(jnp.int64)  # sorted; pads hold int64-max
             # dn = [mask_n, union_lo, union_hi]: every dispatch masks against
@@ -732,6 +782,7 @@ def _build(
                 for (d, v), (dd, dv) in zip(cols, dcols)
             )
             n = n_eff
+            stage("scan.mask")
         # HBM lanes may be narrowed (int32 dict codes / bounded values — see
         # tpu_engine._narrowed). TWO views: the default batch upcasts integer
         # lanes to int64 (fused into each consumer); binder-proven narrow
@@ -761,6 +812,7 @@ def _build(
         ngroups = None
 
         for exi, (ex, pre) in enumerate(zip(executors[1:], parsed)):
+            stage(_STAGE[ex.tp][1] if ex.tp in _STAGE else ex.tp)
             if ex.tp == dagpb.SELECTION:
                 nok = getattr(ex, "narrow_ok", [])
                 for ci_, cond in enumerate(pre):
@@ -1325,6 +1377,7 @@ def _build(
 
         # final packaging; ngroups travels out so the caller can detect
         # agg-cap overflow even when agg is not the last executor
+        stage("pack")
         og = ngroups if ngroups is not None else jnp.asarray(-1, dtype=jnp.int64)
         offsets = dag.output_offsets or list(range(len(batch.cols)))
         if kind == "agg":
@@ -1403,13 +1456,17 @@ def _build(
 
     if D:
         def kernel(handles, cols, ranges, nvalid, dh, dcols, dtomb, dn):
-            return _kernel_body(handles, cols, ranges, nvalid, (dh, dcols, dtomb, dn))
+            with _Stages() as stage:
+                return _kernel_body(handles, cols, ranges, nvalid, (dh, dcols, dtomb, dn), stage)
     else:
         def kernel(handles, cols, ranges, nvalid):
-            return _kernel_body(handles, cols, ranges, nvalid, None)
+            with _Stages() as stage:
+                return _kernel_body(handles, cols, ranges, nvalid, None, stage)
 
+    family = kernel_family(dag, nb, D)
+    kernel.__name__ = kernel.__qualname__ = family  # the XLA module is jit_<family>
     jitted = jax.jit(kernel)
-    return CompiledKernel(jitted, "agg" if agg_is_last else "rows", out_n, agg_cap, lanes_holder)
+    return CompiledKernel(jitted, "agg" if agg_is_last else "rows", out_n, agg_cap, lanes_holder, family)
 
 
 def _hier_top_k(jax, jnp, vals, K: int):

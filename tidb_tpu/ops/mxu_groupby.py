@@ -24,11 +24,27 @@ the full 10-limb int64 split, still exact but wider.
 
 from __future__ import annotations
 
+import functools
+
 _CHUNK = 1 << 23  # int32 accumulator headroom: 255 * 2^23 < 2^31
 _LIMB_BITS = 8  # biased to [-128, 127] so full bytes ride SIGNED int8
 _LIMB_MASK = (1 << _LIMB_BITS) - 1
 _LIMB_BIAS = 1 << (_LIMB_BITS - 1)
 MAX_B = 64  # onehot is materialized (B, chunk) int8 — keep it < ~512MB
+
+
+def _scoped(fn):
+    """Trace ``fn`` under ``jax.named_scope("mxu_groupby")``, so that its
+    operations carry that name in a profiler trace (metadata only)."""
+
+    @functools.wraps(fn)
+    def inner(*args, **kwargs):
+        import jax
+
+        with jax.named_scope("mxu_groupby"):
+            return fn(*args, **kwargs)
+
+    return inner
 
 
 def rollup_bucket_space(doms) -> int:
@@ -108,6 +124,7 @@ def dot_plan(pairs, bounds):
     return (plans, col_specs, w_col_of, limb_cols_of, len(col_specs))
 
 
+@_scoped
 def dot_acc(seg, pairs, B: int, n: int, plan, acc=None):
     """Accumulate one batch's grouped int8 matmuls into ``acc`` (B, C) int64.
     Chunks internally so the int32 accumulator never overflows."""
@@ -177,6 +194,7 @@ def dot_acc(seg, pairs, B: int, n: int, plan, acc=None):
     return acc
 
 
+@_scoped
 def dot_recombine(acc, plan, L: int, B: int):
     """(B, C) limb accumulator → exact (counts, sums), both (B, L) int64."""
     import jax.numpy as jnp

@@ -1558,13 +1558,11 @@ class MPPGatherExec:
         spec = gather_to_pb(self.plan, cap, schema_ver=sess._db.catalog.schema_version)
         store = sess.store
         import time as _t
-        from contextlib import nullcontext
 
+        from tidb_tpu.utils import tracing as _tracing
         from tidb_tpu.utils.execdetails import MPPExecDetails
 
-        from tidb_tpu.utils.tracing import effective as _effective_tracer
-
-        tr = _effective_tracer(sess.tracer)
+        tr = _tracing.effective(sess.tracer)
         store_addr = f"{getattr(store, 'host', 'shard')}:{getattr(store, 'port', '?')}"
         exec_pb: list = []
         t0 = _t.perf_counter()
@@ -1586,7 +1584,7 @@ class MPPGatherExec:
         # the dispatch+conn pair runs under ONE client span; the server's
         # task session records its own spans under the propagated context
         # and they graft in here, tagged with the store that recorded them
-        with (tr.span("mpp-gather-rpc") if tr is not None else nullcontext()) as sp:
+        with _tracing.region("mpp-gather-rpc", tracer=tr) as sp:
             # the trace kwarg only appears when tracing is ON — untraced
             # dispatch keeps the plain (spec, read_ts) signature
             kw = {"trace": tr.context().to_pb()} if tr is not None else {}
@@ -1595,7 +1593,7 @@ class MPPGatherExec:
                 if e:
                     exec_pb.append(e)
                 if spans and tr is not None:
-                    tr.merge_remote(spans, base_s=sp.start_s, node=store_addr, depth=sp.depth + 1)
+                    tr.merge_remote(spans, base_s=sp.span.start_s, node=store_addr, depth=sp.span.depth + 1)
 
             while True:
                 try:

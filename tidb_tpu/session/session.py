@@ -24,6 +24,7 @@ from tidb_tpu.planner.optimizer import optimize
 from tidb_tpu.planner.plans import PlanError, explain_plan
 from tidb_tpu.utils import eventlog as _ev
 from tidb_tpu.utils import sysvar_int
+from tidb_tpu.utils import tracing as _tracing
 from tidb_tpu.utils.chunk import Chunk
 
 DEFAULT_SYSVARS = {
@@ -294,6 +295,7 @@ class Session:
         # (the reading statement already cleared self.warnings)
         self._prev_warnings: list[tuple] = []
         self._stmt_count = 0
+        self.conn_id = 0  # the wire server sets its connection's id
 
     def append_warning(self, level: str, code: int, msg: str) -> None:
         """Statement-context warning accumulation (ref: stmtctx.go:1025
@@ -397,11 +399,7 @@ class Session:
 
     # -- tracing (ref: util/tracing StartRegionEx call sites) ----------------
     def span(self, name: str):
-        if self.tracer is not None:
-            return self.tracer.span(name)
-        import contextlib
-
-        return contextlib.nullcontext()
+        return _tracing.region(name, tracer=self.tracer)
 
     def _sample_tracer(self):
         """The per-statement sampling coin (ref: Dapper §4 uniform
@@ -566,8 +564,6 @@ class Session:
     def execute(self, sql: str) -> Result:
         import time as _time
 
-        from tidb_tpu.utils import metrics as _m
-
         t0 = _time.perf_counter()
         # -- always-on sampled tracing: ONE dict read when the rate is 0, so
         # the tracer-is-None zero-cost path stays strictly intact
@@ -577,13 +573,34 @@ class Session:
             # nothing leaks across statements
             self.tracer = None
             self._sampled_tracer = None
-        s_span = None
         if self.tracer is None and self.vars.get("tidb_tpu_trace_sample_rate", 0):
             tr = self._sample_tracer()
             if tr is not None:
                 self.tracer = self._sampled_tracer = tr
-                s_span = tr.span("statement")
-                s_span.__enter__()
+        # the one id every span of this statement carries, its cop tasks on
+        # pool threads included (utils/tracing; CopClient.send hands it on)
+        self._stmt_count += 1
+        who = f"c{self.conn_id}" if self.conn_id else f"s{id(self):x}"  # an embedded session has no connection
+        prev_bound = _tracing.bind(None, f"{who}.{self._stmt_count}")
+        s_span = self.span("statement")
+        s_span.__enter__()
+        try:
+            return self._execute_bound(sql, t0)
+        finally:
+            s_span.__exit__(None, None, None)
+            _tracing.bind(*prev_bound)
+            if self._sampled_tracer is not None:
+                tr, self._sampled_tracer = self._sampled_tracer, None
+                if self.tracer is tr:
+                    self.tracer = None
+                self._deposit_trace(tr, _time.perf_counter() - t0, sql)
+
+    def _execute_bound(self, sql: str, t0: float) -> Result:
+        """``execute`` once the statement's id and root span are in place."""
+        import time as _time
+
+        from tidb_tpu.utils import metrics as _m
+
         entry: Optional[_CachedStmt] = None
         cached = self._stmt_cache.get(sql)
         if cached is not None:
@@ -678,7 +695,6 @@ class Session:
                     inst_entry.digest = digest_cache[0]
             return digest_cache[0]
 
-        self._stmt_count += 1
         # per-statement exec-details lifecycle (cheap: three attribute sets)
         self.exec_summary = None
         self.mpp_details = []
@@ -776,13 +792,6 @@ class Session:
         finally:
             if topsql is not None:
                 topsql.detach()
-            if self._sampled_tracer is not None:
-                tr, self._sampled_tracer = self._sampled_tracer, None
-                if s_span is not None:
-                    s_span.__exit__(None, None, None)
-                if self.tracer is tr:
-                    self.tracer = None
-                self._deposit_trace(tr, _time.perf_counter() - t0, sql)
 
     def query(self, sql: str) -> list[tuple]:
         return self.execute(sql).rows
@@ -957,7 +966,7 @@ class Session:
 
             self.tracer = Tracer()
             try:
-                with self.tracer.span(type(stmt.stmt).__name__.lower()):
+                with self.span(type(stmt.stmt).__name__.lower()):
                     self._execute_stmt(stmt.stmt)
             finally:
                 tracer, self.tracer = self.tracer, None
@@ -1424,8 +1433,10 @@ class Session:
         if g is not None and g.exec_elapsed_s and g.action == "DRYRUN":
             self._runaway_obs = (time.monotonic() + g.exec_elapsed_s, g.name)
         try:
-            with self.span("plan"):
+            with self.span("plan") as p_span:
                 plan = self._plan_select(stmt, cache_key=cache_key, capture=is_outer)
+                if p_span is not None:
+                    p_span.note(cache="hit" if self.vars.get("last_plan_from_cache") else "miss")
             from tidb_tpu.executor import build_executor
 
             from tidb_tpu.parallel.probe import MPPRetryExhausted

@@ -53,6 +53,8 @@ def _is_lock_ctor(node: ast.expr) -> bool:
     leaf = name.rsplit(".", 1)[-1]
     if leaf in _LOCK_CTORS and ("threading" in name or name == leaf):
         return True
+    if leaf == "TracedLock":  # utils/tracing: a name around a lock made in place
+        return any(_is_lock_ctor(a) for a in node.args)
     # factory aliases (lockcheck's _ORIG_LOCK, bound pre-instrumentation)
     return not node.args and leaf.upper().endswith(("_LOCK", "_RLOCK"))
 

@@ -29,8 +29,10 @@ import hashlib
 import math
 import threading
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from dataclasses import dataclass, field
+
+from tidb_tpu.utils import tracing as _tracing
 
 
 @dataclass
@@ -55,6 +57,7 @@ class CopExecDetails:
         "host_ms", "compile_ms", "h2d_bytes", "d2h_bytes", "dev_cache_hits",
         "dev_cache_misses", "engine", "degraded", "retries", "backoff_ms",
         "resplits", "delta_rows", "merges", "keys_scanned", "bytes_scanned",
+        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms",
     )
 
     def __init__(self, region_id: int = -1, store: str = ""):
@@ -63,7 +66,7 @@ class CopExecDetails:
         self.queue_ms = 0.0  # send-queue wait before a worker picked it up
         self.wire_ms = 0.0  # RPC wall minus store-side processing (remote)
         self.proc_ms = 0.0  # store-side processing wall
-        self.device_ms = 0.0  # device-path wall (dispatch + transfer back)
+        self.device_ms = 0.0  # host wall of the device path (RU accounting reads it); the chip's share is ≤ fetch_ms
         self.host_ms = 0.0  # host-engine wall
         self.compile_ms = 0.0  # first-call jit compile (kernel-cache miss)
         self.h2d_bytes = 0
@@ -79,6 +82,15 @@ class CopExecDetails:
         self.merges = 0  # delta→base merges this task triggered (query-path)
         self.keys_scanned = 0  # store-side MVCC keys this task read (RU input)
         self.bytes_scanned = 0  # store-side bytes those keys carried
+        # the device path's wall by phase (PHASES): bind = column cache + binder
+        # + kernel lookup, inputs = device-input assembly (LRU lookups, H2D),
+        # dispatch = host enqueue of the program, fetch = blocked on the
+        # device's result + D2H (the wait for the chip), decode = buffers → Chunk
+        self.bind_ms = 0.0
+        self.inputs_ms = 0.0
+        self.dispatch_ms = 0.0
+        self.fetch_ms = 0.0
+        self.decode_ms = 0.0
 
     def to_pb(self) -> dict:
         """Compact wire form (zeros omitted — the sidecar rides every cop
@@ -116,6 +128,10 @@ class CopExecDetails:
             out["sk"] = self.keys_scanned
         if self.bytes_scanned:
             out["sb"] = self.bytes_scanned
+        for key, attr in _PHASE_PB:
+            v = getattr(self, attr)
+            if v:
+                out[key] = round(v, 3)
         return out
 
     def merge_pb(self, pb: dict) -> None:
@@ -140,6 +156,14 @@ class CopExecDetails:
         self.merges += int(pb.get("mg", 0))
         self.keys_scanned += int(pb.get("sk", 0))
         self.bytes_scanned += int(pb.get("sb", 0))
+        for key, attr in _PHASE_PB:
+            if key in pb:
+                setattr(self, attr, getattr(self, attr) + float(pb[key]))
+
+
+# the device path's phases, in the order they run and render
+PHASES = ("bind", "inputs", "dispatch", "fetch", "decode")
+_PHASE_PB = tuple(("ph" + p[:2], p + "_ms") for p in PHASES)  # wire key → attribute
 
 
 class CopTasksSummary:
@@ -151,7 +175,7 @@ class CopTasksSummary:
         "h2d_bytes", "d2h_bytes", "dev_cache_hits", "dev_cache_misses",
         "engines", "degraded", "retries", "backoff_ms", "resplits",
         "delta_rows", "merges", "keys_scanned", "bytes_scanned",
-        "max_proc_ms", "max_task_store", "max_task_region",
+        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms",
     )
 
     def __init__(self):
@@ -177,6 +201,7 @@ class CopTasksSummary:
         self.max_proc_ms = 0.0
         self.max_task_store = ""
         self.max_task_region = -1
+        self.phases_ms = [0.0] * len(PHASES)
 
     @property
     def num(self) -> int:
@@ -204,6 +229,8 @@ class CopTasksSummary:
         self.merges += d.merges
         self.keys_scanned += d.keys_scanned
         self.bytes_scanned += d.bytes_scanned
+        for i, (_key, attr) in enumerate(_PHASE_PB):
+            self.phases_ms[i] += getattr(d, attr)
         if d.proc_ms >= self.max_proc_ms:
             self.max_proc_ms = d.proc_ms
             self.max_task_store = d.store or "local"
@@ -236,6 +263,8 @@ class CopTasksSummary:
             parts.append(f"compile: {self.compile_ms:.1f}ms")
         if self.device_ms:
             parts.append(f"device: {self.device_ms:.1f}ms")
+        if any(self.phases_ms):
+            parts.append(f"phases: {'/'.join(PHASES)} " + "/".join(f"{v:.1f}" for v in self.phases_ms) + "ms")
         if self.host_ms:
             parts.append(f"host: {self.host_ms:.1f}ms")
         if self.h2d_bytes or self.d2h_bytes:
@@ -330,28 +359,18 @@ def current_cop() -> "CopExecDetails | None":
     return getattr(_TLS, "detail", None)
 
 
-def current_tracer():
-    """The Tracer the active task records spans into (remote server side or
-    an embedded traced statement); None when tracing is off."""
-    return getattr(_TLS, "tracer", None)
-
-
 @contextmanager
-def collecting(detail: "CopExecDetails | None", tracer=None):
+def collecting(detail: "CopExecDetails | None", tracer=None, stmt=None):
+    """``detail`` is the sidecar this thread's engines fill; ``tracer`` and
+    ``stmt`` are what its spans record into and carry (utils/tracing)."""
     prev_d = getattr(_TLS, "detail", None)
-    prev_t = getattr(_TLS, "tracer", None)
-    _TLS.detail, _TLS.tracer = detail, tracer
+    _TLS.detail = detail
+    prev = _tracing.bind(tracer, stmt)
     try:
         yield detail
     finally:
-        _TLS.detail, _TLS.tracer = prev_d, prev_t
-
-
-def trace_span(name: str):
-    """A span on the active task's tracer — nullcontext when tracing is off
-    (the zero-cost-when-off rule)."""
-    tr = current_tracer()
-    return tr.span(name) if tr is not None else nullcontext()
+        _TLS.detail = prev_d
+        _tracing.bind(*prev)
 
 
 # -- plan digest -------------------------------------------------------------

@@ -283,6 +283,11 @@ DEVICE_TRANSFER = REGISTRY.counter(
     "Host<->device bytes moved by the cop engines",
     ("dir",),
 )
+LOCK_WAIT_SECONDS = REGISTRY.counter(
+    "tidb_tpu_lock_wait_seconds_total",
+    "Seconds threads spent blocked on a contended served-path lock (utils/tracing.TracedLock)",
+    ("lock",),
+)
 SERVER_CONNS = REGISTRY.gauge(
     "tidb_tpu_server_connections", "Open wire-protocol client connections"
 )

@@ -29,16 +29,26 @@ trace whose statement crossed the slow-log threshold, so the interesting
 outliers survive ring rotation (the slow log cross-links them by trace id).
 Unsampled statements never construct a tracer: the ``Request.tracer is
 None`` zero-cost rule is untouched.
+
+The ONE seam every span site calls is :func:`region`. It records into the
+thread's statement/task ``Tracer`` when there is one (TRACE, sampled), and
+— while a ``jax.profiler`` session is live — also into the profiler's own
+trace as ``tidb:<name>``, which puts the program's spans on the clock of the
+device operations. With neither it returns one shared null context. Lock
+waits go through :class:`TracedLock` the same way.
 """
 
 from __future__ import annotations
 
+import sys
 import threading
 import time
 import uuid
 from collections import OrderedDict, deque
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
+
+from tidb_tpu.utils import metrics as _metrics
 
 
 @dataclass
@@ -173,6 +183,138 @@ def effective(tracer) -> "Tracer | None":
     if tracer is None or not getattr(tracer, "sampled", True):
         return None
     return tracer
+
+
+# -- the seam ----------------------------------------------------------------
+
+PREFIX = "tidb:"  # of every span this program writes into a profiler trace
+_NULL = nullcontext()  # what region() returns when nothing records
+_TLS = threading.local()  # .tracer: the task's Tracer; .stmt: the statement's id
+_annotation = None  # jax.profiler.TraceAnnotation, once some module has imported jax
+
+
+def profiling() -> bool:
+    """True while a ``jax.profiler`` session is live in this process. The
+    program is not told (a benchmark or an operator starts the session from
+    outside), so it asks the runtime. SQL nodes over a remote store never
+    import jax, and this never imports it for them."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        if "jax" not in sys.modules:
+            return False
+        try:
+            from jax.profiler import TraceAnnotation as ann
+        except (ImportError, AttributeError):  # jax itself is mid-import
+            return False
+        _annotation = ann
+    return ann.is_enabled()
+
+
+def live() -> bool:
+    """Whether :func:`region` would record on this thread now: a site that
+    opens many spans a task asks once and saves the calls when not."""
+    return getattr(_TLS, "tracer", None) is not None or profiling()
+
+
+def current_stmt() -> "str | None":
+    """The id every span of the thread's current statement carries."""
+    return getattr(_TLS, "stmt", None)
+
+
+def bind(tracer: "Tracer | None", stmt: "str | None") -> tuple:
+    """Make ``tracer`` and ``stmt`` this thread's: a session around one
+    statement, a cop worker around one task (which is how spans opened on
+    pool threads carry the requester's statement id). Returns what was
+    bound before; ``bind(*prev)`` puts it back."""
+    prev = (getattr(_TLS, "tracer", None), getattr(_TLS, "stmt", None))
+    _TLS.tracer, _TLS.stmt = tracer, stmt
+    return prev
+
+
+class _Region:
+    """One live span: in the Tracer (under ``label`` where its name there
+    differs), in the profiler's trace, or both. ``span`` is the Tracer's
+    Span or None; ``note`` adds what is only known once the work is done."""
+
+    __slots__ = ("span", "_cm", "_ann")
+
+    def __init__(self, name, tracer, parent, label, profiled, meta):
+        self.span = None
+        self._cm = tracer.span(label or name, parent=parent) if tracer is not None else None
+        self._ann = None
+        if profiled:
+            stmt = getattr(_TLS, "stmt", None) or (tracer.trace_id if tracer is not None else None)
+            if stmt is not None:
+                meta["stmt"] = stmt
+            self._ann = _annotation(PREFIX + name, **meta)
+
+    def __enter__(self):
+        if self._cm is not None:
+            self.span = self._cm.__enter__()
+        if self._ann is not None:
+            self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        if self._cm is not None:
+            self._cm.__exit__(*exc)
+        return False
+
+    def note(self, **meta) -> None:
+        if self._ann is not None:
+            self._ann.set_metadata(**meta)
+
+
+def region(name: str, tracer: "Tracer | None" = None, parent: "Span | None" = None,
+           label: "str | None" = None, **meta):
+    """Open a span. ``tracer`` defaults to the thread's (see :func:`bind`);
+    ``parent`` nests a worker's span under the requester's. Yields a
+    :class:`_Region`, or None from the shared null context when neither a
+    Tracer nor a profiler session records — sites that add to a span guard
+    on that (``if r is not None: r.note(...)``)."""
+    if tracer is None:
+        tracer = getattr(_TLS, "tracer", None)
+    profiled = profiling()
+    if tracer is None and not profiled:
+        return _NULL
+    return _Region(name, tracer, parent, label, profiled, meta)
+
+
+class TracedLock:
+    """A named ``threading.Lock``/``RLock`` that reports what it costs to
+    wait for it. ``acquire(False)`` first: an uncontended acquire reads no
+    clock. Only when that fails is the blocking acquire timed, added to
+    ``tidb_tpu_lock_wait_seconds_total{lock}`` and, seam live, written as a
+    ``lock.wait`` span. The lock inside comes from the ``threading`` factory,
+    so ``utils/lockcheck`` sees it like any other."""
+
+    __slots__ = ("name", "_lock")
+
+    def __init__(self, name: str, lock):
+        self.name = name
+        self._lock = lock
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock.acquire(False):
+            return True
+        if not blocking:
+            return False
+        t0 = time.perf_counter()
+        with region("lock.wait", lock=self.name):
+            got = self._lock.acquire(True, timeout)
+        _metrics.LOCK_WAIT_SECONDS.inc(time.perf_counter() - t0, lock=self.name)
+        return got
+
+    def release(self) -> None:
+        self._lock.release()
+
+    __enter__ = acquire
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
 
 
 def clamp_rate(rate: float, qps: float, clamp_qps: float) -> float:
