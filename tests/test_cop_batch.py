@@ -1,0 +1,340 @@
+"""The batch cop task (ISSUE 27): a ``tpu`` request whose results are
+order-blind partial aggregates goes to the engine as ONE task carrying every
+region (``copr/client.py``, ``tpu_engine._batch_path``). What must hold: the
+same partial results, row for row, as a task a region gives, and the host
+engine's answer; a region that is not clean leaves the batch and runs alone;
+the chaos seam fires once a region and a fault touches that region only; the
+sidecar, the ``tidb:cop.task`` span and the counter agree on how many regions
+a task served; a request that is not order-blind keeps a task a region."""
+
+import dataclasses
+import glob
+
+import numpy as np
+import pytest
+
+import tidb_tpu
+from tidb_tpu import config
+from tidb_tpu.copr import client as cop_client
+from tidb_tpu.copr import tpu_engine
+from tidb_tpu.copr.client import CopClient
+from tidb_tpu.executor.load import bulk_load
+from tidb_tpu.kv.fault_injection import NShot
+from tidb_tpu.kv.kv import RegionError, StoreType
+from tidb_tpu.ops import dag_kernel
+from tidb_tpu.utils import failpoint, metrics, tracing
+from tidb_tpu.utils.chunk import Chunk
+
+ROWS, SPLIT = 4000, 1000
+Q1 = "SELECT f, r, SUM(q), SUM(v), SUM(v * (1 - d)), AVG(q), COUNT(*) FROM t WHERE k <= {} GROUP BY f, r ORDER BY f, r"
+Q6 = "SELECT SUM(v * d) FROM t WHERE k >= {} AND k < {} AND d BETWEEN 0.02 AND 0.06 AND q < 24"
+TOPN = "SELECT id, v FROM t WHERE k < 5 ORDER BY v DESC, id LIMIT 7"
+SHAPES = {"q1": Q1.format(5), "q6": Q6.format(1, 6)}
+
+
+def _mk_db(rows=ROWS, split=SPLIT, groups=None):
+    """``t``: ``rows`` rows over at least 4 regions (loaded in batches, so the
+    regions split as they grow), read by the ``tpu`` engine."""
+    db = tidb_tpu.open(region_split_keys=split)
+    s = db.session()
+    s.execute("CREATE TABLE t (id BIGINT PRIMARY KEY, k INT, q INT, d DECIMAL(4,2), v DECIMAL(12,2), f CHAR(1), r CHAR(1), g BIGINT)")
+    ids = np.arange(rows, dtype=np.int64)
+    cols = [ids, ids % 7, ids % 50, ids % 11, ids * 150 + 25, np.array([b"A", b"N", b"R"])[ids % 3],
+            np.array([b"F", b"O"])[ids % 2], ids % 10 if groups is None else groups(ids)]
+    for lo in range(0, rows, split // 2):
+        bulk_load(db, "t", [c[lo : lo + split // 2] for c in cols])
+    assert len(db.store.regions()) >= 4
+    s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    return db, s
+
+
+@pytest.fixture(scope="module")
+def served():
+    db, s = _mk_db()
+    for text in SHAPES.values():  # builds every region's column-cache entry: a first read is a task a region
+        s.query(text)
+    return db, s
+
+
+def _host(s, text):
+    s.execute("SET tidb_isolation_read_engines = 'host'")
+    try:
+        return s.query(text)
+    finally:
+        s.execute("SET tidb_isolation_read_engines = 'tpu'")
+
+
+def _requests(s, text, monkeypatch):
+    """The cop requests the statement sends, and what it answered."""
+    sent = []
+    real = CopClient.send
+
+    def send(self, req):
+        sent.append(req)
+        return real(self, req)
+
+    with monkeypatch.context() as m:
+        m.setattr(CopClient, "send", send)
+        rows = s.query(text)
+    return sent, rows
+
+
+def _summary(s, text):
+    rows = s.query(text)
+    return rows, s.exec_summary
+
+
+def _counts():
+    return metrics.COP_REGIONS.get(path="batched"), metrics.COP_REGIONS.get(path="single")
+
+
+# -- the same answers ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_batch_gives_each_regions_partials_row_for_row(served, shape, monkeypatch):
+    db, s = served
+    (req,), rows = _requests(s, SHAPES[shape], monkeypatch)
+    assert rows == _host(s, SHAPES[shape])
+    assert cop_client._order_blind_partial(req, req.data)
+    regions = list(db.store.pd.regions_in_ranges(req.ranges))
+    assert len(regions) >= 4
+    (res,) = list(CopClient(db.store).send(req))
+    assert res.details.regions == len(regions) and res.details.engine == "tpu" and not res.details.degraded
+    one_by_one = [tpu_engine.execute_dag(db.store, req.data, r, rg, req.start_ts) for r, rg in regions]
+    assert all(len(c) for c in one_by_one)  # a partial row (or group rows) from every region
+    assert res.chunk.rows() == Chunk.concat(one_by_one).rows()
+    host = [cop_client._engines()[StoreType.HOST](db.store, req.data, r, rg, req.start_ts) for r, rg in regions]
+    assert sorted(res.chunk.rows()) == sorted(Chunk.concat(host).rows())
+
+
+def test_regions_of_another_padded_shape_share_the_batch(monkeypatch):
+    """The last region is smaller than the others: another ``n_pad``, another
+    program, the same batch."""
+    db, s = _mk_db(rows=9300, split=4000)  # regions of 2,000 to 3,000 rows: padded to 2,048 and to 4,096
+    s.query(SHAPES["q1"])
+    with dag_kernel._CACHE_MU:
+        dag_kernel._COMPILE_CACHE.clear()
+    rows, summary = _summary(s, SHAPES["q1"])
+    assert rows == _host(s, SHAPES["q1"])
+    assert summary.num == 1 and summary.regions == len(list(db.store.pd.regions_in_ranges(_requests(s, SHAPES["q1"], monkeypatch)[0][0].ranges)))
+    with dag_kernel._CACHE_MU:
+        pads = {key[1] for key in dag_kernel._COMPILE_CACHE}
+    assert len(pads) >= 2, pads
+
+
+WIDE = [
+    "SELECT SUM(b), COUNT(*) FROM w WHERE b > 7 AND c < 4",
+    "SELECT f, SUM(b + c), MIN(b), MAX(b) FROM w WHERE b - 3 > c GROUP BY f ORDER BY f",
+    "SELECT c, SUM(b * c) FROM w GROUP BY c ORDER BY c",
+]
+
+
+@pytest.fixture(scope="module")
+def wide():
+    """``w.b`` fits int32 in the first regions and not in the later ones: the
+    batch binds ONCE over the union of the regions' min/max, so its narrow-lane
+    and magnitude proofs must hold for every region's device arrays, whichever
+    width each was uploaded in."""
+    db = tidb_tpu.open(region_split_keys=1000)
+    s = db.session()
+    s.execute("CREATE TABLE w (id BIGINT PRIMARY KEY, b BIGINT, c INT, f CHAR(1))")
+    ids = np.arange(4000, dtype=np.int64)
+    cols = [ids, np.where(ids < 1500, ids, ids * 3_000_000_000), ids % 5, np.array([b"A", b"B"])[ids % 2]]
+    for lo in range(0, 4000, 500):
+        bulk_load(db, "w", [c[lo : lo + 500] for c in cols])
+    s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    return db, s
+
+
+@pytest.mark.parametrize("text", WIDE)
+def test_one_bind_over_regions_of_different_widths(wide, text):
+    _, s = wide
+    s.query(text)
+    rows, summary = _summary(s, text)
+    assert summary.num == 1 and summary.regions >= 4 and summary.engines == {"tpu": 1}
+    assert rows == _host(s, text)
+
+
+# -- a region that is not clean leaves ------------------------------------------
+
+
+def test_region_with_a_pending_delta_leaves_and_the_answer_holds_the_write(monkeypatch):
+    # a base this small is rebuilt outright as shipped; let it be delta-tracked
+    monkeypatch.setattr(config, "_CURRENT", dataclasses.replace(config.current(), device_delta_min_rows=1))
+    db, s = _mk_db()
+    text = SHAPES["q6"]
+    s.query(text)
+    before, summary = _summary(s, text)
+    assert summary.num == 1 and summary.delta_rows == 0
+    n = summary.regions
+    s.execute(f"INSERT INTO t VALUES ({ROWS + 5}, 3, 1, 0.04, 1000.00, 'A', 'F', 1)")  # acknowledged: autocommit
+    after, summary = _summary(s, text)
+    assert after == _host(s, text)
+    assert after[0][0] - before[0][0] == 40  # 1000.00 * 0.04: the answer holds the write
+    assert summary.num == 2 and summary.regions == n  # the batch, and the written region alone
+    assert summary.delta_rows == 1 and summary.engines == {"tpu": 2} and not summary.degraded
+
+
+def test_first_read_builds_each_region_in_a_task_of_its_own():
+    db, s = _mk_db()
+    rows, summary = _summary(s, SHAPES["q6"])
+    assert summary.num == summary.regions >= 4  # nothing cached: every region left the batch, which served none
+    again, summary = _summary(s, SHAPES["q6"])
+    assert again == rows and summary.num == 1 and summary.regions >= 4
+
+
+# -- faults keep their grain ------------------------------------------------------
+
+
+def _die():
+    raise RuntimeError("chaos: TPU device lost mid-task")
+
+
+def test_engine_fault_degrades_that_region_alone(served):
+    db, s = served
+    text = SHAPES["q1"]
+    want, clean = _summary(s, text)
+    victim = sorted(r.region_id for r in db.store.regions())[-2]
+    degraded = metrics.COP_DEGRADED.get(reason="embedded")
+    shot = NShot(lambda rid, st: _die(), n_times=1, match=lambda rid, st: rid == victim and st == StoreType.TPU)
+    with failpoint.enabled("cop_task_engine", shot):
+        got, summary = _summary(s, text)
+    assert shot.fired == 1 and got == want
+    assert metrics.COP_DEGRADED.get(reason="embedded") == degraded + 1
+    assert summary.num == 2 and summary.regions == clean.regions
+    assert summary.engines == {"tpu": 1, "host": 1}  # the others stayed on the device, in the batch
+    assert summary.degraded == {"embedded:RuntimeError": 1}
+    assert any("degraded to host" in str(w) for w in s.query("SHOW WARNINGS"))
+
+
+def test_region_error_resplits_that_region_alone(served):
+    db, s = served
+    text = SHAPES["q6"]
+    want, clean = _summary(s, text)
+    victim = sorted(r.region_id for r in db.store.regions())[1]
+    backoffs = metrics.BACKOFF_TOTAL.get(config="regionMiss")
+
+    def miss(rid, st):
+        raise RegionError(rid, f"region {rid} epoch changed (chaos)")
+
+    shot = NShot(miss, n_times=1, match=lambda rid, st: rid == victim)
+    with failpoint.enabled("cop_task_engine", shot):
+        got, summary = _summary(s, text)
+    assert shot.fired == 1 and got == want
+    assert metrics.BACKOFF_TOTAL.get(config="regionMiss") == backoffs + 1
+    assert summary.num == 2 and summary.regions == clean.regions and summary.resplits == 1
+    assert summary.engines == {"tpu": 2} and not summary.degraded
+
+
+def test_seam_fires_once_a_region(served):
+    db, s = served
+    seen = []
+    with failpoint.enabled("cop_task_engine", lambda rid, st: seen.append((rid, st))):
+        _, summary = _summary(s, SHAPES["q6"])
+    assert len(seen) == len(set(seen)) == summary.regions and {st for _, st in seen} == {StoreType.TPU}
+
+
+def test_batch_that_fails_as_a_whole_falls_back_to_a_task_a_region(served, monkeypatch):
+    db, s = served
+    text = SHAPES["q1"]
+    want, clean = _summary(s, text)
+    real = tpu_engine._exec_single
+
+    def broken(ph, store, dag, bound, scan, cache, parts, warn=None):
+        if len(parts) > 1:
+            raise RuntimeError("chaos: the device dropped the batch")
+        return real(ph, store, dag, bound, scan, cache, parts, warn)
+
+    monkeypatch.setattr(tpu_engine, "_exec_single", broken)
+    got, summary = _summary(s, text)
+    assert got == want
+    assert summary.num == summary.regions == clean.regions  # no batch result; every region its own task
+    assert summary.engines == {"tpu": clean.regions} and not summary.degraded
+
+
+def test_agg_cap_overflow_reruns_that_region_alone():
+    """A group a row; one region (4,500 rows as the load leaves them, the
+    others 3,000 to 3,750) holds more groups than the first cap, 4,096: its
+    program runs again at a cap that holds them, the others' results stand."""
+    db, s = _mk_db(rows=24000, split=6000, groups=lambda ids: ids)
+    text = "SELECT g, COUNT(*), SUM(q) FROM t GROUP BY g ORDER BY g"
+    s.query(text)
+    with dag_kernel._CACHE_MU:
+        dag_kernel._COMPILE_CACHE.clear()
+    rows, summary = _summary(s, text)
+    assert len(rows) == 24000 and rows == _host(s, text)
+    assert summary.num == 1 and summary.regions >= 4 and summary.engines == {"tpu": 1}
+    with dag_kernel._CACHE_MU:
+        caps = sorted(key[2] for key in dag_kernel._COMPILE_CACHE)
+    assert caps[0] == 4096 and caps[-1] > 4096, caps
+    assert sum(1 for c in caps if c > 4096) == 1  # one region's shape, once
+
+
+# -- it says when it engages --------------------------------------------------------
+
+
+def test_sidecar_span_and_counter_agree(served, tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+
+    db, s = served
+    batched, single = _counts()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        _, summary = _summary(s, SHAPES["q1"])
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    spans: dict[str, list[dict]] = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(tracing.PREFIX):
+                    spans.setdefault(ev.name[len(tracing.PREFIX):], []).append(dict(ev.stats))
+    (task,) = spans["cop.task"]
+    assert summary.num == 1 and summary.regions >= 4
+    assert int(task["regions"]) == summary.regions
+    assert [int(d["regions"]) for d in spans["exec.dispatch"]] == [summary.regions]
+    assert len(spans["exec.fetch"]) == len(spans["exec.decode"]) == 1  # fetched once, decoded once
+    assert _counts() == (batched + summary.regions, single)
+    text = "\n".join(str(r) for r in s.query("EXPLAIN ANALYZE " + SHAPES["q1"]))
+    assert f"cop_task: {{num: 1," in text and f"regions: {summary.regions}," in text
+
+
+# -- what never batches ---------------------------------------------------------------
+
+
+def test_one_region_request_takes_the_old_path(served):
+    db, s = served
+    batched, single = _counts()
+    rows, summary = _summary(s, "SELECT SUM(v) FROM t WHERE id < 10")
+    assert rows[0][0] * 100 == sum(i * 150 + 25 for i in range(10))
+    assert summary.num == summary.regions == 1
+    assert _counts() == (batched, single + 1)
+
+
+def test_topn_request_takes_the_old_path(served, monkeypatch):
+    db, s = served
+    batched, single = _counts()
+    (req,), rows = _requests(s, TOPN, monkeypatch)
+    summary = s.exec_summary
+    assert not cop_client._order_blind_partial(req, req.data)
+    assert rows == _host(s, TOPN)
+    assert summary.num == summary.regions >= 4  # a task a region
+    assert _counts()[0] == batched
+
+
+def test_host_engine_takes_the_old_path(served):
+    db, s = served
+    batched, _ = _counts()
+    s.execute("SET tidb_isolation_read_engines = 'host'")
+    try:
+        _, summary = _summary(s, SHAPES["q6"])
+    finally:
+        s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    assert summary.num == summary.regions >= 4 and summary.engines == {"host": summary.num}
+    assert _counts()[0] == batched

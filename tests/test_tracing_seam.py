@@ -39,7 +39,7 @@ def _mk_db(rows=1000, split=200):
 @pytest.fixture(scope="module")
 def served():
     db, s = _mk_db()
-    assert len(db.store.regions()) > 4  # several cop tasks a statement, run on the shared pool
+    assert len(db.store.regions()) > 4  # several regions a statement: one batch cop task, and one more a region written since
     s.query(Q1.format(5))
     s.query(Q6.format(3))
     return db, s
@@ -112,7 +112,9 @@ def test_trace_phases_sum_to_no_more_than_device_exec(traced_rows):
 @pytest.fixture(scope="module")
 def profiled(served, tmp_path_factory):
     """One statement of each shape run through a `jax.profiler` session on
-    the CPU backend; the `tidb:` events of the trace, name -> [stats]."""
+    the CPU backend, each after a write to the table's last region (that region
+    then runs as a cop task of its own on the pool, beside the batch task on
+    the session's thread); the `tidb:` events of the trace, name -> [stats]."""
     import jax
     from jax.profiler import ProfileData
 
@@ -124,7 +126,9 @@ def profiled(served, tmp_path_factory):
     jax.profiler.start_trace(d, profiler_options=opts)
     try:
         assert tracing.profiling()
+        s.execute("INSERT INTO t VALUES (5000, 1, 1.50, 'A')")
         s.query(Q1.format(5))
+        s.execute("INSERT INTO t VALUES (5001, 1, 1.50, 'B')")
         s.query(Q6.format(3))
     finally:
         jax.profiler.stop_trace()
@@ -143,14 +147,18 @@ def profiled(served, tmp_path_factory):
 
 @pytest.mark.parametrize("name", ["cop.task"] + [f"exec.{p}" for p in PHASES])
 def test_profiler_gets_every_span_with_the_statements_id(profiled, name):
-    stmts = sorted({e["stmt"] for e in profiled["statement"]})
+    stmts = sorted({e["stmt"] for e in profiled["cop.task"]})  # the two reads; the writes have no cop task
     assert len(stmts) == 2 and all(isinstance(x, str) for x in stmts)
+    assert set(stmts) <= {e["stmt"] for e in profiled["statement"]}
     mine = profiled[name]
     by_stmt = {x: [e for e in mine if e.get("stmt") == x] for x in stmts}
     assert sum(len(v) for v in by_stmt.values()) == len(mine)  # none without an id, none with a third
-    n_tasks = {x: len([e for e in profiled["cop.task"] if e["stmt"] == x]) for x in stmts}
+    tasks = {x: [e for e in profiled["cop.task"] if e["stmt"] == x] for x in stmts}
     for x in stmts:
-        assert n_tasks[x] > 4 and len(by_stmt[x]) >= n_tasks[x]  # a span (bind: two) in every task
+        # the batch task and the written region's own; every region is in one of them
+        assert sorted(int(e.get("regions", 1)) for e in tasks[x]) == [1, sum(int(e.get("regions", 1)) for e in tasks[x]) - 1]
+        assert sum(int(e.get("regions", 1)) for e in tasks[x]) > 4
+        assert len(by_stmt[x]) >= len(tasks[x])  # a span (bind: two) in every task
 
 
 def test_profiler_spans_of_pool_threads_carry_it_too(profiled):
@@ -160,6 +168,9 @@ def test_profiler_spans_of_pool_threads_carry_it_too(profiled):
     for e in tasks:
         assert e["engine"] == "tpu" and int(e["cpu_us"]) >= 0 and int(e["queue_us"]) >= 0 and "region" in e
     assert {e["kernel"] for e in profiled["exec.dispatch"]} == {"cop_sel_agg_g1", "cop_sel_agg_g0"}
+    # a dispatch span says how many regions' programs it sent: the batch's many, the lone task's one
+    assert sorted(int(e["regions"]) for e in profiled["exec.dispatch"])[:2] == [1, 1]
+    assert sum(int(e["regions"]) for e in profiled["exec.dispatch"]) == sum(int(e.get("regions", 1)) for e in tasks)
     assert {e["cache"] for e in profiled["plan"]} <= {"hit", "miss"}
 
 

@@ -2,8 +2,17 @@
 
 Reference parity: pkg/store/copr/coprocessor.go (buildCopTasks :334 splits
 ranges by region; copIterator :684 runs a worker pool with keep-order
-channels; :87 CopClient.Send). Concurrency here is a thread pool — numpy and
-XLA release the GIL in their hot paths, so region tasks overlap for real.
+channels; :87 CopClient.Send) and batch_coprocessor.go (one task a store
+carrying many regions: ``CopClient``'s batch cop task). Concurrency here is a
+thread pool, and what it buys is bounded by the interpreter lock: a task of the
+``tpu`` engine is mostly Python (bind, input lookups, dispatch, decode), so
+region tasks on the pool queue for the lock and pay a switch interval (~5 ms)
+each time they let it go — at the dispatch, in ``device_get``, dropping the
+device result (PERF.md §5, PR 26: 18 ms of wall for 3.2 ms of CPU a task,
+eight in flight). The pool overlaps only what waits outside the interpreter:
+the device's result, numpy's larger kernels, a store RPC. That is why a
+statement's clean regions go to the engine as ONE task where the request
+allows it, and why more connections do not answer more statements.
 
 The worker pool is ONE lazily-built process-wide executor (ref: the
 reference's copIteratorWorker goroutines being cheap — spawning an OS thread
@@ -20,6 +29,7 @@ import threading
 import time
 from concurrent import futures
 from concurrent.futures import ThreadPoolExecutor
+from contextlib import contextmanager
 from dataclasses import dataclass
 from typing import Callable, Iterator, Optional
 
@@ -278,6 +288,10 @@ class CopTask:
     region: Region
     ranges: list[KeyRange]
     task_id: int
+    # of a region that left a batch task: what the chaos seam made of its first
+    # attempt there, where it fired once for the region already — the error it
+    # raised (the attempt raises it again), or True (it passed)
+    seam: object = None
 
 
 @dataclass
@@ -395,8 +409,40 @@ class CopResponse:
                 self._cancel()
 
 
+def _order_blind_partial(req: Request, dag: dagpb.DAGRequest) -> bool:
+    """A request whose per-region results the root merges whatever their order
+    and however they are grouped into tasks: for the ``tpu`` engine, a table
+    scan that ends in a PARTIAL aggregation, with no window, no descending scan
+    and no keep-order (the pushed-down fragments of the TPC-H scans). Only such
+    a request rides a batch cop task."""
+    ex = dag.executors
+    return (
+        req.store_type == StoreType.TPU
+        and not req.keep_order
+        and not req.desc
+        and len(ex) > 1
+        and ex[0].tp == dagpb.TABLE_SCAN
+        and not ex[0].desc
+        and ex[-1].tp in (dagpb.AGGREGATION, dagpb.STREAM_AGG)
+        and ex[-1].agg_mode == dagpb.AGG_PARTIAL
+        and not any(e.tp == dagpb.WINDOW for e in ex[1:])
+    )
+
+
 class CopClient:
-    """kv.Client for the embedded store (both engines)."""
+    """kv.Client for the embedded store (both engines).
+
+    A request is a task a region, run on the shared pool — or, where its
+    results are order-blind partial aggregates for the ``tpu`` engine
+    (``_order_blind_partial``), ONE batch task carrying every region (ref:
+    batch_coprocessor.go, one task a store carrying many regions): what does
+    not depend on the region — the bound DAG, the kernel lookup, the output
+    schema — is then done once, every region's program is dispatched before
+    the one fetch, and the interpreter lock changes hands a few times a
+    statement, not three times a region. The engine hands back the regions it
+    cannot serve that way (``tpu_engine._batch_path``); they, and any region
+    the chaos seam faults, run as tasks of their own beside the batch, under
+    the same re-split / degrade policy as ever."""
 
     def __init__(self, store: MemStore):
         self.store = store
@@ -421,12 +467,12 @@ class CopClient:
         # copIterator's Backoffer per copTask batch; worker threads share it)
         bo = Backoffer(budget_ms=2000)
 
-        def run_engine(store_type: StoreType, region: Region, ranges: list[KeyRange]) -> Chunk:
+        def seam(store_type: StoreType, region: Region) -> None:
             # chaos seam: tests fault exact (task, engine) pairs (N-shot /
             # scripted) without touching the engines themselves
             failpoint.inject("cop_task_engine", region.region_id, store_type)
-            return _engines()[store_type](self.store, dag, region, ranges, read_ts, warn=req.warn)
 
+        from tidb_tpu.utils import metrics as _m
         from tidb_tpu.utils.memory import QueryKilledError, QueryOOMError
 
         # sidecar timing baseline, cross-thread span parent and the
@@ -437,16 +483,59 @@ class CopClient:
         parent_span = tracer.current() if tracer is not None else None
         stmt = _tracing.current_stmt()
 
+        @contextmanager
+        def task_scope(det: _ed.CopExecDetails, label: str):
+            """One task's sidecar collection and, inside it, its ``cop.task``
+            span, which takes from the sidecar what the task came to."""
+            det.queue_ms = (time.perf_counter() - t_submit) * 1000.0
+            with _ed.collecting(det, tracer=tracer, stmt=stmt), _tracing.region(
+                "cop.task", parent=parent_span, label=label if tracer is not None else None,
+                region=det.region_id, queue_us=int(det.queue_ms * 1000.0),
+            ) as span:
+                cpu0 = time.thread_time() if span is not None else 0.0
+                yield
+                if span is not None:
+                    span.note(
+                        cpu_us=int((time.thread_time() - cpu0) * 1e6), h2d=det.h2d_bytes, d2h=det.d2h_bytes,
+                        engine=det.engine, regions=det.regions,
+                    )
+
+        def finish(det: _ed.CopExecDetails, t0: float, chunk: Chunk, path: str) -> None:
+            # processing = task wall minus its own backoff sleeps
+            det.proc_ms = max((time.perf_counter() - t0) * 1000.0 - det.backoff_ms, 0.0)
+            _m.COP_REGIONS.inc(det.regions, path=path)
+            ring = getattr(self.store, "cop_ring", None)
+            if ring is not None:
+                # per-store cop-digest ring (embedded fleet members only —
+                # attached by ShardedStore): the same per-TABLE digest the
+                # wire servers record, so the balancer's hot boost sees
+                # embedded and wire fleets identically
+                from tidb_tpu import config as _config
+
+                tid = dag.executors[0].table_id if dag.executors else 0
+                ring.record(
+                    f"cop table={tid} region={det.region_id}" + (f" regions={det.regions}" if det.regions != 1 else ""),
+                    det.proc_ms / 1000.0,
+                    len(chunk),
+                    user="store",
+                    slow_threshold_s=_config.current().store_slow_cop_ms / 1000.0,
+                    digest_val=f"cop:{tid}|cop table={tid}",
+                )
+
         def run(task: CopTask) -> CopResult:
             rid = task.region.region_id
             det = _ed.CopExecDetails(rid)
             t0 = time.perf_counter()
-            det.queue_ms = (t0 - t_submit) * 1000.0
-            with _ed.collecting(det, tracer=tracer, stmt=stmt), _tracing.region(
-                "cop.task", parent=parent_span, label=f"cop.r{rid}" if tracer is not None else None,
-                region=rid, queue_us=int(det.queue_ms * 1000.0),
-            ) as span:
-                cpu0 = time.thread_time() if span is not None else 0.0
+
+            def run_engine(store_type: StoreType, region: Region, ranges: list[KeyRange]) -> Chunk:
+                met, task.seam = task.seam, None  # the first attempt's alone
+                if isinstance(met, BaseException):
+                    raise met
+                if met is None:
+                    seam(store_type, region)
+                return _engines()[store_type](self.store, dag, region, ranges, read_ts, warn=req.warn)
+
+            with task_scope(det, f"cop.r{rid}"):
                 chunk = run_task_resilient(
                     bo,
                     run_engine,
@@ -465,45 +554,73 @@ class CopClient:
                     detail=det,
                     trace_id=tracer.trace_id if tracer is not None else None,
                 )
-                if span is not None:
-                    span.note(
-                        cpu_us=int((time.thread_time() - cpu0) * 1e6), h2d=det.h2d_bytes, d2h=det.d2h_bytes,
-                        engine=det.engine,
-                    )
-            # processing = task wall minus its own backoff sleeps
-            det.proc_ms = max((time.perf_counter() - t0) * 1000.0 - det.backoff_ms, 0.0)
-            ring = getattr(self.store, "cop_ring", None)
-            if ring is not None:
-                # per-store cop-digest ring (embedded fleet members only —
-                # attached by ShardedStore): the same per-TABLE digest the
-                # wire servers record, so the balancer's hot boost sees
-                # embedded and wire fleets identically
-                from tidb_tpu import config as _config
+            finish(det, t0, chunk, "single")
+            return CopResult(chunk, task.task_id, rid, det)
 
-                tid = dag.executors[0].table_id if dag.executors else 0
-                ring.record(
-                    f"cop table={tid} region={task.region.region_id}",
-                    det.proc_ms / 1000.0,
-                    len(chunk),
-                    user="store",
-                    slow_threshold_s=_config.current().store_slow_cop_ms / 1000.0,
-                    digest_val=f"cop:{tid}|cop table={tid}",
-                )
-            return CopResult(chunk, task.task_id, task.region.region_id, det)
+        def fan_out(ts: list[CopTask], window: int):
+            """→ (results in task order, cancel) of tasks run one after another
+            on this thread, or on the shared pool, at most ``window`` of THIS
+            request in flight there."""
+            if window == 1:
+                return (run(t) for t in ts), None
+            return windowed_fanout(shared_cop_pool(window), run, ts, window)
 
-        if concurrency == 1 or len(tasks) == 1:
-            def gen_serial():
+        if len(tasks) == 1 or not _order_blind_partial(req, dag):
+            # shared pool, windowed: at most ``concurrency`` tasks of THIS
+            # request occupy workers at once. Yielding in task order (not
+            # completion order) costs nothing — the reader gathers every result
+            # before returning — and keeps ORDER BY tie-breaks deterministic
+            # across runs and engines (a stable root sort preserves the concat
+            # order of equal keys, so completion-order concat would make ties
+            # racy)
+            return CopResponse(*fan_out(tasks, concurrency))
+
+        # the batch task runs on the requesting thread as the reader pulls;
+        # beside it, on the pool, a task for each region that left it
+        alone: list = []  # (results, cancel) of each hand-over, in the order they left
+
+        def leave(parts: list, met=True) -> None:
+            ts = [CopTask(region, ranges, len(tasks) + i, met) for i, (region, ranges) in enumerate(parts)]
+            # with a window of one they wait, unstarted, until the batch is through
+            alone.append(fan_out(ts, concurrency))
+
+        def run_batch() -> Optional[CopResult]:
+            det = _ed.CopExecDetails(tasks[0].region.region_id)
+            t0 = time.perf_counter()
+            with task_scope(det, f"cop.r{det.region_id}+{len(tasks) - 1}"):
+                batch = []
                 for t in tasks:
-                    yield run(t)
+                    try:  # the seam fires once a region, as on the other path
+                        seam(StoreType.TPU, t.region)
+                    except Exception as e:  # noqa: BLE001 — raised again, and judged, in the region's own task
+                        leave([(t.region, t.ranges)], e)
+                    else:
+                        batch.append((t.region, t.ranges))
+                chunk = None
+                if batch:
+                    chunk = _engines()[StoreType.TPU](
+                        self.store, dag, None, None, read_ts, warn=req.warn, batch=batch, leave=leave
+                    )
+                if chunk is None:
+                    det.regions = 0  # every region left: there is no result, the span says so
+            if chunk is None:
+                return None
+            finish(det, t0, chunk, "batched")
+            return CopResult(chunk, 0, det.region_id, det)
 
-            return CopResponse(gen_serial())
+        def cancel():
+            for _, c in alone:
+                if c is not None:
+                    c()
 
-        # shared pool, windowed: at most ``concurrency`` tasks of THIS
-        # request occupy workers at once. Yielding in task order (not
-        # completion order) costs nothing — the reader gathers every result
-        # before returning — and keeps ORDER BY tie-breaks deterministic
-        # across runs and engines (a stable root sort preserves the concat
-        # order of equal keys, so completion-order concat would make ties
-        # racy)
-        it, cancel = windowed_fanout(shared_cop_pool(concurrency), run, tasks, concurrency)
-        return CopResponse(it, cancel)
+        def gen():
+            try:
+                res = run_batch()
+                if res is not None:
+                    yield res
+                for results, _ in alone:
+                    yield from results
+            finally:
+                cancel()
+
+        return CopResponse(gen(), cancel)

@@ -410,6 +410,17 @@ class ColumnCache:
                 note(region.region_id, table_id, n, n * 8 * max(1, len(slots)))
         return base_delta
 
+    def head(self, region: Region, table_id: int, read_ts: int) -> Optional[RegionColumns]:
+        """The cached entry where it IS the region's head at ``read_ts``, else
+        None: get_split's first test alone, with nothing built, merged or read
+        from the store. A batch cop task sorts its regions by it, and those it
+        gives None run as tasks of their own."""
+        with self._mu:
+            entry = self._entries.get((region.region_id, table_id))
+        if entry is not None and entry.data_version == region.data_version and read_ts >= entry.built_ts:
+            return entry
+        return None
+
     def _get_split_once(self, key, region, table_id, schema, slots, read_ts):
         """One get_split attempt; None = a concurrent merge replaced the
         entry AFTER we read the change log (its prune may have erased the

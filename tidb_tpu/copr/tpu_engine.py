@@ -34,6 +34,7 @@ from __future__ import annotations
 import threading
 import time as _time
 from collections import OrderedDict
+from typing import NamedTuple
 
 import numpy as np
 
@@ -47,6 +48,7 @@ from tidb_tpu.kv.rowcodec import RowSchema
 from tidb_tpu.ops.dag_kernel import MAX_RANGES, get_kernel
 from tidb_tpu.types import FieldType, TypeKind
 from tidb_tpu.types.field_type import bigint_type
+from tidb_tpu.utils import eventlog as _ev
 from tidb_tpu.utils import execdetails as _ed
 from tidb_tpu.utils import metrics as _metrics
 from tidb_tpu.utils import tracing as _tracing
@@ -71,18 +73,21 @@ def _delta_cap() -> int:
 
 
 class _BinderView:
-    """Stats facade over base ⊕ delta for the binder: min/max (sort bounds,
-    MXU magnitude proofs, narrow-eval proofs) must cover delta values too,
-    or a fresh row outside the base envelope would break an exactness gate."""
+    """Stats facade over several column sources for the binder — a base and
+    its delta, or the entries of every region of a batch task: min/max (sort
+    bounds, MXU magnitude proofs, narrow-eval proofs) must cover every
+    source's values, or a row outside the first one's envelope would break an
+    exactness gate. A proof that holds on the union holds on each source."""
 
-    def __init__(self, base, delta):
-        self.base, self.delta = base, delta
-        self.n = base.n + delta.n
+    def __init__(self, *sources):
+        self.sources = sources
+        self.n = sum(s.n for s in sources)
+        self._minmax: dict = {}
 
     @property
     def handles(self):
         # only the endpoints are consumed (binder._col_stats min/max)
-        hs = [h for h in (self.base.handles, self.delta.handles) if len(h)]
+        hs = [s.handles for s in self.sources if len(s.handles)]
         if not hs:
             return np.empty(0, np.int64)
         return np.array(
@@ -90,11 +95,12 @@ class _BinderView:
         )
 
     def minmax(self, slot: int) -> tuple[int, int]:
-        mm = self.base.minmax(slot)
-        dm = self.delta.minmax(slot)
-        if dm is None:
-            return mm
-        return (min(mm[0], dm[0]), max(mm[1], dm[1]))
+        mm = self._minmax.get(slot)
+        if mm is None:
+            # a delta with no valid PUT value in the slot gives None
+            mms = [m for m in (s.minmax(slot) for s in self.sources) if m is not None]
+            mm = self._minmax[slot] = (min(m[0] for m in mms), max(m[1] for m in mms))
+        return mm
 
 
 def _n_blocks(n: int) -> int:
@@ -466,11 +472,17 @@ class _Phases:
             setattr(det, self._attr, getattr(det, self._attr) + (now - self._t0) * 1000.0)
 
 
-def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn=None):
+def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn=None,
+                *, batch=None, leave=None):
+    """One region's DAG → Chunk. With ``batch`` — ``[(region, ranges), ...]``,
+    ``region`` and ``ranges`` None — the batch cop task of copr/client.py: the
+    partial results of every region it can serve in one pass, as one Chunk
+    (None where it served none); the regions it cannot are handed to
+    ``leave([(region, ranges), ...])``, which runs them as tasks of their own."""
     det = _ed.current_cop()
     if det is None:
         try:
-            return _execute_dag_device(store, dag, region, ranges, read_ts, warn)
+            return _execute_dag_device(store, dag, region, ranges, read_ts, warn, batch, leave)
         except UnsupportedForDevice:
             # the planner's legality gate keeps most host-only shapes off this
             # engine; anything it misses (unbindable constants, unpackable
@@ -481,7 +493,7 @@ def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: 
     try:
         try:
             with _tracing.region("device-exec"):
-                return _execute_dag_device(store, dag, region, ranges, read_ts, warn)
+                return _execute_dag_device(store, dag, region, ranges, read_ts, warn, batch, leave)
         except UnsupportedForDevice:
             det.degraded = det.degraded or "unsupported-for-device"
             return host_execute_dag(store, dag, region, ranges, read_ts, warn)
@@ -498,12 +510,85 @@ def execute_dag(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: 
             _metrics.COP_DEVICE_SECONDS.observe(dev_ms / 1000.0)
 
 
-def _execute_dag_device(store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn=None):
+def _execute_dag_device(store, dag, region, ranges, read_ts, warn=None, batch=None, leave=None):
     ph = _Phases()
     try:
+        if batch is not None:
+            return _batch_path(ph, store, dag, batch, read_ts, warn, leave)
         return _device_path(ph, store, dag, region, ranges, read_ts, warn)
     finally:
         ph.end()
+
+
+class _Part(NamedTuple):
+    """One region's share of a task on the single-kernel path."""
+
+    entry: object  # colcache.RegionColumns, at most one block of rows
+    region: Region
+    rarr: np.ndarray  # the ranges, padded (_ranges_array)
+    delta: object  # colcache.DeltaOverlay or None
+
+
+def _ranges_array(ranges: list[KeyRange], table_id: int) -> np.ndarray:
+    """ranges → padded static array; rows outside any range are masked out."""
+    rarr = np.zeros((MAX_RANGES, 2), dtype=np.int64)
+    for i, kr in enumerate(ranges):
+        rarr[i] = tablecodec.range_to_handles(kr, table_id)
+    return rarr
+
+
+def _batch_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, batch: list, read_ts: int, warn, leave):
+    """The batch cop task: ``_device_path`` over many regions of one
+    order-blind partial-aggregation DAG (copr/client.py decides that), with
+    what does not depend on the region done once. Per region only the cheap
+    part: the cached head entry, the ranges, the full-scan proof. A region
+    that is not clean and small — no head entry in the cache (never read, or
+    written since: a pending delta), a slot still to decode, more than
+    MAX_RANGES ranges, more rows than one block, an entry that is not
+    complete — leaves before anything is bound and takes ``_device_path`` as
+    a task of its own, beside this one. So does every region if the batch
+    fails as a whole: each then meets the fault alone, under the client's
+    re-split / degrade policy. Partials stay per region; the root's final
+    aggregation merges them as it merges tasks."""
+    scan = dag.executors[0]
+    ph.to("bind")
+    schema = RowSchema(scan.storage_schema)
+    slots = [c.column_id for c in scan.columns if not c.is_handle]
+    cache = cache_for(store)
+    parts, kept, left = [], [], []
+    for region, ranges in batch:
+        part = None
+        try:
+            head = cache.head(region, scan.table_id, read_ts) if len(ranges) <= MAX_RANGES else None
+            if head is not None and all(s in head.cols for s in slots):
+                # the head's quick path: no build, no decode, no merge; it counts the read
+                entry, delta = cache.get_split(region, scan.table_id, schema, slots, read_ts)
+                if (delta is None or not delta.n) and entry.complete and entry.n <= _BLOCK:
+                    part = _Part(entry, region, _ranges_array(ranges, scan.table_id), None)
+        except Exception:  # noqa: BLE001 — the region's own task meets it again, under the client's policy
+            part = None
+        if part is None:
+            left.append((region, ranges))
+        else:
+            parts.append(part)
+            kept.append((region, ranges))
+    if left:
+        leave(left)
+    if not parts:
+        return None
+    try:
+        bound = Binder(cache, scan.table_id, scan.columns, _BinderView(*(p.entry for p in parts))).bind_dag(dag)
+        out = _exec_single(ph, store, dag, bound, scan, cache, parts, warn)
+    except Exception as e:  # noqa: BLE001 — as above, for all of them
+        lg = _ev.on(_ev.WARN)
+        if lg is not None:
+            lg.emit(_ev.WARN, "copr", "batch_fallback", regions=len(parts), cause=f"{type(e).__name__}: {e}")
+        leave(kept)
+        return None
+    det = _ed.current_cop()
+    if det is not None:
+        det.regions = len(parts)
+    return out
 
 
 def _device_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, region: Region, ranges: list[KeyRange], read_ts: int, warn):
@@ -545,11 +630,7 @@ def _device_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, region: Re
     binder_entry = entry if delta is None else _BinderView(entry, delta)
     binder = Binder(cache, scan.table_id, scan.columns, binder_entry)
     bound = binder.bind_dag(dag)
-
-    # ranges → padded static array; rows outside any range are masked out
-    rarr = np.zeros((MAX_RANGES, 2), dtype=np.int64)
-    for i, kr in enumerate(ranges):
-        rarr[i] = tablecodec.range_to_handles(kr, scan.table_id)
+    rarr = _ranges_array(ranges, scan.table_id)
 
     if has_window:
         _window_pack_guard(bound, entry.n)
@@ -569,7 +650,7 @@ def _device_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, region: Re
     )
     if entry.n > _BLOCK and not agg_complete:
         return _exec_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
-    return _exec_single(ph, store, dag, bound, scan, cache, entry, region, rarr, warn, delta)
+    return _exec_single(ph, store, dag, bound, scan, cache, [_Part(entry, region, rarr, delta)], warn)
 
 
 def _grown_cap(agg_cap: int, ngroups: int, ceiling: int) -> int:
@@ -607,63 +688,91 @@ def _single_device_inputs(store, scan, cache, entry, region, n_pad):
     return handles_pair[0], cols_dev
 
 
-def _exec_single(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None) -> Chunk:
-    """Small regions (≤ one block) or COMPLETE-mode aggs: one padded array,
-    one kernel invocation — the round-1 path, preserved verbatim."""
-    n_pad = bucket_size(max(entry.n, 1))
+def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=None) -> Chunk:
+    """Regions of at most one block each (or COMPLETE-mode aggs): one padded
+    array and one kernel invocation a region. A task is one region, or the
+    many of a batch (``_batch_path``; ``bound`` then holds for all of them):
+    every region's program is dispatched before the ONE fetch, and the
+    results decode into one Chunk, region after region."""
+    needs_agg = kernel_needs_agg(bound)
     ph.to("inputs")
-    handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad)
-    dcap = 0
-    dargs = ()
-    if delta is not None:
-        dcap = _delta_cap()
-        dh, dcols, dtomb = _delta_device_inputs(store, scan, cache, delta, region)
-        dargs = (dh, dcols, dtomb, _delta_counts(delta.n, 0, delta.n))
-    args = (handles_dev, tuple(cols_dev), _device_ranges(rarr), _device_nvalid(entry.n), *dargs)
-
-    ph.to("bind")
-    agg_cap = min(_DEFAULT_AGG_CAP, n_pad + dcap) if kernel_needs_agg(bound) else _DEFAULT_AGG_CAP
-    fs = _covers_all(rarr, entry, delta)
-    while True:
-        kernel = get_kernel(bound, n_pad, agg_cap, full_scan=fs, delta_cap=dcap)
-        buf, fbuf, count = _run_one(ph, kernel, args)
-        if int(buf[0, 1]) <= kernel.agg_cap:
-            break
-        if agg_cap >= n_pad + dcap:
-            # more groups than rows cannot happen; n_pad cap always fits
-            raise RuntimeError("aggregation group overflow beyond row count")
-        agg_cap = _grown_cap(agg_cap, int(buf[0, 1]), n_pad + dcap)
+    runs = []  # a region: [n_pad, full_scan, delta_cap, agg_cap, the program's arguments]
+    for entry, region, rarr, delta in parts:
+        n_pad = bucket_size(max(entry.n, 1))
+        handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad)
+        dcap = 0
+        dargs = ()
+        if delta is not None:
+            dcap = _delta_cap()
+            dh, dcols, dtomb = _delta_device_inputs(store, scan, cache, delta, region)
+            dargs = (dh, dcols, dtomb, _delta_counts(delta.n, 0, delta.n))
+        args = (handles_dev, tuple(cols_dev), _device_ranges(rarr), _device_nvalid(entry.n), *dargs)
+        agg_cap = min(_DEFAULT_AGG_CAP, n_pad + dcap) if needs_agg else _DEFAULT_AGG_CAP
+        runs.append([n_pad, _covers_all(rarr, entry, delta), dcap, agg_cap, args])
+    results: list = [None] * len(runs)
+    todo = list(range(len(runs)))
+    while todo:
         ph.to("bind")
-    return _decode_one(ph, buf, fbuf, count, kernel, dag, cache, scan, warn)
+        kernels: dict = {}  # one lookup (a fingerprint of the DAG) per distinct padded shape, not per region
+        calls = []
+        for i in todo:
+            n_pad, fs, dcap, agg_cap, args = runs[i]
+            key = (n_pad, fs, dcap, agg_cap)
+            if key not in kernels:
+                kernels[key] = get_kernel(bound, n_pad, agg_cap, full_scan=fs, delta_cap=dcap)
+            calls.append((kernels[key], args))
+        over = []
+        for i, (kernel, _), got in zip(todo, calls, _run_all(ph, calls)):
+            ngroups = int(got[0][0, 1])
+            if ngroups <= kernel.agg_cap:
+                results[i] = (*got, kernel)
+                continue
+            # an overflow re-runs that region alone, at the cap that holds it
+            n_pad, _, dcap, agg_cap, _ = runs[i]
+            if agg_cap >= n_pad + dcap:
+                # more groups than rows cannot happen; n_pad cap always fits
+                raise RuntimeError("aggregation group overflow beyond row count")
+            runs[i][3] = _grown_cap(agg_cap, ngroups, n_pad + dcap)
+            over.append(i)
+        todo = over
+    return _decode(ph, results, dag, cache, scan, warn)
 
 
-def _run_one(ph, kernel, args):
-    """Dispatch ``kernel`` once and fetch its result: (buf, fbuf, count) on
-    the host. ONE device→host round trip per task: device_get batches every
-    buffer of the packed result into a single transfer — two sequential
-    np.asarray calls would pay the round trip twice. Exception: large
+def _run_all(ph, calls: list) -> list:
+    """Dispatch every ``(kernel, args)`` without waiting, then fetch: one
+    ``(buf, fbuf, count)`` on the host for each. ONE device→host round trip
+    for them all: device_get batches every buffer of every packed result into
+    a single transfer — one call a result, or two sequential np.asarray
+    calls, would pay the round trip again and again. Exception: large
     rows-kind buffers spend a second tiny RTT on the meta row and transfer
     only the live slice (_probe_slice_rows)."""
     import jax
 
-    ph.to("dispatch", kernel=kernel.family)
-    packed = kernel.fn(*args)
+    ph.to("dispatch", kernel=calls[0][0].family, regions=len(calls))
+    packed = [kernel.fn(*args) for kernel, args in calls]
     ph.to("fetch")
-    if kernel.kind == "rows" and kernel.out_n > 65536:
-        _, (packed,) = _probe_slice_rows([packed], kernel)
-    buf, fbuf = jax.device_get(packed) if isinstance(packed, tuple) else (jax.device_get(packed), None)
-    # the device result ends HERE, inside the phase that waited for it, and
-    # under a span of its own: dropping it lets go of the interpreter lock, and
+    for i, (kernel, _) in enumerate(calls):
+        if kernel.kind == "rows" and kernel.out_n > 65536:
+            _, (packed[i],) = _probe_slice_rows([packed[i]], kernel)
+    fetched = jax.device_get(packed)
+    # the device results end HERE, inside the phase that waited for them, and
+    # under a span of its own: dropping them lets go of the interpreter lock, and
     # a task pays to get that back (at the return it was nobody's time)
     with _tracing.region("exec.release"):
         del packed
-    return buf, fbuf, int(buf[0, 0])
+    out = []
+    for got in fetched:
+        buf, fbuf = got if isinstance(got, tuple) else (got, None)
+        out.append((buf, fbuf, int(buf[0, 0])))
+    return out
 
 
-def _decode_one(ph, buf, fbuf, count, kernel, dag, cache, scan, warn) -> Chunk:
+def _decode(ph, results: list, dag, cache, scan, warn) -> Chunk:
+    """``[(buf, fbuf, count, kernel), ...]`` → one Chunk, in that order."""
     ph.to("decode")
-    _emit_kernel_warnings(buf, kernel, warn)
-    return _chunk_from_bufs(buf, fbuf, count, kernel, dag, cache, scan)
+    for buf, _, _, kernel in results:
+        _emit_kernel_warnings(buf, kernel, warn)
+    return _chunk_from_bufs(results, dag, cache, scan)
 
 
 def _exec_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None):
@@ -747,13 +856,8 @@ def _blocks_stacked(ph, run_block, nb: int, kernel, dag, cache, scan, warn=None)
         fetched = jax.device_get(gets)
         with _tracing.region("exec.release"):
             del packed, gets
-        ph.to("decode")
-        chunks = []
-        for cnt, got in zip(counts, fetched):
-            buf, fbuf = got if tup else (got, None)
-            _emit_kernel_warnings(buf, kernel, warn)
-            chunks.append(_chunk_from_bufs(buf, fbuf, cnt, kernel, dag, cache, scan))
-        return _concat_chunks(chunks)
+        results = [(*(got if tup else (got, None)), cnt, kernel) for cnt, got in zip(counts, fetched)]
+        return _decode(ph, results, dag, cache, scan, warn)
     stacked = jnp.stack([p[0] if tup else p for p in packed])
     if tup:
         stacked = (stacked, jnp.stack([p[1] for p in packed]))
@@ -765,14 +869,8 @@ def _blocks_stacked(ph, run_block, nb: int, kernel, dag, cache, scan, warn=None)
         del packed, stacked
     if kernel.kind == "agg" and any(int(b[0, 1]) > kernel.agg_cap for b in bi_all):
         return None
-    ph.to("decode")
-    chunks = []
-    for b in range(nb):
-        buf = bi_all[b]
-        fbuf = bf_all[b] if bf_all is not None else None
-        _emit_kernel_warnings(buf, kernel, warn)
-        chunks.append(_chunk_from_bufs(buf, fbuf, int(buf[0, 0]), kernel, dag, cache, scan))
-    return _concat_chunks(chunks)
+    results = [(bi_all[b], bf_all[b] if bf_all is not None else None, int(bi_all[b][0, 0]), kernel) for b in range(nb)]
+    return _decode(ph, results, dag, cache, scan, warn)
 
 
 def _exec_fused_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=None, delta=None):
@@ -802,14 +900,14 @@ def _exec_fused_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, 
     fs = _covers_all(rarr, entry, delta)
     while True:
         kernel = get_kernel(bound, _BLOCK, agg_cap, nb=nb, full_scan=fs, delta_cap=dcap)
-        buf, fbuf, count = _run_one(ph, kernel, args)
+        ((buf, fbuf, count),) = _run_all(ph, [(kernel, args)])
         if int(buf[0, 1]) <= kernel.agg_cap:
             break
         if agg_cap >= n_total + dcap:
             raise RuntimeError("aggregation group overflow beyond row count")
         agg_cap = _grown_cap(agg_cap, int(buf[0, 1]), n_total + dcap)
         ph.to("bind")
-    return _decode_one(ph, buf, fbuf, count, kernel, dag, cache, scan, warn)
+    return _decode(ph, [(buf, fbuf, count, kernel)], dag, cache, scan, warn)
 
 
 def _blocks_paged_limit(ph, run_block, nb: int, kernel, dag, cache, scan, warn=None):
@@ -818,60 +916,58 @@ def _blocks_paged_limit(ph, run_block, nb: int, kernel, dag, cache, scan, warn=N
     import jax
 
     limit = dag.executors[-1].limit
-    chunks = []
+    results = []
     got = 0
     window = 1
     bi = 0
-    # `not chunks` keeps LIMIT 0 well-formed: one empty-count block result
+    # `not results` keeps LIMIT 0 well-formed: one empty-count block result
     # still carries the output schema for chunk assembly
-    while bi < nb and (got < limit or not chunks):
+    while bi < nb and (got < limit or not results):
         batch = list(range(bi, min(bi + window, nb)))
         packed = [run_block(i) for i in batch]
         tup = isinstance(packed[0], tuple)
         ph.to("fetch")
         if kernel.out_n > 65536:  # LIMIT-last DAGs are always rows-kind
-            counts, packed = _probe_slice_rows(packed, kernel)
+            _, packed = _probe_slice_rows(packed, kernel)
         fetched = jax.device_get(packed)
         with _tracing.region("exec.release"):
             del packed
-        ph.to("decode")
         for got_b in fetched:
             buf, fbuf = got_b if tup else (got_b, None)
-            cnt = int(buf[0, 0])
-            _emit_kernel_warnings(buf, kernel, warn)
-            chunks.append(_chunk_from_bufs(buf, fbuf, cnt, kernel, dag, cache, scan))
-            got += cnt
+            results.append((buf, fbuf, int(buf[0, 0]), kernel))
+            got += results[-1][2]
         bi += len(batch)
         window = min(window * 2, 8)
-    return _concat_chunks(chunks)
+    return _decode(ph, results, dag, cache, scan, warn)
 
 
-def _concat_chunks(chunks: list[Chunk]) -> Chunk:
-    return chunks[0] if len(chunks) == 1 else Chunk.concat(chunks)
-
-
-def _chunk_from_bufs(buf, fbuf, count: int, kernel, dag, cache, scan) -> Chunk:
-    """Packed kernel buffers → Chunk (trim to count, re-attach dictionaries)."""
+def _chunk_from_bufs(results: list, dag, cache, scan) -> Chunk:
+    """Packed kernel buffers ``[(buf, fbuf, count, kernel), ...]`` — one a
+    region or block, all of one DAG — → ONE Chunk (each trimmed to its count,
+    one after another; dictionaries re-attached). The output schema and the
+    dictionary slots are worked out once for them all."""
     det = _ed.current_cop()
     if det is not None:
-        nb = int(getattr(buf, "nbytes", 0)) + (int(getattr(fbuf, "nbytes", 0)) if fbuf is not None else 0)
+        nb = sum(
+            int(getattr(buf, "nbytes", 0)) + (int(getattr(fbuf, "nbytes", 0)) if fbuf is not None else 0)
+            for buf, fbuf, _, _ in results
+        )
         det.d2h_bytes += nb
         _metrics.DEVICE_TRANSFER.inc(nb, dir="d2h")
-    outs = []
-    for (which, idx), vidx in zip(kernel.lane_loc, kernel.valid_loc):
-        data = fbuf[idx] if which == "f" else buf[idx]
-        valid = buf[vidx].astype(bool)
-        outs.append((data, valid))
-
     # assemble chunk: output schema comes from the *unbound* DAG (string
     # columns keep their dictionaries)
     out_fts = output_ftypes(dag)
     offsets = dag.output_offsets or list(range(len(out_fts)))
     cols = []
-    for (data, valid), off in zip(outs, offsets):
+    for lane, off in zip(range(len(results[0][3].lane_loc)), offsets):
+        datas, valids = [], []
+        for buf, fbuf, count, kernel in results:
+            which, idx = kernel.lane_loc[lane]
+            datas.append((fbuf if which == "f" else buf)[idx][:count])
+            valids.append(buf[kernel.valid_loc[lane]][:count])
+        d = datas[0] if len(datas) == 1 else np.concatenate(datas)
+        v = valids[0] if len(valids) == 1 else np.concatenate(valids)
         ft = out_fts[off]
-        d = np.asarray(data)[:count]
-        v = np.asarray(valid)[:count]
         dic = None
         if ft.kind == TypeKind.STRING:
             slot = string_slot_for_output(dag, off)
@@ -1007,9 +1103,7 @@ def device_probe_fn(store, dag, region, ranges, read_ts):
     cache = cache_for(store)
     entry = cache.get(region, scan.table_id, schema, slots, read_ts)
     bound = Binder(cache, scan.table_id, scan.columns, entry).bind_dag(dag)
-    rarr = np.zeros((MAX_RANGES, 2), dtype=np.int64)
-    for i, kr in enumerate(ranges):
-        rarr[i] = tablecodec.range_to_handles(kr, scan.table_id)
+    rarr = _ranges_array(ranges, scan.table_id)
     rj = jnp.asarray(rarr)
     cacheable = entry.complete
     agg_complete = any(

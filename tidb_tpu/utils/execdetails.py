@@ -57,11 +57,14 @@ class CopExecDetails:
         "host_ms", "compile_ms", "h2d_bytes", "d2h_bytes", "dev_cache_hits",
         "dev_cache_misses", "engine", "degraded", "retries", "backoff_ms",
         "resplits", "delta_rows", "merges", "keys_scanned", "bytes_scanned",
-        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms",
+        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions",
     )
 
     def __init__(self, region_id: int = -1, store: str = ""):
-        self.region_id = region_id
+        self.region_id = region_id  # of a batch task: its first region
+        # regions this task served: many for a batch task of the embedded client
+        # (copr/client.py); a store server serves one a request, so not on the wire
+        self.regions = 1
         self.store = store  # "" = embedded (local) store
         self.queue_ms = 0.0  # send-queue wait before a worker picked it up
         self.wire_ms = 0.0  # RPC wall minus store-side processing (remote)
@@ -175,7 +178,7 @@ class CopTasksSummary:
         "h2d_bytes", "d2h_bytes", "dev_cache_hits", "dev_cache_misses",
         "engines", "degraded", "retries", "backoff_ms", "resplits",
         "delta_rows", "merges", "keys_scanned", "bytes_scanned",
-        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms",
+        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions",
     )
 
     def __init__(self):
@@ -202,6 +205,7 @@ class CopTasksSummary:
         self.max_task_store = ""
         self.max_task_region = -1
         self.phases_ms = [0.0] * len(PHASES)
+        self.regions = 0  # regions the tasks served: above ``num`` where tasks were batches
 
     @property
     def num(self) -> int:
@@ -229,6 +233,7 @@ class CopTasksSummary:
         self.merges += d.merges
         self.keys_scanned += d.keys_scanned
         self.bytes_scanned += d.bytes_scanned
+        self.regions += d.regions
         for i, (_key, attr) in enumerate(_PHASE_PB):
             self.phases_ms[i] += getattr(d, attr)
         if d.proc_ms >= self.max_proc_ms:
@@ -254,6 +259,7 @@ class CopTasksSummary:
             f"engine: {eng}",
             f"backoff: {self.backoff_ms:.0f}ms",
             f"resplits: {self.resplits}",
+            f"regions: {self.regions}",
         ]
         if self.queue_ms:
             parts.append(f"queue: {self.queue_ms / n:.1f}ms")  # avg send-queue wait

@@ -231,6 +231,11 @@ COP_DEGRADED = REGISTRY.counter(
     "Cop tasks that fell back from the TPU engine to the host engine",
     ("reason",),
 )
+COP_REGIONS = REGISTRY.counter(
+    "tidb_tpu_cop_regions_total",
+    "Regions served by embedded cop tasks: batched = inside a many-region task, single = a task of their own",
+    ("path",),
+)
 STORE_FAILOVER = REGISTRY.counter(
     "tidb_tpu_store_failover_total",
     "Sharded-fleet reads/authority calls served by a non-primary replica",
