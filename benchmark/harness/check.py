@@ -51,15 +51,28 @@ class Answers:
         key = (template, pool, prefix)
         if key not in self._rows:
             tpl = self.mix.templates[template]
-            ref, drawn = tpl.ref, tpl.drawn[pool]
-            states = self._states.setdefault((template, pool), [])
-            if not states:
-                states.append(ref.state(self.columns[ref.TABLE], drawn, control=self.control))
-            while len(states) <= prefix:
-                step = ref.state(self.written[len(states) - 1][ref.TABLE], drawn, control=self.control)
-                states.append(merge_states(states[-1], step))
-            self._rows[key] = norm(ref.rows(states[prefix]))
+            self._rows[key] = norm(tpl.ref.rows(self._state(template, pool, prefix)))
         return self._rows[key]
+
+    def _state(self, template: str, pool: int, prefix: int):
+        tpl = self.mix.templates[template]
+        ref, drawn = tpl.ref, tpl.drawn[pool]
+        states = self._states.setdefault((template, pool), [])
+        if not states:
+            states.append(ref.state(tpl.ref_columns(self.columns), drawn, control=self.control))
+        while len(states) <= prefix:  # only one-table templates stand beside a writer (traffic.Mix)
+            step = ref.state(tpl.ref_columns(self.written[len(states) - 1]), drawn, control=self.control)
+            states.append(merge_states(states[-1], step))
+        return states[prefix]
+
+    def same(self, got: list, template: str, pool: int, prefix: int = 0) -> bool:
+        """Is ``got`` (normed rows) this statement's answer? Row for row
+        against `rows`, unless the reference judges for itself (`same(got,
+        state)`: a statement whose text leaves the order of tied rows open)."""
+        ref = self.mix.templates[template].ref
+        if hasattr(ref, "same"):
+            return ref.same(got, self._state(template, pool, prefix))
+        return same_rows(got, self.rows(template, pool, prefix))
 
 
 def prefix_bounds(s: dict, write_log: list[dict]) -> tuple[int, int]:
@@ -71,26 +84,43 @@ def prefix_bounds(s: dict, write_log: list[dict]) -> tuple[int, int]:
     return lo, max(lo, hi)
 
 
-def judge(statements, cop_by_stmt, answers: Answers, write_log: list[dict], config: dict) -> dict:
+def on_device(tasks: list[dict], gathers: list[dict], answered_by, chips: int) -> bool:
+    """A statement ran on the device iff (a) every cop task of it was answered
+    by the `tpu` engine, undegraded; (b) every MPP gather of it ran on exactly
+    the cell's ``chips`` devices of the local mesh and did not raise (a gather
+    that gives up, MPPRetryExhausted, leaves the statement to the host
+    executor); (c) it shows at least one of either; (d) where its template says
+    `"answered_by": "mpp"`, at least one gather: readers that were `tpu` cop
+    tasks under a join, aggregate and TopN in the host executor are not it."""
+    if any(t["engine"] != "tpu" or t["degraded"] for t in tasks):
+        return False
+    if any(g["raised"] is not None or g["store"] != "" or g["ndev"] != chips for g in gathers):
+        return False
+    if not tasks and not gathers:
+        return False
+    return bool(gathers) or answered_by != "mpp"
+
+
+def judge(statements, cop_by_stmt, mpp_by_stmt, answers: Answers, write_log: list[dict], config: dict, chips: int) -> dict:
     """name -> {"value", "limit"}; `correct` is every value within its limit."""
     writes = bool(config.get("writes"))
     wrong = stale = failed = off_device = no_delta = 0
-    for s, cops in zip(statements, cop_by_stmt):
+    for s, cops, gathers in zip(statements, cop_by_stmt, mpp_by_stmt):
         if s["error"] is not None:
             failed += 1
             continue
         got = norm(s["rows"])
         if writes:
             lo, hi = prefix_bounds(s, write_log)
-            if not any(same_rows(got, answers.rows(s["template"], s["pool"], k)) for k in range(lo, hi + 1)):
-                if any(same_rows(got, answers.rows(s["template"], s["pool"], k)) for k in range(0, lo)):
+            if not any(answers.same(got, s["template"], s["pool"], k) for k in range(lo, hi + 1)):
+                if any(answers.same(got, s["template"], s["pool"], k) for k in range(0, lo)):
                     stale += 1
                 else:
                     wrong += 1
-        elif not same_rows(got, answers.rows(s["template"], s["pool"])):
+        elif not answers.same(got, s["template"], s["pool"]):
             wrong += 1
         tasks = [t for c in cops for t in c["tasks"]]
-        if not tasks or any(t["engine"] != "tpu" or t["degraded"] for t in tasks):
+        if not on_device(tasks, gathers, answers.mix.templates[s["template"]].answered_by, chips):
             off_device += 1
         # through the delta layer: a task carried a delta as its operand, or (the delta
         # past its capacity) folded it into the base on the way, as the program does
@@ -109,7 +139,7 @@ def judge(statements, cop_by_stmt, answers: Answers, write_log: list[dict], conf
     return out
 
 
-def controls(statements, cop_by_stmt, answers: Answers, write_log: list[dict], config: dict) -> dict:
+def controls(statements, cop_by_stmt, mpp_by_stmt, answers: Answers, write_log: list[dict], config: dict, chips: int) -> dict:
     """The controls, each put in the program's place and judged like it:
     `float32`, the reference computed in float32 (breaks "exact answers");
     `stale`, the exact reference without the last transaction acknowledged
@@ -123,7 +153,7 @@ def controls(statements, cop_by_stmt, answers: Answers, write_log: list[dict], c
             lo = prefix_bounds(s, write_log)[0] if write_log else 0
             src, k = (low, lo) if name == "float32" else (answers, max(lo - 1, 0))
             stood_in.append(dict(s, rows=src.rows(s["template"], s["pool"], k), error=None))
-        verdict = judge(stood_in, cop_by_stmt, answers, write_log, config)
+        verdict = judge(stood_in, cop_by_stmt, mpp_by_stmt, answers, write_log, config, chips)
         out[name] = {k: v["value"] for k, v in verdict.items() if k.startswith("answers")}
         out[name]["correct"] = all(v["value"] <= v["limit"] for v in verdict.values())
     return out

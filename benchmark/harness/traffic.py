@@ -4,7 +4,11 @@ each repeats (client k starts k places into it), think time, and the volume
 of a refresh stream beside them. Templates are data too (`queries/<name>.json`:
 text and the ranges of its substitution parameters); what a template MEANS
 (how drawn parameters become literals, and its answer) is its reference's,
-`reference/<name>.py`.
+`reference/<name>.py`. A template names what it reads in one of two forms:
+`table` + `reads` (one table; its reference gives `TABLE` and gets that
+table's columns) or `tables` (`{table: [columns]}`; its reference gives
+`TABLES` and gets `{table: {column: array}}`). `Template.tables` and
+`Template.ref_columns` are the one place that knows both.
 
 Closed loops: a client sends its next statement when the last one's final row
 has arrived. A template's parameter sets are fixed in its file (the program
@@ -47,6 +51,27 @@ class Template:
         self.texts = [self.spec["sql"].format(**self.ref.bind(d)) for d in self.drawn]
         # one text that is the same for every seed (each literal is a kernel of its own)
         self.first_text = self.spec["sql"].format(**self.ref.bind(fixed[0]))
+        # what it reads, {table: [columns]}, from either form of the file
+        self.joined = hasattr(self.ref, "TABLES")
+        self.tables: dict[str, list[str]] = (
+            {t: list(cs) for t, cs in self.spec["tables"].items()} if "tables" in self.spec
+            else {self.spec["table"]: list(self.spec["reads"])}
+        )
+        ref_tables = list(self.ref.TABLES) if self.joined else [self.ref.TABLE]
+        if sorted(ref_tables) != sorted(self.tables) or self.joined != ("tables" in self.spec):
+            raise ValueError(f"queries/{name}.json reads {sorted(self.tables)} but reference/{name}.py is over {sorted(ref_tables)}"
+                             " (`tables` goes with TABLES, `table` + `reads` with TABLE)")
+        # "mpp": every statement must show an MPP gather (check.judge); absent: cop tasks and/or gathers
+        self.answered_by = self.spec.get("answered_by")
+        if self.answered_by not in (None, "mpp"):
+            raise ValueError(f"queries/{name}.json: answered_by is {self.answered_by!r}; only \"mpp\" is known")
+
+    def ref_columns(self, columns: dict) -> dict:
+        """Of ``columns`` ({table: {column: array}}), what the reference's
+        `state` takes: one table's columns, or the tables' by name."""
+        if self.joined:
+            return {t: columns[t] for t in self.ref.TABLES}
+        return columns[self.ref.TABLE]
 
 
 class Mix:
@@ -58,6 +83,12 @@ class Mix:
         self.cycle = list(self.spec["cycle"])
         self.templates = {t: Template(t, seed, i) for i, t in enumerate(sorted(set(self.cycle)))}
         self.writer = self.spec.get("writer")
+        joined = sorted(t for t, tpl in self.templates.items() if tpl.joined)
+        if self.writer and joined:
+            # a join's state is not a sum over row sets (`merge_states`), so it cannot
+            # be brought up to date one acknowledged transaction at a time
+            raise ValueError(f"traffic {name}: templates over several tables ({', '.join(joined)}) beside a writer are not"
+                             " implemented: the reference cannot add a refresh stream's rows to a join's answer")
 
     def writes_per_statement(self, scale_factor: float) -> float:
         """The refresh stream's volume: one refresh function of

@@ -1,13 +1,25 @@
 """kernels: the least time the chip could take for the traced statements over
 the time its operations took. Least time = bytes / peak HBM bytes/s; bytes =
-rows of the table x the narrowest whole-byte widths that hold the read
-columns' spec domains (`domains.json`). Bound by memory: Q1 and Q6 do a
-handful of operations per byte. The count is the data's, not the kernel's, so
-the share cannot pass 100%."""
+the sum over the tables a statement reads of rows x the narrowest whole-byte
+widths that hold the read columns' spec domains (`domains.json`). Bound by
+memory: Q1 and Q6 do a handful of operations per byte, and a join reads each
+side at least once. The count is the data's, not the kernel's, so the share
+cannot pass 100%. A column `domains.json` lacks is an error, never 0 bytes."""
 import json
 import os
 
 UNIT = "%"
+
+
+def least_bytes(mix, rows: dict, statements: list, domains: dict) -> int:
+    """The bytes ``statements`` must read at the least, by their templates."""
+    per_template = {}
+    for name, tpl in mix.templates.items():
+        missing = [f"{t}.{c}" for t, cs in tpl.tables.items() for c in cs if c not in domains.get(t, {})]
+        if missing:
+            raise KeyError(f"domains.json has no width for {', '.join(missing)} (read by queries/{name}.json)")
+        per_template[name] = sum(rows[t] * sum(domains[t][c]["bytes"] for c in cs) for t, cs in tpl.tables.items())
+    return sum(per_template[s["template"]] for s in statements)
 
 
 def read(ctx):
@@ -24,8 +36,4 @@ def read(ctx):
     busy = red.busy_s(lo, hi)
     if busy <= 0:
         return None
-    least_bytes = 0
-    for s in ctx.statements:
-        spec = ctx.mix.templates[s["template"]].spec
-        least_bytes += ctx.rows[spec["table"]] * sum(domains[spec["table"]][c]["bytes"] for c in spec["reads"])
-    return 100.0 * (least_bytes / peaks[ctx.device_kind]["hbm_bytes_per_s"]) / busy
+    return 100.0 * (least_bytes(ctx.mix, ctx.rows, ctx.statements, domains) / peaks[ctx.device_kind]["hbm_bytes_per_s"]) / busy

@@ -9,7 +9,10 @@ connect the clients over TCP, run every text of the cell once on every
 connection (this compiles, or loads from the persistent cache, every kernel
 the window will use). Then the window: closed loops for `--seconds`. Then, the
 program's state released, the plain reference answers every statement of the
-window again and the comparison decides `correct`.
+window again and the comparison decides `correct`: every answer right, and
+every statement on the cell's devices by the evidence the program hands out
+itself (each cop task's ExecDetails, each MPP gather's MPPExecDetails;
+`harness/spans.py` records them, `harness/check.on_device` is the rule).
 
 The last line of stdout is the result. `--trace 0` prints the cell's
 end-to-end metrics, `--trace 1` its per-layer metrics, read from the
@@ -86,11 +89,11 @@ def read_metrics(folder: str, names: list[str], ctx) -> dict:
     return out
 
 
-def attach_cop(statements: list[dict], cop: list[dict], thread_of_client: dict) -> list[list[dict]]:
-    """The cop spans of each statement: those of its connection's server
-    thread that lie inside the statement's own interval."""
+def attach(statements: list[dict], records: list[dict], thread_of_client: dict) -> list[list[dict]]:
+    """The cop (or mpp) records of each statement: those of its connection's
+    server thread that lie inside the statement's own interval."""
     by_thread: dict[int, list[dict]] = {}
-    for c in cop:
+    for c in records:
         by_thread.setdefault(c["thread"], []).append(c)
     for spans in by_thread.values():
         spans.sort(key=lambda c: c["t0"])
@@ -173,7 +176,8 @@ def run(args) -> int:
     # the reference keeps only the columns some template of this cell reads
     read_cols: dict[str, set] = {}
     for tpl in mix.templates.values():
-        read_cols.setdefault(tpl.spec["table"], set()).update(tpl.spec["reads"])
+        for t, cs in tpl.tables.items():
+            read_cols.setdefault(t, set()).update(cs)
     columns = {
         t: {c: col for c, col in zip(gen.COLUMNS[t], tables[t]) if c in read_cols[t]} for t in read_cols
     }
@@ -214,8 +218,8 @@ def run(args) -> int:
         for tpl in mix.templates.values():
             for text in tpl.texts:
                 c.query(text)
-        cop = rec.drain()
-        thread_of_client[k] = cop[-1]["thread"] if cop else None
+        cop, mpp = rec.drain()  # a statement shows cop tasks, gathers or both: either names the thread
+        thread_of_client[k] = (cop or mpp)[-1]["thread"] if cop or mpp else None
         log(f"client {k} warmed ({compiles['n']} compiles so far, {compiles['s']:.1f}s)")
     for c in clients:  # a second pass: nothing may be left to compile or to cache
         for tpl in mix.templates.values():
@@ -247,7 +251,10 @@ def run(args) -> int:
     log(f"{len(db.store.regions())} regions at the close")
     stats = [d.memory_stats() or {} for d in devs[: cell["chips"]]]
     memory_peak = max((s.get("peak_bytes_in_use", 0) for s in stats), default=0)
-    cop = rec.drain()
+    cop, mpp = rec.drain()
+    log(f"{len(cop)} cop spans, {len(mpp)} gathers (ndev {sorted({g['ndev'] for g in mpp if g['raised'] is None})}, "
+        f"{sum(g['compiles'] or 0 for g in mpp)} programs built, {sum(1 for g in mpp if g['retries'])} re-planned, "
+        f"{sum(1 for g in mpp if g['raised'])} gave up)")
     write_log = writer.log if writer else []
     for c in clients + ([writer.conn] if writer else []):
         c.close()
@@ -257,9 +264,10 @@ def run(args) -> int:
 
     # -- correct? -----------------------------------------------------------------
     written = [t["rows"] for t in (writer.transactions[: len(write_log)] if writer else [])]
-    cop_by_stmt = attach_cop(statements, cop, thread_of_client)
+    cop_by_stmt = attach(statements, cop, thread_of_client)
+    mpp_by_stmt = attach(statements, mpp, thread_of_client)
     answers = check.Answers(mix, columns, written)
-    checks = check.judge(statements, cop_by_stmt, answers, write_log, config)
+    checks = check.judge(statements, cop_by_stmt, mpp_by_stmt, answers, write_log, config, cell["chips"])
     if writer and writer.errors:
         log(f"writer errors: {writer.errors[:3]}")
     for s in statements:
@@ -268,13 +276,16 @@ def run(args) -> int:
             break
     correct = all(c["value"] <= c["limit"] for c in checks.values())
     log("judged")
-    controls = check.controls(statements, cop_by_stmt, answers, write_log, config) if args.control else None
+    controls = (check.controls(statements, cop_by_stmt, mpp_by_stmt, answers, write_log, config, cell["chips"])
+                if args.control else None)
 
     # -- metrics ------------------------------------------------------------------
-    ok = [s for s in statements if s["error"] is None]
+    kept = [i for i, s in enumerate(statements) if s["error"] is None]
+    ok = [statements[i] for i in kept]
     ctx = types.SimpleNamespace(  # what a metric reader may read
-        cell=cell, config=config, mix=mix, rows=rows, statements=ok, cop_by_stmt=[c for s, c in zip(statements, cop_by_stmt) if s["error"] is None],
-        cop=cop, window=(t0, t1), window_s=t1 - t0, setup_s=setup_s,
+        cell=cell, config=config, mix=mix, rows=rows, statements=ok,
+        cop_by_stmt=[cop_by_stmt[i] for i in kept], mpp_by_stmt=[mpp_by_stmt[i] for i in kept], cop=cop, mpp=mpp,
+        window=(t0, t1), window_s=t1 - t0, setup_s=setup_s,
         write_log=[w for w in write_log[setup_written:] if not w.get("failed")],
         compiles_in_window=compiles_in_window, trace=None, trace_window=None, device_kind=devs[0].device_kind, platform=devs[0].platform, here=HERE,
     )
@@ -295,7 +306,7 @@ def run(args) -> int:
         breakdown = {
             "device_ops": red.top_ops(w_lo, w_hi, 10),
             "idle_gaps": red.idle_by_span(
-                w_lo, w_hi, [("exec", "device_exec"), ("cop", "cop"), ("stmt", "frontend"), ("writer", "writer")],
+                w_lo, w_hi, [("exec", "device_exec"), ("cop", "cop"), ("mpp", "mpp_gather"), ("stmt", "frontend"), ("writer", "writer")],
                 "between-statements")[:10],
         }
         log(f"trace reduced: {path} lines {sorted(set(red.lines_seen))[:12]}")

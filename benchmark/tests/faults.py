@@ -17,6 +17,11 @@ client: the harness must see `correct` come out false for each.
                   quiet fallback would do): answers right, device not used
   stale_snapshot  every cop request reads at the snapshot of the request before
                   it: writes acknowledged in between are missing from answers
+  mpp_fewer_devices  every MPP gather is held to one device fewer than the
+                  cell's `chips` (`parallel/mesh.FORCE_NDEV`). With one chip
+                  that leaves none: the gather gives up (MPPRetryExhausted) and
+                  the session answers from the host executor. Answers right,
+                  the join not on the cell's devices
 """
 
 import os
@@ -27,7 +32,7 @@ BENCH = os.path.dirname(HERE)
 sys.path[:0] = [os.path.dirname(BENCH), BENCH]
 
 
-def plant(fault: str) -> None:
+def plant(fault: str, chips: int = 1) -> None:
     from tidb_tpu.copr import client as cop_client
     from tidb_tpu.copr import host_engine
     from tidb_tpu.kv.kv import StoreType
@@ -71,6 +76,10 @@ def plant(fault: str) -> None:
             return real_send(self, req)
 
         cop_client.CopClient.send = send
+    elif fault == "mpp_fewer_devices":
+        from tidb_tpu.parallel import mesh
+
+        mesh.FORCE_NDEV = chips - 1
     elif fault != "none":
         raise SystemExit(f"unknown fault {fault!r}")
 
@@ -82,5 +91,5 @@ if __name__ == "__main__":
         os.environ["JAX_PLATFORMS"] = "cpu"
     # as run.py does, before the program is imported: it reads both at import
     os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(run.CACHE, "xla"))
-    plant(sys.argv[1])
+    plant(sys.argv[1], run.find_cell(run.parse_args(sys.argv[2:]).workload)[1]["chips"])
     sys.exit(run.main(sys.argv[2:]))

@@ -12,6 +12,7 @@ import subprocess
 import sys
 
 import pytest
+import test_join_cell_is_files_only as join_cell
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 STATIC = ["--workload", "tpch_sf2.q1q6_1c", "--scale", "0.02", "--seconds", "2"]
@@ -67,3 +68,20 @@ def test_htap_stale_snapshot_comes_out_not_correct():
     assert line["correct"] is False
     assert line["checks"]["answers_stale"]["value"] >= 1
     assert line["checks"]["answers_wrong"]["value"] == 0
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_gathers_on_fewer_devices_than_the_cell_asks_for_come_out_not_on_device(tmp_path, chips):
+    """No accepted cell has a gather, so the fault runs in the copy with the
+    two-table join cell of `test_join_cell_is_files_only.py`, on as many CPU
+    devices as the cell's `chips`. One chip: none is left, the gather gives up
+    and the host executor answers. Four: the gather runs on three."""
+    root = tmp_path / "checkout"
+    join_cell.build(root, scale=0.02, chips=chips)
+    if chips > 1:  # the sound drive first: a gather over the cell's four devices is on the device
+        line = join_cell.result(join_cell.drive(root, "tiny_join.q3ol_1c", 0, devices=chips))
+        assert line["correct"] is True and line["device"]["count"] == chips and line["attempted"] >= 3
+    line = join_cell.result(join_cell.drive(root, "tiny_join.q3ol_1c", 0, fault="mpp_fewer_devices", devices=chips))
+    assert line["correct"] is False and line["attempted"] >= 3
+    assert line["checks"]["not_on_device"]["value"] == line["attempted"]
+    assert line["checks"]["answers_wrong"]["value"] == 0 and line["checks"]["statements_failed"]["value"] == 0
