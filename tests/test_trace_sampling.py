@@ -258,7 +258,17 @@ def mpp_db():
     return db, s
 
 
-def test_mpp_per_shard_breakdown(mpp_db):
+@pytest.fixture
+def shard_probes(monkeypatch):
+    """The straggler probes are off as shipped (a program with a host callback
+    does not persist in the compile cache): a test that reads the per-shard
+    breakdown turns them on; the ``mpp_shard_slow`` failpoint does by itself."""
+    from tidb_tpu.parallel import gather
+
+    monkeypatch.setattr(gather, "PROBES_ENABLED", True)
+
+
+def test_mpp_per_shard_breakdown(mpp_db, shard_probes):
     """Every MPP gather records one [shard, ms, rows, bytes] row per mesh
     shard, rendered into the mpp_task line and fed to MPP_SHARD_SECONDS."""
     from tidb_tpu.utils import metrics as _m
@@ -314,7 +324,7 @@ def test_mpp_straggler_named_from_explain_analyze(mpp_db):
     assert by_id[victim] >= max(others) + 200.0, by_id
 
 
-def test_mpp_remote_dispatch_ships_shard_breakdown():
+def test_mpp_remote_dispatch_ships_shard_breakdown(shard_probes):
     """Remote MPP: the server's shard probes travel home in the exec
     sidecar, so the dispatching SQL layer renders the same straggler line."""
     import numpy as np

@@ -773,14 +773,16 @@ def bench_shard_probe_overhead() -> float:
             best = min(best, (_t.perf_counter() - t0) * 1000)
         return best
 
+    shipped = gather.PROBES_ENABLED  # off since PR 29
     try:
+        gather.PROBES_ENABLED = True
         s.query(q)  # warm: compile the probed variant
         with_probes = best_of(5)
         gather.PROBES_ENABLED = False
         s.query(q)  # warm: compile the probe-free variant
         without = best_of(5)
     finally:
-        gather.PROBES_ENABLED = True
+        gather.PROBES_ENABLED = shipped
     return max(with_probes - without, 0.0)
 
 

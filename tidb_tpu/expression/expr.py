@@ -82,6 +82,23 @@ class Constant(Expression):
 
 
 @dataclass
+class Operand(Expression):
+    """A literal lifted out of a compiled program: its value enters the
+    program as run-time operand ``slot`` (``EvalBatch.operands``), already in
+    the physical representation of ``ftype``, so statements that differ only
+    in such literals share one program (parallel/gather.py lifts them)."""
+
+    slot: int
+    ftype: FieldType
+
+    def to_pb(self) -> dict:
+        return {"tp": "operand", "slot": self.slot, "ft": _ft_pb(self.ftype)}
+
+    def __repr__(self):
+        return f"?{self.slot}"
+
+
+@dataclass
 class ScalarFunc(Expression):
     sig: str
     args: list[Expression]
@@ -214,6 +231,7 @@ class EvalBatch:
     dicts: list[Optional[Dictionary]]
     n: int
     warn: Optional[object] = None
+    operands: Optional[Sequence] = None  # what each `Operand` slot holds
 
     @staticmethod
     def from_chunk(chunk, warn=None) -> "EvalBatch":
@@ -255,6 +273,8 @@ def eval_expr(expr: Expression, batch: EvalBatch, xp=np):
     if isinstance(expr, ColumnRef):
         d, v = batch.cols[expr.index]
         return d, v, batch.dicts[expr.index]
+    if isinstance(expr, Operand):
+        return batch.operands[expr.slot], None, None
     if isinstance(expr, Constant):
         pv, valid = _const_physical(expr, xp)
         if isinstance(pv, bytes):

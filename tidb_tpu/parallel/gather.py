@@ -28,6 +28,8 @@ from tidb_tpu.expression.expr import (
     Constant,
     EvalBatch,
     Expression,
+    Operand,
+    ScalarFunc,
     can_push_down,
     eval_expr,
     expr_from_pb,
@@ -75,12 +77,21 @@ _MPP_CACHE_MU = _threading.Lock()
 # cache (_MPP_FN_CACHE) keeps working across queries
 _SHARD_OBS: dict = {"t0": 0.0, "sink": None}
 
-# straggler-probe switch: False compiles probe-FREE fragment programs (the
-# jax.debug.callback never enters the jaxpr), so the host-callback tax is
-# measurable as on-vs-off latency — benchdaily's shard_probe_overhead_ms
-# lane and the driver's multichip dryrun both flip this. Part of the
-# compiled-program cache key, so the two variants coexist.
-PROBES_ENABLED = True
+# straggler-probe switch: off as shipped, so the fragment program holds no
+# host callback (the jax.debug.callback never enters the jaxpr) and its
+# executable persists in the compile cache — with one, every process compiled
+# every program again (12 compiles, 652 s of a 1,084 s set-up on a v5e;
+# builder's chip run, PR 28). A test, benchdaily's shard_probe_overhead_ms
+# lane or the multichip dryrun turn it on, and so does an enabled
+# ``mpp_shard_slow`` failpoint. Part of the compiled-program cache key, so the
+# two variants coexist.
+PROBES_ENABLED = False
+
+
+def _probes_on() -> bool:
+    from tidb_tpu.utils import failpoint
+
+    return PROBES_ENABLED or failpoint.is_enabled("mpp_shard_slow")
 
 
 def _shard_probe(idx, rows, xbytes):
@@ -259,6 +270,28 @@ class PhysMPPGather(PhysicalPlan):
         return self.joins[0].exchange if self.joins else "hash"
 
     @property
+    def arm_folds(self) -> list[bool]:
+        """Per join: the fragment folds it into the build side of the join
+        before it, first (a unique inner join whose probe keys all lie in
+        that build side — a snowflake arm). Decided here, once: the program
+        reads it as ``DistJoinSpec.arm``, the by-slot aggregate and EXPLAIN
+        read this."""
+        out = [False] * len(self.joins)
+        lo = len(self.readers[0].schema)
+        for ji in range(1, len(self.joins)):
+            before, join = self.joins[ji - 1], self.joins[ji]
+            hi = lo + (len(self.readers[ji].schema) if before.kind in ("inner", "left", "right") else 0)
+            out[ji] = (
+                join.kind == "inner"
+                and join.unique
+                and before.kind == "inner"
+                and not join.other
+                and all(lo <= lp < hi for lp, _ in join.eq)
+            )
+            lo = hi
+        return out
+
+    @property
     def fragments(self) -> list[str]:
         out = []
         fi = 1
@@ -293,15 +326,110 @@ class PhysMPPGather(PhysicalPlan):
 
 
 def _right_side_unique(reader: PhysTableReader, key_slots: list[int]) -> bool:
-    t = reader.table
-    if t.pk_is_handle and key_slots == [t.pk_offset]:
-        return True
-    for idx in t.indexes:
-        if idx.state != "public":
-            continue  # a mid-DDL unique index hasn't proven uniqueness yet
-        if (idx.unique or idx.primary) and sorted(idx.column_offsets) == sorted(key_slots):
-            return True
-    return False
+    from tidb_tpu.planner.optimizer import table_unique_on
+
+    return table_unique_on(reader.table, key_slots)
+
+
+_LIFTED = ("eq", "ne", "lt", "le", "gt", "ge")
+
+
+def _lift_literals(cond_lists: list) -> tuple[list, list]:
+    """The readers' bound conditions with each literal that a comparison
+    holds against a non-literal replaced by an :class:`Operand`, and the
+    literals' physical values in slot order. A fragment program is keyed by
+    the conditions' SHAPE: Q3's 8 parameter sets (a date, a dictionary code)
+    are one program. Other literals (IN lists, arithmetic, NULL) stay in the
+    program as constants, and in its key."""
+    from tidb_tpu.types import Datum
+
+    values: list = []
+
+    def lift(e):
+        if not isinstance(e, ScalarFunc):
+            return e
+        args = list(e.args)
+        if e.sig in _LIFTED and len(args) == 2 and sum(isinstance(a, Constant) for a in args) == 1:
+            for i, a in enumerate(args):
+                if isinstance(a, Constant) and a.value is not None and a.ftype.kind != TypeKind.STRING:
+                    pv = Datum(a.value, a.ftype).physical()
+                    if isinstance(pv, (int, np.integer)) and not isinstance(pv, bool):
+                        values.append(np.asarray(pv, dtype=np.int64))
+                    elif isinstance(pv, (float, np.floating)):
+                        values.append(np.asarray(pv, dtype=np.float64))
+                    else:
+                        continue
+                    args[i] = Operand(len(values) - 1, a.ftype)
+        return ScalarFunc(e.sig, [lift(a) for a in args], e.ftype)
+
+    return [[lift(c) for c in cl] for cl in cond_lists], values
+
+
+def _slot_join(readers: list, joins: list, folds: list, agg) -> Optional[int]:
+    """The join whose unique build row determines every group key of
+    ``agg`` (``DistAggSpec.slot_join``), or None: all keys are plain columns,
+    each the join's probe key or a column of its build arm, the key itself
+    among them."""
+    if agg is None or not agg.group_by or not all(isinstance(g, ColumnRef) for g in agg.group_by):
+        return None
+    want = {g.index for g in agg.group_by}
+    lo = len(readers[0].schema)
+    for ji, join in enumerate(joins):
+        if join.kind not in ("inner", "left", "right"):
+            continue
+        hi = lo + len(readers[ji + 1].schema)
+        if join.kind == "inner" and join.unique and len(join.eq) == 1 and not isinstance(readers[ji + 1], SubplanReader):
+            end = hi
+            for k in range(ji + 1, len(joins)):
+                if not folds[k]:
+                    break
+                end += len(readers[k + 1].schema)
+            lp, rp = join.eq[0]
+            if want <= {lp} | set(range(lo, end)) and want & {lp, lo + rp}:
+                return ji
+        lo = hi
+    return None
+
+
+class _Phases:
+    """One gather's walk (lanes, program, dispatch, fetch, merge) as spans
+    ``mpp.<phase>`` inside ``mpp.gather``: ``to`` ends the phase before and
+    begins the named one, so the phases tile ``MPPGatherExec.execute`` as
+    ``tpu_engine._Phases`` tiles a cop task. ``label``: the span's name in a
+    session's TRACE. Nothing is opened unless the seam records."""
+
+    __slots__ = ("_tracer", "_live", "_outer", "_span")
+
+    def __init__(self, tracer, **meta):
+        from tidb_tpu.utils import tracing
+
+        self._tracer = tracer
+        self._live = tracer is not None or tracing.live()
+        self._span = None
+        self._outer = tracing.region("mpp.gather", tracer=tracer, **meta).__enter__() if self._live else None
+
+    def to(self, phase: str, label: Optional[str] = None, **meta) -> None:
+        if not self._live:
+            return
+        from tidb_tpu.utils import tracing
+
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+        self._span = tracing.region("mpp." + phase, tracer=self._tracer, label=label, **meta).__enter__()
+
+    def note(self, **meta) -> None:
+        """Add to the open phase's span what is only known inside it."""
+        if self._span is not None:
+            self._span.note(**meta)
+
+    def end(self, **meta) -> None:
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        if self._outer is not None:
+            self._outer.note(**meta)
+            self._outer.__exit__(None, None, None)
+            self._outer = None
 
 
 def _reader_mpp_ok(reader: PhysTableReader) -> bool:
@@ -1173,14 +1301,15 @@ def _scan_schema(reader: PhysTableReader) -> Schema:
     return out
 
 
-def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: int):
+def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: int, arms=None):
     """MPPJoin chain → DistJoinSpec list with power-of-two bucketed caps and
     JOINT per-key value bounds (both sides must pack identically). Shared by
     the outer plan chain and the join chains inside device stages. left_keys
     of later joins need no rebase: after join ji the accumulated lane layout
     = probe lanes + build lanes, and ``lane_of`` is computed over the full
     reader list. Key-validity lanes enforce NULL-key semantics (inner-join
-    keys must be non-NULL to match)."""
+    keys must be non-NULL to match). ``arms``: ``PhysMPPGather.arm_folds`` of
+    the outer chain (a stage's chain folds none)."""
     from tidb_tpu.parallel.mpp import DistJoinSpec
 
     shard = lambda n: max(_pow2(2 * ((max(n, 1) + ndev - 1) // ndev)), 64)
@@ -1209,6 +1338,7 @@ def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: 
                 unique=join.unique,
                 out_cap=max(_pow2(probe_cap), 1024),
                 key_bounds=tuple(kb),
+                arm=bool(arms and arms[ji]),
             )
         )
         if join.kind == "right":
@@ -1466,73 +1596,83 @@ class MPPGatherExec:
         bo = gather_backoffer()
         no_progress = 0
         self._compiles = 0
-        while True:
-            devices = GLOBAL_PROBER.alive(jax.devices())
-            from tidb_tpu.parallel import mesh as _mesh_mod
+        from tidb_tpu.ops.dag_kernel import _ensure_x64
 
-            if _mesh_mod.FORCE_NDEV is not None:
-                # scaling runs pin the mesh width (benchdaily scaling lanes,
-                # ndev-parity tests) — same path, fewer shards
-                devices = devices[: _mesh_mod.FORCE_NDEV]
-            if not devices:
-                raise MPPRetryExhausted("no alive devices for MPP")
-            mesh = make_mesh(devices=devices)
-            try:
-                failpoint.inject("mpp_run_fragment", mesh)
-                import time as _t
+        _ensure_x64()  # a process whose first device statement is a gather has not met the cop engine
+        ph = _Phases(self.session.tracer, readers=len(self.plan.readers))
+        ndev = 0
+        try:
+            while True:
+                ph.to("lanes", label="mpp-inputs")
+                devices = GLOBAL_PROBER.alive(jax.devices())
+                from tidb_tpu.parallel import mesh as _mesh_mod
 
-                t0 = _t.perf_counter()
-                out = self._execute_attempt(mesh)
-                # MPP exec-details: the gather's analog of the cop sidecar —
-                # feeds EXPLAIN ANALYZE's mpp_task line on this gather node,
-                # including the per-shard straggler breakdown the fragment
-                # program's shard probes recorded
-                from tidb_tpu.utils import metrics as _m
-                from tidb_tpu.utils.execdetails import MPPExecDetails
-
-                shards = getattr(self, "_shard_obs", [])
-                for sh in shards:
-                    _m.MPP_SHARD_SECONDS.observe(sh[1] / 1000.0)
-                self.session.record_mpp_detail(
-                    self.plan,
-                    MPPExecDetails(
-                        n_fragments=len(self.plan.fragments),
-                        ndev=int(mesh.devices.size),
-                        wall_ms=(_t.perf_counter() - t0) * 1000.0,
-                        rows=len(out),
-                        retries=bo.attempts(),
-                        store="hybrid" if getattr(self, "_hybrid", False) else "",
-                        shards=shards,
-                        compiles=getattr(self, "_compiles", 0),
-                        stages=getattr(self, "_n_stages", 1),
-                        stage_bytes=getattr(self, "_stage_bytes", []),
-                    ),
-                )
-                return out
-            except (MPPRetryExhausted, QueryKilledError, QueryOOMError):
-                # kills and quota cancels are statement verdicts, not device
-                # failures — retrying would defeat KILL / the memory quota
-                raise
-            except RuntimeError as exc:  # device loss / per-shard OOM / injected
-                bad = getattr(exc, "mpp_device", None)
-                if bad is not None:
-                    GLOBAL_PROBER.report_failure(bad)
-                else:
-                    # attribute by probing (MPPAlive analog): any device that
-                    # fails the round-trip is blacklisted; the next attempt
-                    # runs on the survivors
-                    if probe_and_blacklist(devices) == 0:
-                        no_progress += 1
-                if no_progress >= 2:
-                    raise MPPRetryExhausted(
-                        f"mpp execution made no progress after {bo.attempts() + 1} attempts: {exc}"
-                    ) from exc
+                if _mesh_mod.FORCE_NDEV is not None:
+                    # scaling runs pin the mesh width (benchdaily scaling lanes,
+                    # ndev-parity tests) — same path, fewer shards
+                    devices = devices[: _mesh_mod.FORCE_NDEV]
+                if not devices:
+                    raise MPPRetryExhausted("no alive devices for MPP")
+                mesh = make_mesh(devices=devices)
+                ndev = int(mesh.devices.size)
                 try:
-                    bo.backoff(boMPP)  # exc classifies fatal; budget-only pacing
-                except BackoffExhausted as be:
-                    raise MPPRetryExhausted(
-                        f"mpp retry budget exhausted after {be.attempts} attempts: {exc}"
-                    ) from exc
+                    failpoint.inject("mpp_run_fragment", mesh)
+                    import time as _t
+
+                    t0 = _t.perf_counter()
+                    out = self._execute_attempt(mesh, ph)
+                    # MPP exec-details: the gather's analog of the cop sidecar —
+                    # feeds EXPLAIN ANALYZE's mpp_task line on this gather node,
+                    # including the per-shard straggler breakdown the fragment
+                    # program's shard probes recorded
+                    from tidb_tpu.utils import metrics as _m
+                    from tidb_tpu.utils.execdetails import MPPExecDetails
+
+                    shards = getattr(self, "_shard_obs", [])
+                    for sh in shards:
+                        _m.MPP_SHARD_SECONDS.observe(sh[1] / 1000.0)
+                    self.session.record_mpp_detail(
+                        self.plan,
+                        MPPExecDetails(
+                            n_fragments=len(self.plan.fragments),
+                            ndev=ndev,
+                            wall_ms=(_t.perf_counter() - t0) * 1000.0,
+                            rows=len(out),
+                            retries=bo.attempts(),
+                            store="hybrid" if getattr(self, "_hybrid", False) else "",
+                            shards=shards,
+                            compiles=getattr(self, "_compiles", 0),
+                            stages=getattr(self, "_n_stages", 1),
+                            stage_bytes=getattr(self, "_stage_bytes", []),
+                        ),
+                    )
+                    return out
+                except (MPPRetryExhausted, QueryKilledError, QueryOOMError):
+                    # kills and quota cancels are statement verdicts, not device
+                    # failures — retrying would defeat KILL / the memory quota
+                    raise
+                except RuntimeError as exc:  # device loss / per-shard OOM / injected
+                    bad = getattr(exc, "mpp_device", None)
+                    if bad is not None:
+                        GLOBAL_PROBER.report_failure(bad)
+                    else:
+                        # attribute by probing (MPPAlive analog): any device that
+                        # fails the round-trip is blacklisted; the next attempt
+                        # runs on the survivors
+                        if probe_and_blacklist(devices) == 0:
+                            no_progress += 1
+                    if no_progress >= 2:
+                        raise MPPRetryExhausted(
+                            f"mpp execution made no progress after {bo.attempts() + 1} attempts: {exc}"
+                        ) from exc
+                    try:
+                        bo.backoff(boMPP)  # exc classifies fatal; budget-only pacing
+                    except BackoffExhausted as be:
+                        raise MPPRetryExhausted(
+                            f"mpp retry budget exhausted after {be.attempts} attempts: {exc}"
+                        ) from exc
+        finally:
+            ph.end(retries=bo.attempts(), ndev=ndev)
 
     def _execute_remote(self):
         """Ship the gather to the storage-server process (ref: kv/mpp.go
@@ -1656,7 +1796,7 @@ class MPPGatherExec:
         )
         return chunk
 
-    def _execute_attempt(self, mesh):
+    def _execute_attempt(self, mesh, ph):
         import jax
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec
@@ -1678,7 +1818,10 @@ class MPPGatherExec:
         mesh_ids = tuple(int(d.id) for d in mesh.devices.flat)
         lane_sharding = NamedSharding(mesh, PartitionSpec("dp"))
 
+        h2d = [0]  # bytes this attempt uploads: 0 where every lane was resident
+
         def put(a):
+            h2d[0] += a.nbytes
             return jax.device_put(a, lane_sharding)
 
         self._stage_bytes = []  # per-device-stage exchanged bytes (psum)
@@ -1712,7 +1855,7 @@ class MPPGatherExec:
                 for join in parts[1]:
                     for (ta, sa), (tb, sb) in join.str_keys:
                         _cache.unify_dictionaries(ta, sa, tb, sb)
-        conds = [self._bind_conditions(r) for r in p.readers]
+        conds, operands = _lift_literals([self._bind_conditions(r) for r in p.readers])
         agg = p.agg
 
         def pad_side(chunk):
@@ -1853,17 +1996,23 @@ class MPPGatherExec:
                     _MPP_DEV_CACHE.pop(next(iter(_MPP_DEV_CACHE)))
             return dev
 
-        # traced under TRACE (or a propagated remote trace context): the two
-        # dominant phases of a gather get their own spans. A STAGED reader
-        # materializes its stage readers' RAW lanes (per-column pooled like
-        # any plain scan) — the subplan's aggregate never touches the host.
-        with self.session.span("mpp-inputs"):
-            sides = [
-                [dev_side(sr) for sr in stage_parts[ri][0]]
-                if stage_parts[ri] is not None
-                else dev_side(r)
-                for ri, r in enumerate(p.readers)
-            ]
+        # A STAGED reader materializes its stage readers' RAW lanes (per-
+        # column pooled like any plain scan) — the subplan's aggregate never
+        # touches the host.
+        sides = [
+            [dev_side(sr) for sr in stage_parts[ri][0]]
+            if stage_parts[ri] is not None
+            else dev_side(r)
+            for ri, r in enumerate(p.readers)
+        ]
+        flat_sides = [x for ri, side in enumerate(sides) for x in (side if stage_parts[ri] is not None else [side])]
+        ph.note(
+            rows_valid=sum(n for _, n, _ in flat_sides),
+            rows_padded=sum(int(lanes[-1].shape[0]) for lanes, _, _ in flat_sides),
+            h2d=h2d[0],
+            cache="miss" if h2d[0] else "hit",
+        )
+        ph.to("program")
         stats = self.session._db.stats
 
         def _stage_cap(sub, probe_n: int) -> int:
@@ -1920,7 +2069,7 @@ class MPPGatherExec:
             def fn(*cols):
                 pairs = [(cols[2 * i], cols[2 * i + 1]) for i in range(nc)]
                 live = cols[2 * nc]
-                batch = EvalBatch(pairs, [None] * nc, pairs[0][0].shape[0], warn=warn_sink)
+                batch = EvalBatch(pairs, [None] * nc, pairs[0][0].shape[0], warn=warn_sink, operands=operand_box)
                 m = live
                 for cond in cond_list:
                     d, v, _ = eval_expr(cond, batch, jnp)
@@ -1933,6 +2082,10 @@ class MPPGatherExec:
             return fn
 
         selections = [side_selection(conds[i], ncols[i]) for i in range(len(p.readers))]
+        operand_box: list = []  # the program's traced operands, once it is traced
+
+        def bind_operands(traced):
+            operand_box[:] = traced
 
         # agg input mapping over the accumulated lane layout
         total_cols = _plan_schema_len(p.readers, p.joins)
@@ -2061,7 +2214,9 @@ class MPPGatherExec:
                     out.append(jnp.where(v, d, sent))
                 else:
                     out.append(jnp.where(v, d, 0))
-                out.append(v.astype(jnp.int64))
+                # a count of non-NULL rows: int32 lanes sum, sort and gather at a third of
+                # the cost of the emulated int64 on a TPU (2^31 rows of one group fit no mesh)
+                out.append(v.astype(jnp.int32))
             return out
 
         # per-join capacities: per-side receive capacity from ITS row count;
@@ -2069,7 +2224,7 @@ class MPPGatherExec:
         # power-of-two bucketed so the caps (compile-key components) land on
         # the same grid for nearby sizes and for grow-and-retry attempts
         join_specs = _make_join_specs(
-            p.joins, nrows, all_bounds, bounds_by_reader, lane_of, ndev
+            p.joins, nrows, all_bounds, bounds_by_reader, lane_of, ndev, arms=p.arm_folds
         )
 
         # device-stage runtimes: each staged build side carries its own
@@ -2238,6 +2393,8 @@ class MPPGatherExec:
         has_stages = any(s is not None for s in stage_runtimes)
 
         group_cap = 0
+        slot_join = None if has_stages else _slot_join(p.readers, p.joins, p.arm_folds, agg)
+        family = f"mpp_j{len(p.joins)}_" + (f"agg_g{len(agg.group_by)}" if agg is not None else "topn")
         if agg is not None:
             # a dispatching client may ship its stats-informed cap with the
             # task (the server's stats handle starts empty)
@@ -2281,6 +2438,7 @@ class MPPGatherExec:
                     val_kinds=tuple(val_kinds),
                     n_dkeys=ndk,
                     distinct_mask=dmask if ndk else (),
+                    slot_join=slot_join,
                 )
                 if agg is not None
                 else None
@@ -2302,12 +2460,13 @@ class MPPGatherExec:
                     out_lanes=out_lanes,
                     out_cap=max(_pow2(limit), 1024),
                 )
-            # compile cache: the jitted shard_map program is pure structure —
-            # keyed on specs + bound-condition fingerprints, NOT data (row
-            # caps and padded shapes are power-of-two bucketed above, so
-            # same-shape queries at different sizes produce THE SAME key).
-            # Without this every query pays a full XLA mesh compile (~10s+
-            # on TPU).
+            # compile cache: the compiled shard_map program is structure plus
+            # the lanes' padded shapes — keyed on specs + bound-condition
+            # fingerprints + shapes, NOT data (row caps and padded shapes are
+            # power-of-two bucketed above, so a table that grows inside its
+            # bucket keeps its key; one that crosses it gets a program of its
+            # own, as a retrace would). Without this every query pays a full
+            # XLA mesh compile (~10s+ on TPU).
             fn_key = (
                 id(mesh),
                 repr(join_specs),
@@ -2328,7 +2487,10 @@ class MPPGatherExec:
                     r.fingerprint() if isinstance(r, SubplanReader) and r.staged else ""
                     for r in p.readers
                 ),
-                PROBES_ENABLED,
+                _probes_on(),
+                tuple((v.shape, str(v.dtype)) for v in operands),
+                # the cached executable is compiled for these very shapes
+                tuple((a.shape, str(a.dtype)) for a in all_lanes),
             )
             from tidb_tpu.utils import metrics as _met
 
@@ -2336,6 +2498,9 @@ class MPPGatherExec:
             if cached is None:
                 _met.MPP_PROGRAM_CACHE.inc(result="miss")
                 self._compiles = getattr(self, "_compiles", 0) + 1
+                import time as _t
+
+                t_build = _t.perf_counter()
                 fn = build_dist_pipeline(
                     mesh,
                     join_specs,
@@ -2345,11 +2510,18 @@ class MPPGatherExec:
                     agg_inputs=agg_inputs if agg is not None else None,
                     topn=topn_spec,
                     warn_sink=warn_sink,
-                    shard_probe=_shard_probe if PROBES_ENABLED else None,
+                    shard_probe=_shard_probe if _probes_on() else None,
                     pair_filters=pair_filters,
                     chain_filters=chain_filters,
                     stages=stage_runtimes if has_stages else None,
+                    n_operands=len(operands),
+                    bind_operands=bind_operands,
+                    name=family,
                 )
+                # traced and compiled here, not at the first call: the program
+                # phase owns the compile, the dispatch phase only enqueues
+                fn = fn.lower(*all_lanes, *operands).compile()
+                ph.note(cache="miss", compile_us=int((_t.perf_counter() - t_build) * 1e6))
                 # the sink is baked into the compiled program's closures: a
                 # cache hit must attribute warn counts via the ORIGINAL sink
                 with _MPP_CACHE_MU:
@@ -2358,15 +2530,18 @@ class MPPGatherExec:
                         _MPP_FN_CACHE.pop(next(iter(_MPP_FN_CACHE)))
             else:
                 _met.MPP_PROGRAM_CACHE.inc(result="hit")
+                ph.note(cache="hit")
                 fn, warn_sink = cached
-            with self.session.span(f"mpp-pipeline[{ndev}dev]"), _MESH_EXEC_LOCK:
+            ph.to("dispatch", label=f"mpp-pipeline[{ndev}dev]", kernel=family)
+            with _MESH_EXEC_LOCK:
                 import time as _t
 
                 shard_obs: list = []
                 _SHARD_OBS["t0"] = _t.perf_counter()
                 _SHARD_OBS["sink"] = shard_obs
                 try:
-                    outs = fn(*all_lanes)
+                    outs = fn(*all_lanes, *operands)
+                    ph.to("fetch")
                     # ONE device→host round trip for every output lane:
                     # device_get batches the whole tuple into a single
                     # transfer — and blocking inside the lock keeps the
@@ -2407,6 +2582,7 @@ class MPPGatherExec:
                     for _ in range(min(wtotal, 64)):
                         self.session.append_warning("Warning", code, msg)
                 break
+            ph.to("program")  # another attempt, with a bigger program
             # grow-on-overflow, like coprocessor paging (skewed owners can
             # exceed either side's 2× headroom; the counters are shared, so
             # grow everything that can overflow — stage caps included)
@@ -2428,9 +2604,10 @@ class MPPGatherExec:
                         st.spec.group_cap *= 4
                         for s in st.spec.joins:
                             s.out_cap *= 4
-        if agg is not None:
-            return self._merge(arrs[:-2], agg)
-        return self._rows_chunk(arrs[:-2])
+        ph.to("merge")
+        out = self._merge(arrs[:-2], agg) if agg is not None else self._rows_chunk(arrs[:-2])
+        ph.note(groups=len(out))
+        return out
 
     def _initial_group_cap(self, n_left_rows: int) -> int:
         """Static per-shard group capacity: NDV-product estimate with a

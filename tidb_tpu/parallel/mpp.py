@@ -17,7 +17,7 @@ the exchange can never overflow.
 from __future__ import annotations
 
 import functools
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Callable, Sequence
 
 import numpy as np
@@ -51,6 +51,12 @@ class DistAggSpec:
     # distinct slot reduction instead of a plain value lane.
     n_dkeys: int = 0
     distinct_mask: tuple = ()
+    # index of the chain's join whose UNIQUE build row determines every group
+    # key, the join key among them (Q3: GROUP BY l_orderkey, o_orderdate,
+    # o_shippriority under ``l_orderkey = o_orderkey``): a group IS a build
+    # slot, so the partial aggregate reduces by slot (:func:`_slot_partial`)
+    # where the fragment took the direct-address lookup for that join
+    slot_join: int | None = None
 
 
 def _pack_keys(jnp, keys, bounds):
@@ -227,6 +233,10 @@ class DistJoinSpec:
     # pack into one narrow exact lane (int32 when the domain fits): native
     # sorts, and component re-verification becomes belt-and-braces
     key_bounds: tuple = ()
+    # a snowflake arm: folded into the build side of the join before it,
+    # first (the gather decides: a unique inner join whose probe keys all lie
+    # in that build side, ``PhysMPPGather.arm_folds``)
+    arm: bool = False
 
 
 def _combine_keys(jnp, keys):
@@ -321,6 +331,74 @@ def _sorted_lookup(jnp, rk_s, lkey):
     cum_right = jnp.cumsum(jnp.where(perm < m, 1, 0))
     pos = inv[m:]
     return jnp.clip(cum_right[pos] - 1, 0, m - 1)
+
+
+# the widest key domain a direct-address table is built over: int32 slots,
+# 512 MiB, 3% of one v5e's HBM. A join whose packed key domain is wider, or
+# whose keys carry no bounds, keeps the sort-merge lookup.
+DIRECT_DOMAIN_MAX = 1 << 27
+
+
+def _direct_lookup(jnp, lkey, lvalid, rkey, rvalid, n_codes):
+    """The build row of each probe row, -1 where it has none, through a
+    direct-address table over the key's domain: one scatter of the build
+    side's row numbers, one gather by the probe keys, no sort. Keys are
+    packed codes in [0, n_codes) (:func:`_pack_keys` over the JOINT bounds of
+    both sides, so equal codes are equal keys); the build side holds a code
+    at most once. On one v5e (builder's chip run, PR 29): 25 ms to scatter 4M
+    rows into 12M slots, 145 ms to gather 16M int32; the sort-merge lookup
+    sorts build + probe concatenated, twice."""
+    m = rkey.shape[0]
+    table = jnp.full(n_codes, -1, jnp.int32).at[jnp.where(rvalid, rkey, n_codes)].set(
+        jnp.arange(m, dtype=jnp.int32), mode="drop"
+    )
+    return jnp.where(lvalid, table[jnp.where(lvalid, lkey, 0)], -1)
+
+
+def _slot_partial(jax, jnp, slot, mask, vals, cap):
+    """Grouped partial sums where a group IS a build slot (``slot``: each
+    probe row's build row from :func:`_direct_lookup`). Probe rows whose live
+    slots never step back (a fact table stored in its dimension's key order:
+    `lineitem` by `l_orderkey`) are reduced in place, as runs; anything else
+    is sorted by slot first, the value lanes riding the sort. Which, the
+    program sees in the data (one running maximum). Returns (slot, first
+    probe row, sums, counts, overflow) of the first ``cap`` groups; a group
+    holds rows iff its count > 0, and ``overflow`` counts groups past
+    ``cap`` as :func:`_segment_partial` does."""
+    n = slot.shape[0]
+    live = mask & (slot >= 0)
+    s = jnp.where(live, slot, -1)
+    rows = jnp.arange(n, dtype=jnp.int32)
+    vals = [jnp.where(live, v, 0) for v in vals]
+
+    def before(x):  # the last live slot before each row
+        return jnp.concatenate([jnp.full(1, -1, x.dtype), jax.lax.cummax(x)[:-1]])
+
+    def by_slot():
+        key = jnp.where(live, s, jnp.iinfo(jnp.int32).max)  # rows of no group sort last
+        out = jax.lax.sort((key, rows, *vals), num_keys=1)
+        key = jnp.where(out[0] == jnp.iinfo(jnp.int32).max, -1, out[0])
+        return (key, before(key), out[1], *out[2:])
+
+    prev = before(s)
+    s, prev, rows, *vals = jax.lax.cond(jnp.all(~live | (s >= prev)), lambda: (s, prev, rows, *vals), by_slot)
+    live = s >= 0
+    first = live & (s != prev)
+    seg = jnp.cumsum(first.astype(jnp.int32))  # groups begun up to and at each row
+    overflow = jnp.maximum(seg[-1].astype(jnp.int64) - cap, 0)
+    at = jnp.searchsorted(seg, jnp.arange(1, cap + 2, dtype=jnp.int32))  # group k's first row; n past the last
+    starts = jnp.clip(at[:-1], 0, n - 1)
+    ends = jnp.clip(at[1:] - 1, 0, n - 1)
+    has = jnp.arange(cap, dtype=jnp.int32) < seg[-1]
+
+    def run_sums(x):
+        cs = jnp.cumsum(x)
+        lo = jnp.where(starts > 0, cs[jnp.maximum(starts - 1, 0)], 0)
+        return jnp.where(has, cs[ends] - lo, 0)
+
+    sums = [run_sums(v) for v in vals]
+    cnt = run_sums(live.astype(jnp.int32)).astype(jnp.int64)
+    return jnp.where(has, s[starts], 0), jnp.where(has, rows[starts], 0), sums, cnt, overflow
 
 
 def _local_unique_join(jax, jnp, lkey, lkeys, lvalid, rkey, rkeys, rcols, rvalid,
@@ -502,11 +580,13 @@ class StageRuntime:
         self.chain_filters = chain_filters  # [(chain position, mask fn)]
 
 
-def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf):
+def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None):
     """Fold ONE build side into the accumulated probe layout — the per-join
     body of the fragment pipeline, shared by the outer chain and the join
     chains INSIDE device stages. Returns (acc, mask, dropped, overflow,
-    xbytes) deltas accumulated into the caller's counters."""
+    xbytes) deltas accumulated into the caller's counters. ``slot_out``: a
+    dict that receives ``slot`` (each probe row's build row) and ``build``
+    (the build lanes it indexes) where the direct-address lookup ran."""
     dropped = jnp.int64(0)
     overflow = jnp.int64(0)
     xbytes = jnp.int64(0)
@@ -633,9 +713,19 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf):
         )
         mask = mask & (cnt > 0) if kind == "semi" else mask & (cnt == 0)
     elif join.unique:
-        gathered, match = _local_unique_join(
-            jax, jnp, lkey, lkeys, probe_live, rkey, rkeys, rcols, rvalid, dead_b, dead_p
-        )
+        if ncodes is not None and ncodes <= DIRECT_DOMAIN_MAX:
+            # bounded keys, a domain that fits: no sort (the bounds decide)
+            with jax.named_scope("mpp.build"):
+                slot = _direct_lookup(jnp, lkey, probe_live, rkey, rvalid, ncodes)
+            match = slot >= 0
+            at = jnp.maximum(slot, 0)
+            gathered = [rc[at] for rc in rcols]  # lanes nothing reads are never gathered
+            if slot_out is not None:
+                slot_out.update(slot=slot, build=rcols)
+        else:
+            gathered, match = _local_unique_join(
+                jax, jnp, lkey, lkeys, probe_live, rkey, rkeys, rcols, rvalid, dead_b, dead_p
+            )
         if kind == "inner":
             mask = match
             acc = acc + gathered
@@ -778,6 +868,9 @@ def build_dist_pipeline(
     pair_filters: Sequence[Callable | None] | None = None,
     chain_filters: Sequence[tuple] = (),
     stages: "Sequence[StageRuntime | None] | None" = None,
+    n_operands: int = 0,
+    bind_operands: Callable | None = None,
+    name: str = "mpp",
 ):
     """The generalized MPP pipeline in ONE jitted shard_map (ref: §3.3 —
     fragments: scan→sel→[exchange→join]*→(partial agg→hash exchange→merge |
@@ -805,7 +898,14 @@ def build_dist_pipeline(
     its input block through :func:`_run_stage` and the STAGE OUTPUT slots
     (device-resident) become the join's build side; with stages present the
     program emits one extra replicated output, the per-stage exchanged-byte
-    vector (ordered by reader index), before the warn count."""
+    vector (ordered by reader index), before the warn count.
+
+    ``n_operands`` scalars follow the lanes: the literals of the readers'
+    pushed conditions, handed to ``bind_operands`` at trace time so that the
+    selections read them as traced values — one program serves every literal.
+    ``name`` names the XLA module (``jit_<name>``); the stages carry the
+    scopes ``mpp.build``, ``mpp.probe``, ``mpp.agg``, ``mpp.topn`` and
+    ``mpp.exchange``."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -826,6 +926,8 @@ def build_dist_pipeline(
         return mask
 
     def step(*cols):
+        if bind_operands is not None:
+            bind_operands(cols[offs[-1] :])
         acc = list(cols[offs[0] : offs[1]])
         mask = jnp.ones(acc[0].shape[0], dtype=bool)
         if selections[0] is not None:
@@ -839,7 +941,8 @@ def build_dist_pipeline(
         # per-stage exchanged bytes (reader order), replicated output when
         # any stage exists — the dryrun/EXPLAIN per-stage breakdown
         stage_xb: list = []
-        for ji, join in enumerate(joins):
+        builds: list = []  # per join: (build lanes, live mask)
+        for ji in range(len(joins)):
             block = list(cols[offs[ji + 1] : offs[ji + 2]])
             stage = stages[ji + 1] if stages is not None else None
             if stage is not None:
@@ -853,15 +956,57 @@ def build_dist_pipeline(
                 rvalid = jnp.ones(rcols[0].shape[0], dtype=bool)
                 if selections[ji + 1] is not None:
                     rvalid = selections[ji + 1](*rcols)
-            pf = pair_filters[ji] if pair_filters is not None else None
-            acc, mask, d, of, xb = _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf)
+            builds.append((rcols, rvalid))
+        # a snowflake arm (``DistJoinSpec.arm``, the gather's decision) folds
+        # from its tip: a unique inner join whose probe keys all lie in the
+        # build side of the join before it (lineitem -> orders -> customer:
+        # ``o_custkey`` is a lane of ``orders``) is folded into THAT build
+        # side first, at its row count — the 12M-row probe then looks up ONE
+        # table that already says which orders pass. The lanes it gathers land
+        # where the chain would have put them, so the accumulated layout, and
+        # every filter placed in it, is as written.
+        lane_off = [len(acc)]  # where join ji's build lanes start in the accumulated layout
+        for ji, join in enumerate(joins):
+            lane_off.append(lane_off[-1] + (len(builds[ji][0]) if join.kind in ("inner", "left", "right") else 0))
+        for ji in reversed(range(1, len(joins))):
+            join, before = joins[ji], joins[ji - 1]
+            if not join.arm:
+                continue
+            lo = lane_off[ji - 1]
+            arm = replace(
+                join,
+                left_keys=[k - lo for k in join.left_keys],
+                left_key_valid=tuple(k - lo for k in join.left_key_valid),
+                left_row_cap=before.right_row_cap,
+            )
+            with jax.named_scope("mpp.build"):
+                bl, bv, d, of, xb = _fold_join(jax, jnp, arm, ndev, *builds[ji - 1], *builds[ji], None)
+            builds[ji - 1] = (bl, bv)
             dropped, overflow, xbytes = dropped + d, overflow + of, xbytes + xb
+        slot = None  # by-slot aggregate: (each row's build slot, that build's lanes, where they start)
+        for ji, join in enumerate(joins):
+            if join.arm:
+                mask = _apply_chain(ji + 1, acc, mask)
+                continue
+            pf = pair_filters[ji] if pair_filters is not None else None
+            took = {} if agg is not None and agg.slot_join == ji else None
+            if slot is not None and not (
+                (ndev == 1 or join.exchange != "hash") and join.kind in ("inner", "semi", "anti") and join.unique
+            ):
+                slot = None  # this fold moves or multiplies the probe rows
+            n_before = len(acc)
+            with jax.named_scope("mpp.probe"):
+                acc, mask, d, of, xb = _fold_join(jax, jnp, join, ndev, acc, mask, *builds[ji], pf, took)
+            dropped, overflow, xbytes = dropped + d, overflow + of, xbytes + xb
+            if took:
+                slot = (took["slot"], took["build"], n_before)
             mask = _apply_chain(ji + 1, acc, mask)
-        outs, local_rows = (
-            _agg_tail(acc, mask, dropped, overflow)
-            if agg is not None
-            else _topn_tail(acc, mask, dropped, overflow)
-        )
+        if agg is not None:
+            with jax.named_scope("mpp.agg"):
+                outs, local_rows = _agg_tail(acc, mask, dropped, overflow, slot)
+        else:
+            with jax.named_scope("mpp.topn"):
+                outs, local_rows = _topn_tail(acc, mask, dropped, overflow)
         if shard_probe is not None:
             # effect-only host callback; local_rows depends on the shard's
             # tail reduction, so the probe fires after this shard's compute
@@ -916,20 +1061,37 @@ def build_dist_pipeline(
         goverflow = jax.lax.psum(overflow, "dp")
         return (*outs, glive, total, gdropped, goverflow), cnt
 
-    def _agg_tail(joined, mask, dropped, overflow):
+    def _agg_tail(joined, mask, dropped, overflow, slot=None):
         acols = agg_inputs(joined) if agg_inputs is not None else joined
         G, D = agg.n_keys, agg.n_dkeys
-        # distinct lanes join the stage-1 segment keys: grouping by (g, x)
-        # IS the dedup (ref: TiFlash two-phase distinct aggregation)
-        keys = list(acols[: G + D])
         vals = [acols[i] for i in agg.sums]
-        pkeys, psums, pcnt, of1 = _segment_partial(jnp, keys, vals, mask, cap, agg.key_bounds, agg.val_kinds)
-        # route by GROUP keys only: every (g, *) slot lands on g's owner
-        # shard, where x dedups globally
-        rxkeys, rxsums, rxcnt, of_slots = _exchange_group_slots(
-            jax, jnp, ndev, cap, pkeys, psums, pcnt, route_keys=pkeys[:G]
-        )
-        mkeys, msums_cnt, _, of3 = _segment_partial(jnp, rxkeys, rxsums + [rxcnt], rxcnt > 0, cap, agg.key_bounds, tuple(agg.val_kinds) + ("sum",))
+        if slot is not None and not D and all(k == "sum" for k in agg.val_kinds):
+            # a group IS a build slot of the direct-address join: sums by
+            # slot, and the group's key lanes read where the group is known —
+            # build lanes at its slot, probe lanes at its first row — so no
+            # build lane is ever gathered out to the probe's row count
+            idx, build, at = slot
+            gslot, grow, psums, pcnt, of1 = _slot_partial(jax, jnp, idx, mask, vals, cap)
+            head = [a[grow] for a in joined[:at]] + [b[gslot] for b in build]
+            head += [a[grow] for a in joined[at + len(build) :]]
+            pkeys = [jnp.where(pcnt > 0, k, 0) for k in agg_inputs(head)[:G]]
+        else:
+            # distinct lanes join the stage-1 segment keys: grouping by (g, x)
+            # IS the dedup (ref: TiFlash two-phase distinct aggregation)
+            keys = list(acols[: G + D])
+            pkeys, psums, pcnt, of1 = _segment_partial(jnp, keys, vals, mask, cap, agg.key_bounds, agg.val_kinds)
+        if ndev == 1:
+            # one shard: its partial groups are the groups — nothing to
+            # exchange, nothing to merge
+            mkeys, msums_cnt, of_slots, of3 = pkeys, psums + [pcnt], 0, 0
+        else:
+            # route by GROUP keys only: every (g, *) slot lands on g's owner
+            # shard, where x dedups globally
+            with jax.named_scope("mpp.exchange"):
+                rxkeys, rxsums, rxcnt, of_slots = _exchange_group_slots(
+                    jax, jnp, ndev, cap, pkeys, psums, pcnt, route_keys=pkeys[:G]
+                )
+            mkeys, msums_cnt, _, of3 = _segment_partial(jnp, rxkeys, rxsums + [rxcnt], rxcnt > 0, cap, agg.key_bounds, tuple(agg.val_kinds) + ("sum",))
         if D:
             # stage 3: per-g reduction over the deduped (g, x) slots — the
             # distinct output pair is (Σ distinct x, count of distinct x);
@@ -979,10 +1141,11 @@ def build_dist_pipeline(
         extra += (P(None),)  # per-stage exchange-bytes vector
     if warn_sink is not None:
         extra += (P(),)
+    step.__name__ = step.__qualname__ = name  # the XLA module is jit_<name>
     fn = jax.shard_map(
         step,
         mesh=mesh,
-        in_specs=tuple(P("dp") for _ in range(sum(n_lanes))),
+        in_specs=tuple(P("dp") for _ in range(sum(n_lanes))) + (P(),) * n_operands,
         out_specs=(P(None),) * n_rep + (P(), P(), P()) + extra,
         check_vma=False,
     )

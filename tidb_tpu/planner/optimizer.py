@@ -65,6 +65,7 @@ def optimize(plan: LogicalPlan, engines: list[str], stats=None, vars=None) -> Ph
     ``vars``: session variables for planner toggles."""
     plan, _ = _prune(plan, None)
     plan = _push_selections(plan)
+    plan = _reorder_joins(plan, stats)
     fast = _try_point_get(plan)
     if fast is not None:
         return fast
@@ -320,14 +321,182 @@ def _push_selections(plan: LogicalPlan) -> LogicalPlan:
         # merge adjacent selections on the same side
         for side in (0, 1):
             ch = join.children[side]
-            if isinstance(ch, LogicalSelection) and isinstance(ch.children[0], LogicalSelection):
+            while isinstance(ch, LogicalSelection) and isinstance(ch.children[0], LogicalSelection):
                 inner = ch.children[0]
                 inner.conditions = ch.conditions + inner.conditions
-                join.children[side] = inner
+                join.children[side] = ch = inner
+            if isinstance(ch, LogicalSelection) and isinstance(ch.children[0], LogicalJoin):
+                # the walk is bottom-up, so a selection hung under this join
+                # just now has not seen the nested join below it: push again,
+                # down to the readers (Q3's `FROM customer, orders, lineitem
+                # WHERE ...` otherwise strands `c_custkey = o_custkey` and both
+                # filters above a cross join of customer x orders)
+                join.children[side] = _push_selections(ch)
         if not keep:
             return join
         plan.conditions = keep
     return plan
+
+
+# ---------------------------------------------------------------------------
+# join order (ref: rule_join_reorder.go, the greedy solver)
+# ---------------------------------------------------------------------------
+
+
+def table_unique_on(table, key_slots: list[int]) -> bool:
+    """The table holds at most one row per value of the columns at
+    ``key_slots``: its integer primary key, or a public unique index."""
+    if table.pk_is_handle and key_slots == [table.pk_offset]:
+        return True
+    for idx in table.indexes:
+        if idx.state != "public":
+            continue  # a mid-DDL unique index hasn't proven uniqueness yet
+        if (idx.unique or idx.primary) and sorted(idx.column_offsets) == sorted(key_slots):
+            return True
+    return False
+
+
+def _reorderable(plan) -> bool:
+    return (
+        isinstance(plan, LogicalJoin)
+        and plan.kind == "inner"
+        and bool(plan.eq_conds)
+        and not plan.other_conds
+        and not plan.null_aware
+        and not plan.preferred
+    )
+
+
+def _leaf_scan(leaf):
+    """(scan, conditions) of a join leaf that is a table scan under at most
+    one selection, else None."""
+    conds: list = []
+    if isinstance(leaf, LogicalSelection):
+        conds, leaf = leaf.conditions, leaf.children[0]
+    return (leaf, conds) if isinstance(leaf, LogicalScan) else None
+
+
+def _reorder_joins(plan: LogicalPlan, stats=None) -> LogicalPlan:
+    """Greedy order for a tree of inner equi-joins: a side joined on a
+    unique key of its own is a BUILD side (a lookup, no expansion), and of
+    the roots that leave fewest non-unique builds the largest filtered side
+    probes. A tree is rebuilt (left-deep, under a projection that restores
+    its column order) only when that lowers the number of non-unique builds
+    — `FROM customer, orders, lineitem` (Q3) builds on `o_custkey` and
+    `l_orderkey` as written and on two primary keys as `lineitem`, `orders`,
+    `customer`; a tree that already builds on unique keys, or carries a join
+    hint, stays as the statement wrote it."""
+    if not _reorderable(plan):
+        for i, c in enumerate(getattr(plan, "children", [])):
+            plan.children[i] = _reorder_joins(c, stats)
+        return plan
+    leaves: list = []  # (node, offset of its first column in the tree's schema)
+    edges: list[tuple[int, int]] = []  # equalities, tree-schema positions
+    written_builds: list = []  # per join as written: its right side, if a leaf
+
+    def flatten(node, off: int) -> None:
+        if not _reorderable(node):
+            leaves.append((node, off))
+            return
+        left, right = node.children
+        flatten(left, off)
+        roff = off + len(left.schema)
+        flatten(right, roff)
+        edges.extend((off + l, roff + r) for l, r in node.eq_conds)
+        written_builds.append(None if _reorderable(right) else len(leaves) - 1)
+
+    flatten(plan, 0)
+    for i, (leaf, _) in enumerate(leaves):
+        leaves[i] = (_reorder_joins(leaf, stats), leaves[i][1])
+    n = len(leaves)
+    leaf_of = [li for li, (leaf, _) in enumerate(leaves) for _ in leaf.schema]
+
+    def unique_on(li: int, positions: list[int]) -> bool:
+        got = _leaf_scan(leaves[li][0])
+        if got is None:
+            return False
+        scan, _ = got
+        off = leaves[li][1]
+        return table_unique_on(scan.table, [scan.schema[p - off].slot for p in positions])
+
+    def rows_of(li: int):
+        got = _leaf_scan(leaves[li][0])
+        st = stats.get(got[0].table.id) if got is not None and stats is not None else None
+        if st is None or not st.row_count:
+            return None
+        rows = float(st.row_count)
+        if got[1]:
+            from tidb_tpu.statistics.selectivity import estimate_selectivity
+
+            rows *= estimate_selectivity(got[1], got[0].schema, st)
+        return rows
+
+    def keys_into(li: int, joined: set) -> list[int]:
+        """Positions of leaf ``li``'s columns equated with a joined leaf's."""
+        out = []
+        for a, b in edges:
+            if leaf_of[a] == li and leaf_of[b] in joined:
+                out.append(a)
+            elif leaf_of[b] == li and leaf_of[a] in joined:
+                out.append(b)
+        return out
+
+    written = 0  # non-unique builds of the tree as written
+    for li in written_builds:
+        if li is None:
+            written += 1
+            continue
+        keys = keys_into(li, set(range(n)) - {li})
+        written += not unique_on(li, keys)
+    if written == 0:
+        return plan
+    rows = [rows_of(li) for li in range(n)]
+    best = None
+    for root in range(n):
+        order, joined, bad = [root], {root}, 0
+        while len(order) < n:
+            cands = []
+            for li in range(n):
+                if li in joined:
+                    continue
+                keys = keys_into(li, joined)
+                if keys:
+                    uniq = unique_on(li, keys)
+                    cands.append((not uniq, rows[li] if rows[li] is not None else float("inf"), li))
+            if not cands:
+                break  # not connected from this root: it would take a cross join
+            nonuniq, _, li = min(cands)
+            bad += nonuniq
+            order.append(li)
+            joined.add(li)
+        if len(order) < n:
+            continue
+        score = (bad, -(rows[root] or 0.0), root)
+        if best is None or score < best[0]:
+            best = (score, order)
+    if best is None or best[0][0] >= written:
+        return plan
+    order = best[1]
+    newpos: dict[int, int] = {}
+    cur, joined = None, set()
+    for li in order:
+        leaf, off = leaves[li]
+        if cur is None:
+            cur = leaf
+        else:
+            eq = []
+            for a, b in edges:
+                if leaf_of[b] in joined and leaf_of[a] == li:
+                    a, b = b, a
+                if leaf_of[a] in joined and leaf_of[b] == li:
+                    eq.append((newpos[a], b - off))
+            cur = LogicalJoin(kind="inner", eq_conds=eq, schema=cur.schema + leaf.schema, children=[cur, leaf])
+        start = len(cur.schema) - len(leaf.schema)
+        for i in range(len(leaf.schema)):
+            newpos[off + i] = start + i
+        joined.add(li)
+    exprs = [ColumnRef(newpos[i], oc.ftype, oc.name) for i, oc in enumerate(plan.schema)]
+    return LogicalProjection(exprs=exprs, schema=list(plan.schema), children=[cur])
 
 
 # ---------------------------------------------------------------------------

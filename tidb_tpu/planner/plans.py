@@ -548,7 +548,15 @@ def explain_plan(p, indent: int = 0, stats=None) -> str:
     if isinstance(p, PhysMPPGather):
         if p.joins:
             ex = ",".join(j.exchange for j in p.joins)
-            extra = f"{len(p.fragments)} fragments, {ex} join exchange"
+            # how each build side is probed: a lookup (its join key is unique)
+            # or an expansion; and where it is folded in first (a snowflake arm)
+            folds = p.arm_folds
+            builds = ", ".join(
+                f"{p.readers[ji + 1].table.name}({'unique' if j.unique else 'expand'}"
+                + (f", in {p.readers[ji].table.name}" if folds[ji] else "") + ")"
+                for ji, j in enumerate(p.joins)
+            )
+            extra = f"{len(p.fragments)} fragments, {ex} join exchange, lookup {builds}"
         else:
             extra = f"{len(p.fragments)} fragments"
         lines = [f"{pad}{name} {extra}{_info(p)}"]

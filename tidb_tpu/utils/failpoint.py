@@ -47,6 +47,11 @@ def inject(name: str, *args):
     return action
 
 
+def is_enabled(name: str) -> bool:
+    with _mu:
+        return name in _active
+
+
 @contextmanager
 def enabled(name: str, action: object = True):
     enable(name, action)
