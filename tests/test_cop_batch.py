@@ -5,7 +5,14 @@ same partial results, row for row, as a task a region gives, and the host
 engine's answer; a region that is not clean leaves the batch and runs alone;
 the chaos seam fires once a region and a fault touches that region only; the
 sidecar, the ``tidb:cop.task`` span and the counter agree on how many regions
-a task served; a request that is not order-blind keeps a task a region."""
+a task served; a request that is not order-blind keeps a task a region.
+
+Inside the task (ISSUE 30) the regions that share a kernel key go to the
+device as ONE call of one mapped program (``dag_kernel.get_kernel``'s ``m``),
+its count padded up a ladder: the same partials still, one call a padded
+shape, one compiled program for 5, 6 or 7 regions, a program no larger for 48
+regions than for 8; a region that leaves shrinks the group, an overflow
+re-runs its region alone; sidecar, spans and counter agree on the calls."""
 
 import dataclasses
 import glob
@@ -88,6 +95,10 @@ def _counts():
     return metrics.COP_REGIONS.get(path="batched"), metrics.COP_REGIONS.get(path="single")
 
 
+def _programs():
+    return metrics.COP_PROGRAMS.get(form="mapped"), metrics.COP_PROGRAMS.get(form="single")
+
+
 # -- the same answers ----------------------------------------------------------
 
 
@@ -101,6 +112,7 @@ def test_batch_gives_each_regions_partials_row_for_row(served, shape, monkeypatc
     assert len(regions) >= 4
     (res,) = list(CopClient(db.store).send(req))
     assert res.details.regions == len(regions) and res.details.engine == "tpu" and not res.details.degraded
+    assert 1 <= res.details.programs < len(regions)  # mapped: one call a padded shape, its partials stacked
     one_by_one = [tpu_engine.execute_dag(db.store, req.data, r, rg, req.start_ts) for r, rg in regions]
     assert all(len(c) for c in one_by_one)  # a partial row (or group rows) from every region
     assert res.chunk.rows() == Chunk.concat(one_by_one).rows()
@@ -135,12 +147,16 @@ def wide():
     """``w.b`` fits int32 in the first regions and not in the later ones: the
     batch binds ONCE over the union of the regions' min/max, so its narrow-lane
     and magnitude proofs must hold for every region's device arrays, whichever
-    width each was uploaded in."""
+    width each was uploaded in. A mapped program stacks lanes of one width only,
+    so the batch is two groups. ``w.c`` is NULL in one row of 13 of the later
+    regions and in none of the first: stacked with its validity in one group,
+    without it in the other."""
     db = tidb_tpu.open(region_split_keys=1000)
     s = db.session()
     s.execute("CREATE TABLE w (id BIGINT PRIMARY KEY, b BIGINT, c INT, f CHAR(1))")
     ids = np.arange(4000, dtype=np.int64)
-    cols = [ids, np.where(ids < 1500, ids, ids * 3_000_000_000), ids % 5, np.array([b"A", b"B"])[ids % 2]]
+    c = [None if i % 13 == 0 and i >= 1500 else int(i % 5) for i in ids]  # NULLs: a group of their own, rows no comparison keeps
+    cols = [ids, np.where(ids < 1500, ids, ids * 3_000_000_000), c, np.array([b"A", b"B"])[ids % 2]]
     for lo in range(0, 4000, 500):
         bulk_load(db, "w", [c[lo : lo + 500] for c in cols])
     s.execute("SET tidb_isolation_read_engines = 'tpu'")
@@ -154,6 +170,117 @@ def test_one_bind_over_regions_of_different_widths(wide, text):
     rows, summary = _summary(s, text)
     assert summary.num == 1 and summary.regions >= 4 and summary.engines == {"tpu": 1}
     assert rows == _host(s, text)
+
+
+@pytest.mark.parametrize("text", WIDE)
+def test_mapped_partials_of_both_lane_widths_equal_each_regions_own(wide, text, monkeypatch):
+    """Two groups (``b`` as int32, ``b`` as int64), a call each; the stacked
+    results, unstacked, are the rows a call a region gives, in region order.
+    Only the second group's ``c`` holds NULLs and is stacked with its validity."""
+    db, s = wide
+    s.query(text)
+    (req,), rows = _requests(s, text, monkeypatch)
+    regions = list(db.store.pd.regions_in_ranges(req.ranges))
+    (res,) = list(CopClient(db.store).send(req))
+    assert res.details.regions == len(regions) >= 4 and res.details.programs == 2
+    entries = [tpu_engine.cache_for(db.store).head(r, req.data.executors[0].table_id, req.start_ts) for r, _ in regions]
+    c_id = next(c.column_id for c in req.data.executors[0].columns if not c.is_handle and not entries[-1].all_valid(c.column_id))
+    assert {e.all_valid(c_id) for e in entries} == {True, False}
+    one_by_one = [tpu_engine.execute_dag(db.store, req.data, r, rg, req.start_ts) for r, rg in regions]
+    assert res.chunk.rows() == Chunk.concat(one_by_one).rows()
+    assert rows == _host(s, text)
+
+
+# -- one program a padded shape ---------------------------------------------------
+
+
+def _mapped_keys():
+    """Kernel-cache keys of mapped programs: (n_pad, agg_cap, m)."""
+    with dag_kernel._CACHE_MU:
+        return sorted((key[1], key[2], key[6]) for key in dag_kernel._COMPILE_CACHE if key[6] > 1)
+
+
+def test_five_six_and_seven_regions_share_one_program_and_padding_adds_no_row():
+    """Regions of 1,000 rows, bulk-loaded one at a time so none is left half
+    full: each count of them is padded to the ladder's first step."""
+    db = tidb_tpu.open(region_split_keys=1000)
+    s = db.session()
+    s.execute("CREATE TABLE p (id BIGINT PRIMARY KEY, k INT, v BIGINT)")
+    s.execute("SET tidb_isolation_read_engines = 'tpu'")
+    text = "SELECT k, COUNT(*), SUM(v) FROM p GROUP BY k ORDER BY k"
+    ids = np.arange(6000, dtype=np.int64)
+
+    def load(lo):
+        bulk_load(db, "p", [ids[lo : lo + 1000], ids[lo : lo + 1000] % 3, ids[lo : lo + 1000] % 100])  # every load the same bounds: one bound DAG
+
+    for lo in range(0, 3000, 1000):
+        load(lo)
+    with dag_kernel._CACHE_MU:
+        dag_kernel._COMPILE_CACHE.clear()
+    seen = []
+    for lo in (3000, 4000, 5000):
+        load(lo)
+        s.query(text)  # the new region's first read: a task of its own
+        rows, summary = _summary(s, text)
+        assert rows == _host(s, text) and sum(r[1] for r in rows) == lo + 1000  # a padding slot counts no row
+        assert summary.num == 1 and summary.programs == 1
+        seen.append(summary.regions)
+        assert _mapped_keys() == [(1024, 1024, tpu_engine._MAP_STEP)]  # ONE entry, whatever the count
+    assert seen == [5, 6, 7]
+
+
+def test_mixed_padded_shapes_are_one_call_a_shape(monkeypatch):
+    db, s = _mk_db(rows=9300, split=4000)  # regions of 2,000 to 3,000 rows: padded to 2,048 and to 4,096
+    text = SHAPES["q6"]
+    s.query(text)
+    with dag_kernel._CACHE_MU:
+        dag_kernel._COMPILE_CACHE.clear()
+    rows, summary = _summary(s, text)
+    assert rows == _host(s, text) and summary.num == 1
+    with dag_kernel._CACHE_MU:
+        shapes = {(key[1], key[4]) for key in dag_kernel._COMPILE_CACHE}  # (n_pad, full_scan)
+    assert len(shapes) >= 2 and summary.programs == len(shapes) < summary.regions
+
+
+def _eqns(jaxpr) -> int:
+    import jax
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            n += _eqns(sub)
+    return n
+
+
+def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypatch):
+    """Compile cost must not grow with the region count: the body is traced
+    once, whatever ``m`` is."""
+    import jax
+
+    db, s = served
+    sent = []
+    real = tpu_engine._call_args
+    monkeypatch.setattr(tpu_engine, "_call_args", lambda kernel, *a: sent.append((kernel, real(kernel, *a))) or sent[-1][1])
+    bound = []
+    real_get = tpu_engine.get_kernel
+    monkeypatch.setattr(tpu_engine, "get_kernel", lambda dag, *a, **kw: bound.append((dag, a, kw)) or real_get(dag, *a, **kw))
+    for shape in sorted(SHAPES):
+        del sent[:], bound[:]
+        s.query(SHAPES[shape])
+        (kernel, (slots, _, _)), = [c for c in sent if c[0].m > 1]
+        dag, (n_pad, agg_cap), kw = next(b for b in bound if b[2]["m"] == kernel.m)
+        sizes = {}
+        for m in (8, 48):
+            k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"], m=m)
+            args = (slots[:1] * m, np.zeros((m, dag_kernel.MAX_RANGES, 2), np.int64), np.zeros(m, np.int64))
+            sizes[m] = _eqns(jax.make_jaxpr(k.fn)(*args).jaxpr)
+        handles, cols = slots[0]
+        cols = tuple((d, d != d if v is None else v) for d, v in cols)  # the single-region program takes every validity
+        single = _eqns(jax.make_jaxpr(dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"]).fn)(
+            cols[0][0] if handles is None else handles, cols, np.zeros((dag_kernel.MAX_RANGES, 2), np.int64), np.int64(0)).jaxpr)
+        assert abs(sizes[48] - sizes[8]) < 0.1 * sizes[8], sizes
+        assert sizes[48] < 1.5 * single + 40, (sizes, single)  # the body once, the stacking, the packing: not a body a region
 
 
 # -- a region that is not clean leaves ------------------------------------------
@@ -174,6 +301,8 @@ def test_region_with_a_pending_delta_leaves_and_the_answer_holds_the_write(monke
     assert after[0][0] - before[0][0] == 40  # 1000.00 * 0.04: the answer holds the write
     assert summary.num == 2 and summary.regions == n  # the batch, and the written region alone
     assert summary.delta_rows == 1 and summary.engines == {"tpu": 2} and not summary.degraded
+    # the group shrank by the region that left: still one mapped call, and the `_d` program of the region alone
+    assert summary.programs == 2 and _mapped_keys() and all(m == tpu_engine._MAP_STEP for _, _, m in _mapped_keys())
 
 
 def test_first_read_builds_each_region_in_a_task_of_its_own():
@@ -204,6 +333,7 @@ def test_engine_fault_degrades_that_region_alone(served):
     assert metrics.COP_DEGRADED.get(reason="embedded") == degraded + 1
     assert summary.num == 2 and summary.regions == clean.regions
     assert summary.engines == {"tpu": 1, "host": 1}  # the others stayed on the device, in the batch
+    assert 1 <= summary.programs <= clean.programs  # the group shrank by one region; the host engine sends no program
     assert summary.degraded == {"embedded:RuntimeError": 1}
     assert any("degraded to host" in str(w) for w in s.query("SHOW WARNINGS"))
 
@@ -269,6 +399,11 @@ def test_agg_cap_overflow_reruns_that_region_alone():
         caps = sorted(key[2] for key in dag_kernel._COMPILE_CACHE)
     assert caps[0] == 4096 and caps[-1] > 4096, caps
     assert sum(1 for c in caps if c > 4096) == 1  # one region's shape, once
+    with dag_kernel._CACHE_MU:
+        (grown,) = [key for key in dag_kernel._COMPILE_CACHE if key[2] > 4096]
+    assert grown[6] == 1  # the re-run is that region's alone: the single-region program
+    assert any(m > 1 and cap == 4096 for _, cap, m in _mapped_keys())  # it overflowed inside a mapped group
+    assert summary.programs < summary.regions
 
 
 # -- it says when it engages --------------------------------------------------------
@@ -280,6 +415,7 @@ def test_sidecar_span_and_counter_agree(served, tmp_path):
 
     db, s = served
     batched, single = _counts()
+    programs = _programs()
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     opts.host_tracer_level = 1
@@ -298,11 +434,15 @@ def test_sidecar_span_and_counter_agree(served, tmp_path):
     (task,) = spans["cop.task"]
     assert summary.num == 1 and summary.regions >= 4
     assert int(task["regions"]) == summary.regions
-    assert [int(d["regions"]) for d in spans["exec.dispatch"]] == [summary.regions]
+    # `regions` on the dispatch span is the programs sent: one a padded shape, not one a region
+    assert [int(d["regions"]) for d in spans["exec.dispatch"]] == [summary.programs] == [int(task["programs"])]
+    assert 1 <= summary.programs < summary.regions
+    assert all(d["kernel"].startswith("cop_sel_agg_g2") for d in spans["exec.dispatch"])
     assert len(spans["exec.fetch"]) == len(spans["exec.decode"]) == 1  # fetched once, decoded once
     assert _counts() == (batched + summary.regions, single)
+    assert sum(_programs()) == sum(programs) + summary.programs and _programs()[0] > programs[0]
     text = "\n".join(str(r) for r in s.query("EXPLAIN ANALYZE " + SHAPES["q1"]))
-    assert f"cop_task: {{num: 1," in text and f"regions: {summary.regions}," in text
+    assert f"cop_task: {{num: 1," in text and f"regions: {summary.regions}, programs: {summary.programs}," in text
 
 
 # -- what never batches ---------------------------------------------------------------
@@ -313,7 +453,7 @@ def test_one_region_request_takes_the_old_path(served):
     batched, single = _counts()
     rows, summary = _summary(s, "SELECT SUM(v) FROM t WHERE id < 10")
     assert rows[0][0] * 100 == sum(i * 150 + 25 for i in range(10))
-    assert summary.num == summary.regions == 1
+    assert summary.num == summary.regions == summary.programs == 1
     assert _counts() == (batched, single + 1)
 
 

@@ -167,10 +167,13 @@ def test_profiler_spans_of_pool_threads_carry_it_too(profiled):
     assert {e["_line"] for e in tasks} - {session_line}, "every cop task ran on the session's own thread"
     for e in tasks:
         assert e["engine"] == "tpu" and int(e["cpu_us"]) >= 0 and int(e["queue_us"]) >= 0 and "region" in e
-    assert {e["kernel"] for e in profiled["exec.dispatch"]} == {"cop_sel_agg_g1", "cop_sel_agg_g0"}
-    # a dispatch span says how many regions' programs it sent: the batch's many, the lone task's one
+    kernels = {e["kernel"] for e in profiled["exec.dispatch"]}
+    # the lone task's single-region program, and the batch's mapped one (`_m<regions a call>`)
+    assert {k.split("_m")[0] for k in kernels} == {"cop_sel_agg_g1", "cop_sel_agg_g0"} and any("_m" in k for k in kernels)
+    # a dispatch span says how many programs it sent: one a padded shape of a batch, the lone task's one
     assert sorted(int(e["regions"]) for e in profiled["exec.dispatch"])[:2] == [1, 1]
-    assert sum(int(e["regions"]) for e in profiled["exec.dispatch"]) == sum(int(e.get("regions", 1)) for e in tasks)
+    assert sum(int(e["regions"]) for e in profiled["exec.dispatch"]) == sum(int(e["programs"]) for e in tasks)
+    assert sum(int(e["programs"]) for e in tasks) < sum(int(e.get("regions", 1)) for e in tasks)
     assert {e["cache"] for e in profiled["plan"]} <= {"hit", "miss"}
 
 
@@ -343,6 +346,7 @@ def test_family_marks_the_delta_variant_and_fused_blocks(monkeypatch):
                             dagpb.ExecutorPB(dagpb.AGGREGATION, group_by=[{}, {}])])
     assert dag_kernel.kernel_family(dag) == "cop_sel_agg_g2"
     assert dag_kernel.kernel_family(dag, nb=4, delta_cap=8192) == "cop_sel_agg_g2_d_b4"
+    assert dag_kernel.kernel_family(dag, m=48) == "cop_sel_agg_g2_m48"  # a mapped program: 48 regions a call
     assert dag_kernel.kernel_family(dagpb.DAGRequest([dagpb.ExecutorPB(dagpb.TABLE_SCAN)])) == "cop_scan"
     topn = dagpb.DAGRequest([dagpb.ExecutorPB(dagpb.TABLE_SCAN), dagpb.ExecutorPB(dagpb.TOPN)])
     assert dag_kernel.kernel_family(topn) == "cop_topn"

@@ -12,7 +12,13 @@ device result (PERF.md §5, PR 26: 18 ms of wall for 3.2 ms of CPU a task,
 eight in flight). The pool overlaps only what waits outside the interpreter:
 the device's result, numpy's larger kernels, a store RPC. That is why a
 statement's clean regions go to the engine as ONE task where the request
-allows it, and why more connections do not answer more statements.
+allows it. Inside that task the regions that share a padded shape are ONE call
+of one MAPPED program (``ops/dag_kernel.get_kernel``'s ``m``: the
+single-region kernel run once a region along a leading axis, the partials
+returned stacked, one a region) — a group of one region, like every task that
+is not a batch, calls the single-region program — so a statement is a few
+milliseconds of Python and then a wait for the device OUTSIDE the interpreter
+lock: what more connections can overlap.
 
 The worker pool is ONE lazily-built process-wide executor (ref: the
 reference's copIteratorWorker goroutines being cheap — spawning an OS thread
@@ -497,7 +503,7 @@ class CopClient:
                 if span is not None:
                     span.note(
                         cpu_us=int((time.thread_time() - cpu0) * 1e6), h2d=det.h2d_bytes, d2h=det.d2h_bytes,
-                        engine=det.engine, regions=det.regions,
+                        engine=det.engine, regions=det.regions, programs=det.programs,
                     )
 
         def finish(det: _ed.CopExecDetails, t0: float, chunk: Chunk, path: str) -> None:

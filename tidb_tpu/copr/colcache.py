@@ -144,6 +144,7 @@ class RegionColumns:
     # per-slot (min, max) over valid values, computed lazily — feeds the
     # packed window-sort key (binder._window_bounds)
     _minmax: dict = field(default_factory=dict)
+    _all_valid: dict = field(default_factory=dict)  # per-slot "holds no NULL", found lazily (all_valid)
     # per-DEVICE_BLOCK_ROWS-block version tags carried across merges: a block
     # whose content provably did not change keeps its previous tag, so its
     # device arrays stay valid in the HBM LRU (None → data_version everywhere)
@@ -183,6 +184,14 @@ class RegionColumns:
             mm = (int(lv.min()), int(lv.max())) if lv.size else (0, 0)
             self._minmax[slot] = mm
         return mm
+
+    def all_valid(self, slot: int) -> bool:
+        """No NULL in this slot's rows. Found once: a slot's validity array is
+        never replaced (dictionary remaps replace its data only)."""
+        known = self._all_valid.get(slot)
+        if known is None:
+            known = self._all_valid[slot] = bool(self.cols[slot][1].all())
+        return known
 
 
 class ColumnCache:

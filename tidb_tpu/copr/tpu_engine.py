@@ -63,6 +63,10 @@ _DEFAULT_AGG_CAP = 4096
 # colcache.DEVICE_BLOCK_ROWS — both read TIDB_TPU_DEVICE_BLOCK_ROWS)
 _BLOCK = DEVICE_BLOCK_ROWS
 _FUSE_MAX_NB = 8  # fused multi-block programs: HBM holds inputs + the concat
+# mapped programs (one call for the regions of a batch that share a kernel key):
+# the region counts compiled for, and the padded rows one call may stack
+_MAP_STEP = 8
+_MAP_ROWS = 1 << 24
 
 
 def _delta_cap() -> int:
@@ -688,15 +692,45 @@ def _single_device_inputs(store, scan, cache, entry, region, n_pad):
     return handles_pair[0], cols_dev
 
 
+def _map_counts(k: int, n_pad: int) -> list[int]:
+    """How ``k`` regions of one kernel key go to the device: the region count
+    of each call, padded up the ladder — ``[48]`` for 46, 47 or 48 regions,
+    ``[64, 64, 64, 48]`` for 240 of 262,144 padded rows, ``[1]`` for one. The
+    ladder is the multiples of ``_MAP_STEP`` up to ``_MAP_ROWS`` padded rows a
+    call (a mapped program stacks its regions in HBM while it runs): a region
+    split or merge moves a group along it a step at most, so it rarely means a
+    compile on the query path, and a padding slot costs a region's device
+    time: at most 7 in a call. A remainder of one, and regions too large for
+    a step to fit, take the single-region program."""
+    top = _MAP_ROWS // n_pad // _MAP_STEP * _MAP_STEP
+    if k == 1 or not top:
+        return [1] * k
+    counts = [top] * (k // top)
+    rest = k % top
+    if rest:
+        counts.append(1 if rest == 1 else -(-rest // _MAP_STEP) * _MAP_STEP)
+    return counts
+
+
 def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=None) -> Chunk:
     """Regions of at most one block each (or COMPLETE-mode aggs): one padded
-    array and one kernel invocation a region. A task is one region, or the
-    many of a batch (``_batch_path``; ``bound`` then holds for all of them):
-    every region's program is dispatched before the ONE fetch, and the
-    results decode into one Chunk, region after region."""
+    array a region. A task is one region, or the many of a batch
+    (``_batch_path``; ``bound`` then holds for all of them). The regions that
+    share a kernel key — padded shape, full-scan proof, group cap, and each
+    lane's dtype and whether it holds a NULL: what must be equal to stack them
+    — are a GROUP, answered by one call of one MAPPED program
+    (``dag_kernel.get_kernel``'s ``m``: the single-region kernel run once a
+    region along a leading axis) and one stacked result; ``_map_counts`` pads
+    the count up a short ladder, a padding slot being a resident region's
+    arrays again with ``nvalid`` 0 (no H2D, no new HBM), its result dropped
+    before the decode. A group of one region — every task that is not a batch,
+    a region read through its delta, an overflow's re-run, the odd region of
+    another size — calls today's single-region program: there is no second
+    path for it. Every call is dispatched before the ONE fetch; partials stay
+    one a region, and decode into one Chunk, region after region."""
     needs_agg = kernel_needs_agg(bound)
     ph.to("inputs")
-    runs = []  # a region: [n_pad, full_scan, delta_cap, agg_cap, the program's arguments]
+    runs = []  # a region: [its kernel key (n_pad, full_scan, delta_cap, agg_cap, lanes), the arguments of its own]
     for entry, region, rarr, delta in parts:
         n_pad = bucket_size(max(entry.n, 1))
         handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad)
@@ -706,53 +740,92 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
             dcap = _delta_cap()
             dh, dcols, dtomb = _delta_device_inputs(store, scan, cache, delta, region)
             dargs = (dh, dcols, dtomb, _delta_counts(delta.n, 0, delta.n))
-        args = (handles_dev, tuple(cols_dev), _device_ranges(rarr), _device_nvalid(entry.n), *dargs)
         agg_cap = min(_DEFAULT_AGG_CAP, n_pad + dcap) if needs_agg else _DEFAULT_AGG_CAP
-        runs.append([n_pad, _covers_all(rarr, entry, delta), dcap, agg_cap, args])
+        # _narrowed picks int32 or int64 a region: lanes stack only at one width;
+        # a lane with no NULL in it (the handle's never has one) is stacked without its validity
+        lanes = tuple((d.dtype, c.is_handle or entry.all_valid(c.column_id)) for c, (d, _) in zip(scan.columns, cols_dev))
+        key = (n_pad, _covers_all(rarr, entry, delta), dcap, agg_cap, lanes)
+        runs.append([key, (handles_dev, tuple(cols_dev), rarr, entry.n, dargs)])
     results: list = [None] * len(runs)
     todo = list(range(len(runs)))
     while todo:
         ph.to("bind")
-        kernels: dict = {}  # one lookup (a fingerprint of the DAG) per distinct padded shape, not per region
-        calls = []
+        groups: dict = {}
         for i in todo:
-            n_pad, fs, dcap, agg_cap, args = runs[i]
-            key = (n_pad, fs, dcap, agg_cap)
-            if key not in kernels:
-                kernels[key] = get_kernel(bound, n_pad, agg_cap, full_scan=fs, delta_cap=dcap)
-            calls.append((kernels[key], args))
+            groups.setdefault(runs[i][0], []).append(i)
+        sends = []  # [kernel, its key, the regions it answers]; one kernel lookup (a fingerprint of the DAG) per key and count, not per region
+        for key, members in groups.items():
+            n_pad, fs, dcap, agg_cap, _ = key
+            # only clean regions stack: one read through its delta comes alone, and goes alone
+            counts = [1] * len(members) if dcap else _map_counts(len(members), n_pad)
+            at = 0
+            for m in counts:
+                sends.append((get_kernel(bound, n_pad, agg_cap, full_scan=fs, delta_cap=dcap, m=m), key, members[at : at + m]))
+                at += m
+        ph.to("inputs")
+        calls = [(kernel, _call_args(kernel, [runs[i][1] for i in live], key), live) for kernel, key, live in sends]
+        answered = [(i, kernel) for kernel, _, live in calls for i in live]
         over = []
-        for i, (kernel, _), got in zip(todo, calls, _run_all(ph, calls)):
+        for (i, kernel), got in zip(answered, _run_all(ph, calls)):
             ngroups = int(got[0][0, 1])
             if ngroups <= kernel.agg_cap:
                 results[i] = (*got, kernel)
                 continue
             # an overflow re-runs that region alone, at the cap that holds it
-            n_pad, _, dcap, agg_cap, _ = runs[i]
+            n_pad, fs, dcap, agg_cap, lanes = runs[i][0]
             if agg_cap >= n_pad + dcap:
                 # more groups than rows cannot happen; n_pad cap always fits
                 raise RuntimeError("aggregation group overflow beyond row count")
-            runs[i][3] = _grown_cap(agg_cap, ngroups, n_pad + dcap)
+            runs[i][0] = (n_pad, fs, dcap, _grown_cap(agg_cap, ngroups, n_pad + dcap), lanes)
             over.append(i)
         todo = over
     return _decode(ph, results, dag, cache, scan, warn)
 
 
+def _call_args(kernel, regions: list, key: tuple) -> tuple:
+    """The arguments of one program call over ``regions`` (each ``(handles,
+    cols, rarr, n, delta args)``) of the kernel key ``key``: a region's own for
+    the single-region program; for a mapped one the regions' arrays slot by
+    slot — less those it need not read: the handles of a full scan, the
+    validity of a lane without a NULL — its slots past the last region filled
+    with the first region's arrays and ``nvalid`` 0. Ranges and counts are
+    device-resident, cached by value."""
+    import jax.numpy as jnp
+
+    if kernel.m == 1:
+        ((handles, cols, rarr, n, dargs),) = regions
+        return (handles, cols, _device_ranges(rarr), _device_nvalid(n), *dargs)
+    _, full_scan, _, _, lanes = key
+    slots = regions + regions[:1] * (kernel.m - len(regions))
+    counts = tuple(r[3] for r in regions) + (0,) * (kernel.m - len(regions))
+    return (
+        tuple(
+            (None if full_scan else handles, tuple((d, None if no_null else v) for (d, v), (_, no_null) in zip(cols, lanes)))
+            for handles, cols, *_ in slots
+        ),
+        _device_ranges(np.stack([r[2] for r in slots])),
+        _misc_cached(_NVALID_DEV, ("m", counts), lambda: jnp.asarray(np.array(counts, dtype=np.int64))),
+    )
+
+
 def _run_all(ph, calls: list) -> list:
-    """Dispatch every ``(kernel, args)`` without waiting, then fetch: one
-    ``(buf, fbuf, count)`` on the host for each. ONE device→host round trip
-    for them all: device_get batches every buffer of every packed result into
-    a single transfer — one call a result, or two sequential np.asarray
-    calls, would pay the round trip again and again. Exception: large
-    rows-kind buffers spend a second tiny RTT on the meta row and transfer
-    only the live slice (_probe_slice_rows)."""
+    """Dispatch every ``(kernel, args, live)`` without waiting, then fetch:
+    one ``(buf, fbuf, count)`` on the host for each region answered — one a
+    call of a single-region program; ``len(live)`` a call of a mapped one,
+    unstacked from its ``(m, ...)`` buffers, the padding slots' dropped. ONE
+    device→host round trip for them all: device_get batches every buffer of
+    every packed result into a single transfer — one call a result, or two
+    sequential np.asarray calls, would pay the round trip again and again.
+    Exception: large rows-kind buffers spend a second tiny RTT on the meta row
+    and transfer only the live slice (_probe_slice_rows)."""
     import jax
 
     ph.to("dispatch", kernel=calls[0][0].family, regions=len(calls))
-    packed = [kernel.fn(*args) for kernel, args in calls]
+    packed = [kernel.fn(*args) for kernel, args, _ in calls]
+    _count_programs(calls)
     ph.to("fetch")
-    for i, (kernel, _) in enumerate(calls):
-        if kernel.kind == "rows" and kernel.out_n > 65536:
+    for i, (kernel, _, _) in enumerate(calls):
+        if kernel.kind == "rows" and kernel.out_n > 65536 and kernel.m == 1:
             _, (packed[i],) = _probe_slice_rows([packed[i]], kernel)
     fetched = jax.device_get(packed)
     # the device results end HERE, inside the phase that waited for them, and
@@ -761,10 +834,26 @@ def _run_all(ph, calls: list) -> list:
     with _tracing.region("exec.release"):
         del packed
     out = []
-    for got in fetched:
+    for (kernel, _, live), got in zip(calls, fetched):
         buf, fbuf = got if isinstance(got, tuple) else (got, None)
-        out.append((buf, fbuf, int(buf[0, 0])))
+        if kernel.m == 1:
+            out.append((buf, fbuf, int(buf[0, 0])))
+        else:
+            out.extend((buf[r], None if fbuf is None else fbuf[r], int(buf[r, 0, 0])) for r in range(len(live)))
     return out
+
+
+def _count_programs(calls: list) -> None:
+    """Program calls a task sent: the sidecar's ``programs`` and the counter,
+    by form (``mapped``: one call, many regions)."""
+    mapped = sum(1 for kernel, _, _ in calls if kernel.m > 1)
+    det = _ed.current_cop()
+    if det is not None:
+        det.programs += len(calls)
+    if mapped:
+        _metrics.COP_PROGRAMS.inc(mapped, form="mapped")
+    if len(calls) > mapped:
+        _metrics.COP_PROGRAMS.inc(len(calls) - mapped, form="single")
 
 
 def _decode(ph, results: list, dag, cache, scan, warn) -> Chunk:
@@ -829,6 +918,7 @@ def _exec_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=N
                 # never double-count and block outputs concat in handle order
                 args += (*dinp, _delta_counts(delta.n, dcuts[bi], dcuts[bi + 1]))
             ph.to("dispatch", kernel=kernel.family)
+            _count_programs([(kernel, args, None)])
             return kernel.fn(*args)
 
         if limit_last:
@@ -900,7 +990,7 @@ def _exec_fused_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, 
     fs = _covers_all(rarr, entry, delta)
     while True:
         kernel = get_kernel(bound, _BLOCK, agg_cap, nb=nb, full_scan=fs, delta_cap=dcap)
-        ((buf, fbuf, count),) = _run_all(ph, [(kernel, args)])
+        ((buf, fbuf, count),) = _run_all(ph, [(kernel, args, None)])
         if int(buf[0, 1]) <= kernel.agg_cap:
             break
         if agg_cap >= n_total + dcap:

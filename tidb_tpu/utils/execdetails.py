@@ -57,7 +57,7 @@ class CopExecDetails:
         "host_ms", "compile_ms", "h2d_bytes", "d2h_bytes", "dev_cache_hits",
         "dev_cache_misses", "engine", "degraded", "retries", "backoff_ms",
         "resplits", "delta_rows", "merges", "keys_scanned", "bytes_scanned",
-        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions",
+        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions", "programs",
     )
 
     def __init__(self, region_id: int = -1, store: str = ""):
@@ -65,6 +65,10 @@ class CopExecDetails:
         # regions this task served: many for a batch task of the embedded client
         # (copr/client.py); a store server serves one a request, so not on the wire
         self.regions = 1
+        # device program calls this task sent: a batch task sends one MAPPED
+        # program for the regions that share a padded shape (tpu_engine._exec_single),
+        # so far fewer than ``regions``; 0 = the host engine answered
+        self.programs = 0
         self.store = store  # "" = embedded (local) store
         self.queue_ms = 0.0  # send-queue wait before a worker picked it up
         self.wire_ms = 0.0  # RPC wall minus store-side processing (remote)
@@ -131,6 +135,8 @@ class CopExecDetails:
             out["sk"] = self.keys_scanned
         if self.bytes_scanned:
             out["sb"] = self.bytes_scanned
+        if self.programs:
+            out["pg"] = self.programs
         for key, attr in _PHASE_PB:
             v = getattr(self, attr)
             if v:
@@ -159,6 +165,7 @@ class CopExecDetails:
         self.merges += int(pb.get("mg", 0))
         self.keys_scanned += int(pb.get("sk", 0))
         self.bytes_scanned += int(pb.get("sb", 0))
+        self.programs += int(pb.get("pg", 0))
         for key, attr in _PHASE_PB:
             if key in pb:
                 setattr(self, attr, getattr(self, attr) + float(pb[key]))
@@ -178,7 +185,7 @@ class CopTasksSummary:
         "h2d_bytes", "d2h_bytes", "dev_cache_hits", "dev_cache_misses",
         "engines", "degraded", "retries", "backoff_ms", "resplits",
         "delta_rows", "merges", "keys_scanned", "bytes_scanned",
-        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions",
+        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions", "programs",
     )
 
     def __init__(self):
@@ -206,6 +213,7 @@ class CopTasksSummary:
         self.max_task_region = -1
         self.phases_ms = [0.0] * len(PHASES)
         self.regions = 0  # regions the tasks served: above ``num`` where tasks were batches
+        self.programs = 0  # device program calls the tasks sent: below ``regions`` where a batch mapped them
 
     @property
     def num(self) -> int:
@@ -234,6 +242,7 @@ class CopTasksSummary:
         self.keys_scanned += d.keys_scanned
         self.bytes_scanned += d.bytes_scanned
         self.regions += d.regions
+        self.programs += d.programs
         for i, (_key, attr) in enumerate(_PHASE_PB):
             self.phases_ms[i] += getattr(d, attr)
         if d.proc_ms >= self.max_proc_ms:
@@ -260,6 +269,7 @@ class CopTasksSummary:
             f"backoff: {self.backoff_ms:.0f}ms",
             f"resplits: {self.resplits}",
             f"regions: {self.regions}",
+            f"programs: {self.programs}",
         ]
         if self.queue_ms:
             parts.append(f"queue: {self.queue_ms / n:.1f}ms")  # avg send-queue wait

@@ -236,6 +236,11 @@ COP_REGIONS = REGISTRY.counter(
     "Regions served by embedded cop tasks: batched = inside a many-region task, single = a task of their own",
     ("path",),
 )
+COP_PROGRAMS = REGISTRY.counter(
+    "tidb_tpu_cop_programs_total",
+    "Device program calls sent by cop tasks: mapped = one call answering many regions of a batch task, single = one region",
+    ("form",),
+)
 STORE_FAILOVER = REGISTRY.counter(
     "tidb_tpu_store_failover_total",
     "Sharded-fleet reads/authority calls served by a non-primary replica",
