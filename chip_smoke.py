@@ -140,8 +140,8 @@ class Orchestrator:
 
     def phase_store_server(self) -> dict:
         """Phase A: the store server is started the way an operator starts
-        it; one region per chip (bench.py's standing layout) via its config
-        file. It must leave with exit 0 on SIGTERM."""
+        it; one region a table (the layout that reaches the block paths) via
+        its config file. It must leave with exit 0 on SIGTERM."""
         with tempfile.TemporaryDirectory(prefix="chip_smoke_") as tmp:
             cfg = os.path.join(tmp, "store.toml")
             with open(cfg, "w") as f:

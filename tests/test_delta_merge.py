@@ -137,9 +137,10 @@ def test_append_only_ingest_carries_prefix_blocks(deltadb):
     s = deltadb.session()
     s.execute("SET tidb_isolation_read_engines = 'tpu'")
     q = "SELECT COUNT(*), SUM(v) FROM d"
+    h_cold = _h2d()
     s.query(q)
     s.query(q)
-    h_warm = _h2d()
+    warm = _h2d() - h_cold  # what the four blocks cost to bring up
     # 200-row columnar append (> CAP → merge path with tail carry)
     bulk_load(
         deltadb,
@@ -156,6 +157,7 @@ def test_append_only_ingest_carries_prefix_blocks(deltadb):
     assert out[0][0] == 1200
     # only the dirty tail block(s) ship; prefix blocks carry their arrays
     assert paid < 3.5 * BLOCK * 10 * 2, f"append re-uploaded the table ({paid} bytes)"
+    assert paid < 0.6 * warm, f"append re-uploaded {paid} of the warm-up's {warm} bytes"
     t, h = both(deltadb, Q1)
     assert t == h
 

@@ -338,13 +338,6 @@ def test_election_status_endpoint_and_metrics():
         srv.close()
 
 
-def test_owner_failover_bench_runs():
-    from tidb_tpu.bench.benchdaily import run_all
-
-    recs = run_all(["owner_failover_ms"])
-    assert len(recs) == 1 and recs[0]["ms"] > 0
-
-
 def test_resolve_undetermined_reports_commit_and_rollback():
     """The check_txn_status-driven resolver (ROADMAP: undetermined-commit
     resolution). Wire-level UndeterminedError coverage lives in

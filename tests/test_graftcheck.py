@@ -6,6 +6,7 @@ output, and the python -O regression test for the converted asserts."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -32,9 +33,9 @@ def _scan_src(path, src, rules):
 
 
 def test_verdict_scripts_are_linted_for_swallows_and_asserts_only():
-    """bench.py / chip_smoke.py exit with a verdict: a swallowed exception or
-    a -O-stripped assert there reads as a pass. Those two rules reach into
-    the corpus for them; no other rule and no other corpus file is linted."""
+    """chip_smoke.py exits with a verdict: a swallowed exception or a
+    -O-stripped assert there reads as a pass. Those two rules reach into
+    the corpus for it; no other rule and no other corpus file is linted."""
     src = (
         "import threading\n"
         "def main():\n"
@@ -56,7 +57,7 @@ def test_verdict_scripts_are_linted_for_swallows_and_asserts_only():
         ("chip_smoke.py", "opt-assert", 8),
     ]
     assert r.suppressed == 1
-    assert "chip_smoke.py" in build_tree(ROOT).scripts and "bench.py" in build_tree(ROOT).scripts
+    assert list(build_tree(ROOT).scripts) == ["chip_smoke.py"]
 
 
 def test_opt_assert_flags_load_bearing_and_allows_narrowing():
@@ -95,7 +96,7 @@ def test_eventlog_discipline_rule():
     )
     assert not _scan_src("tidb_tpu/kv/x.py", ok, ["eventlog-discipline"]).findings
     # CLI surfaces whose contract IS stdout are exempt
-    for path in ("tidb_tpu/tools/x.py", "tidb_tpu/bench/x.py", "tidb_tpu/kv/__main__.py"):
+    for path in ("tidb_tpu/tools/x.py", "tidb_tpu/kv/__main__.py"):
         assert not _scan_src(path, bad, ["eventlog-discipline"]).findings
     # an explicit suppression silences the line
     sup = bad.replace("print('migrated', x)", "print('migrated', x)  # graftcheck: off=eventlog-discipline")
@@ -684,3 +685,42 @@ def test_except_swallow_suppression_names_the_reason():
     )
     r = _scan_src("tidb_tpu/kv/x.py", ok, ["except-swallow"])
     assert not r.findings and r.suppressed == 1
+
+
+# -- one yardstick: speed is benchmark/run.py -> BENCHMARK.json -> PERF_LEDGER.jsonl ----
+
+_HARNESS_NAMED = r"benchdaily|benchdb|(?<![\w/])bench\.py|bench/qps"
+_HARNESS_RUN = (
+    r"tidb_tpu\.bench\.(?!tpchlike)\w|from tidb_tpu\.bench import (?!tpchlike)"
+    r"|python3? +(-\S+ +)*(\./)?bench\.py"
+)
+# the driver's and the reviewers' files, and the seed's records, are history
+_HISTORY = {"CHANGES.md", "ISSUE.md", "SURVEY.md", "BASELINE.md", "REVIEW.md", "ADVICE.md"}
+# these say what was deleted (and this file holds the patterns): no command, no import
+_RECORDS = {"ROADMAP.md", "PERF.md", "tests/test_graftcheck.py"}
+
+
+def test_the_tree_has_one_way_to_measure():
+    """PR 31 retired the four pre-chip harnesses (`bench.py`, `tidb_tpu/bench/`'s
+    benchdaily, benchdb and qps): every timing they printed was a CPU's. No
+    source or document may send anyone to one again, so a lane cannot grow back."""
+    assert sorted(n for n in os.listdir(os.path.join(ROOT, "tidb_tpu", "bench")) if n.endswith(".py")) == [
+        "__init__.py", "tpchlike.py",
+    ]
+    assert not os.path.exists(os.path.join(ROOT, "bench.py"))
+    with open(os.path.join(ROOT, ".gitignore")) as f:
+        ignored = {".git"} | {ln.strip().rstrip("/") for ln in f if ln.strip().endswith("/")}
+    named, run = re.compile(_HARNESS_NAMED + "|" + _HARNESS_RUN), re.compile(_HARNESS_RUN)
+    found = []
+    for base, dirs, names in os.walk(ROOT):
+        def rel(n):
+            return os.path.relpath(os.path.join(base, n), ROOT).replace(os.sep, "/")
+
+        dirs[:] = [d for d in dirs if d not in ignored and rel(d) not in ignored]
+        for n in names:
+            if not n.endswith((".py", ".md")) or rel(n) in _HISTORY:
+                continue
+            pat = run if rel(n) in _RECORDS else named
+            with open(os.path.join(base, n), encoding="utf-8", errors="replace") as f:
+                found += [f"{rel(n)}:{i}: {ln.strip()[:120]}" for i, ln in enumerate(f, 1) if pat.search(ln)]
+    assert not found, "\n".join(found)

@@ -69,6 +69,24 @@ def test_two_tables_of_one_schema_and_different_sizes_do_not_share_an_executable
     assert _rows(s, JOIN.format(orders="o1", lines="l1")) == _want(FEW)
 
 
+def test_a_second_table_pair_in_the_same_buckets_finds_the_first_pair_s_program(db):
+    """The reuse the cache exists for: the same statement shape over OTHER
+    tables of other sizes, every lane's padded length and every key's bounds
+    in the first pair's power-of-two bucket, is answered without a compile."""
+    from tidb_tpu.utils import metrics
+
+    s = _session(db)
+    nearly = ALL[:90]
+    _fill(db, "o1", "l1", ALL)
+    _fill(db, "o2", "l2", nearly)
+    assert _rows(s, JOIN.format(orders="o1", lines="l1")) == _want(ALL)  # pays the one compile
+    programs = len(gather._MPP_FN_CACHE)
+    missed = metrics.MPP_PROGRAM_CACHE.get(result="miss")
+    assert _rows(s, JOIN.format(orders="o2", lines="l2")) == _want(nearly)
+    assert metrics.MPP_PROGRAM_CACHE.get(result="miss") == missed and len(gather._MPP_FN_CACHE) == programs
+    assert s.mpp_details[-1].compiles == 0
+
+
 def test_a_single_reader_gather_whose_table_crosses_a_bucket_is_answered(db):
     """The group capacity comes from ANALYZE's NDV, not from the rows: the spec
     is the same before and after the table grows fivefold."""

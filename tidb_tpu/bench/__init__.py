@@ -1,2 +1,2 @@
-"""Benchmark harnesses (ref: cmd/benchdb workload CLI + util/benchdaily
-JSON trend emitter)."""
+"""`tpchlike`: the data and statements of `chip_smoke.py`. The benchmark is
+`benchmark/run.py` (`BENCHMARK.json`), outside the package."""

@@ -1,10 +1,11 @@
-"""The TPC-H-shaped tables and statements `bench.py` and `chip_smoke.py`
-share: one seeded generator, one loader, one set of query texts, so the
-benchmark and the smoke cannot drift apart on row widths or SQL.
+"""`chip_smoke.py`'s data: the tables, the loader and the statement texts of
+the on-chip smoke, and nothing else's. It is never a benchmark cell and
+nothing it runs is timed as a speed: the cells are `BENCHMARK.json`'s, their
+data `benchmark/generators/tpch.py`'s.
 
-The data is NOT dbgen's: `lineitem` is seven uniform-random columns (four
-DECIMAL(12,2), two VARCHAR(1), one DATE), and the Q3-shaped join runs on two
-narrow tables (`lineitem2` ⋈ `orders`, 10 : 1). ROADMAP B1 replaces it.
+The data is NOT TPC-H: `lineitem` is seven uniform-random columns (four
+DECIMAL(12,2), two VARCHAR(1), one DATE) under TPC-H's column names, and the
+Q3-shaped join runs on two narrow tables (`lineitem2` ⋈ `orders`, 10 : 1).
 """
 
 from __future__ import annotations
@@ -25,9 +26,9 @@ Q6 = """SELECT SUM(l_extendedprice * l_discount) FROM lineitem
   WHERE l_shipdate >= DATE '1994-01-01' AND l_shipdate < DATE '1995-01-01'
     AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24"""
 
-# the remaining BASELINE.json configs: full-scan count, Q10-style TopN
-# pushdown, Q3-style MPP join (2-way exchange); plus a windowed config
-# (ranking + framed agg over sorted partitions — the device window kernel)
+# full-scan count, Q10-style TopN pushdown, Q3-style MPP join (2-way
+# exchange), and a windowed statement (ranking + framed agg over sorted
+# partitions — the device window kernel)
 WINDOWED = """SELECT l_returnflag, MAX(rn), MAX(cum) FROM (
     SELECT l_returnflag,
            ROW_NUMBER() OVER (PARTITION BY l_returnflag ORDER BY l_extendedprice) AS rn,

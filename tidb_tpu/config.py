@@ -95,12 +95,8 @@ class Config:
     eventlog_debug_capacity: int = 512
     eventlog_error_capacity: int = 1024
     # [perf] instance-level serving: capacity (entries) of EACH cross-session
-    # cache (statement ASTs / plan templates, planner/instcache.py), and the
-    # optional point-get batcher collection window in microseconds — 0 keeps
-    # coalescing purely opportunistic (zero added latency: batches form from
-    # readers that land while a flush is already in flight)
+    # cache (statement ASTs / plan templates, planner/instcache.py)
     instance_plan_cache_size: int = 512
-    pointget_batch_window_us: float = 0.0
     # [perf] delta+merge device column cache (copr/colcache.py): DML lands in
     # bounded per-(region, table) delta overlays the device kernel reads as
     # ``base ⊕ delta``. device-delta-cap is the FIXED kernel delta-operand
@@ -183,9 +179,6 @@ class Config:
         perf = raw.get("perf", {})
         cfg.instance_plan_cache_size = int(
             perf.get("instance-plan-cache-size", cfg.instance_plan_cache_size)
-        )
-        cfg.pointget_batch_window_us = float(
-            perf.get("pointget-batch-window-us", cfg.pointget_batch_window_us)
         )
         cfg.device_delta_cap = int(perf.get("device-delta-cap", cfg.device_delta_cap))
         cfg.device_delta_merge_rows = int(

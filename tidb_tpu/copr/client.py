@@ -194,8 +194,8 @@ class PointGetBatcher:
     flush is in flight queue up and ride the NEXT flush as one batch. N
     concurrent sessions therefore pay one RPC + one store dispatch instead
     of N, while an uncontended reader dispatches exactly as fast as before.
-    An optional collection window ([perf] pointget-batch-window-us) lets the
-    flusher sleep sub-ms per round to grow batches at a latency cost.
+    A collection window (``window_s``; 0 as served) lets the flusher sleep
+    sub-ms per round to grow batches at a latency cost.
 
     Outcomes are delivered PER KEY (bytes | None | exception): one session's
     locked key or dead shard never fails the strangers sharing its batch.
@@ -275,12 +275,7 @@ def point_batcher(store) -> PointGetBatcher:
         with _BATCHER_MU:
             b = getattr(store, "_pointget_batcher", None)
             if b is None:
-                from tidb_tpu import config as _config
-
-                b = PointGetBatcher(
-                    store, window_s=_config.current().pointget_batch_window_us / 1e6
-                )
-                store._pointget_batcher = b
+                b = store._pointget_batcher = PointGetBatcher(store)
     return b
 
 

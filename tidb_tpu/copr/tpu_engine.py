@@ -282,9 +282,7 @@ def _block_bounds(n: int) -> list[tuple[int, int]]:
 
 
 def _should_fuse_agg(dag: dagpb.DAGRequest, entry) -> bool:
-    """Big-table agg-last DAGs run as ONE fused multi-block dispatch —
-    shared by production routing and the bench probe so the probe always
-    times exactly what production runs."""
+    """Big-table agg-last DAGs run as ONE fused multi-block dispatch."""
     agg_last = bool(dag.executors[1:]) and dag.executors[-1].tp in (
         dagpb.AGGREGATION,
         dagpb.STREAM_AGG,
@@ -294,7 +292,7 @@ def _should_fuse_agg(dag: dagpb.DAGRequest, entry) -> bool:
 
 def _fused_block_inputs(store, scan, cache, entry, region):
     """(handles_blocks, cols_blocks, nvalids, nb) for the fused multi-block
-    kernel — one construction site for production and the probe."""
+    kernel."""
     import jax.numpy as jnp
 
     bounds = _block_bounds(entry.n)
@@ -668,8 +666,7 @@ def _grown_cap(agg_cap: int, ngroups: int, ceiling: int) -> int:
 
 def _single_device_inputs(store, scan, cache, entry, region, n_pad):
     """(handles_dev, cols_dev) for the single-kernel path, via the same LRU
-    identities as repeat queries — shared by _exec_single and the bench
-    probe so their device-cache keys can never drift apart."""
+    identities as repeat queries."""
     epoch = cache.epoch
     cacheable = entry.complete
     ver = entry.vtag_span(0, entry.n)
@@ -1169,89 +1166,3 @@ def AggFromPb(pb):
     from tidb_tpu.expression.expr import AggDesc
 
     return AggDesc.from_pb(pb)
-
-
-def device_probe_fn(store, dag, region, ranges, read_ts):
-    """(run_once, sync) over the same cached kernel + device inputs the
-    production dispatch uses for scan→filter→agg/topn tasks — blocked when
-    the region exceeds one device block, single-kernel otherwise, matching
-    _execute_dag_device's routing. Task shapes that production would host-
-    fallback or window-fuse are REJECTED (ValueError) rather than timed
-    with a kernel production never runs. Dispatching run_once K times and
-    syncing once amortizes the host↔device round trip out of a timing,
-    isolating on-chip throughput (bench.py's chip probe)."""
-    import jax
-    import jax.numpy as jnp
-
-    scan = dag.executors[0]
-    if scan.desc or len(ranges) > MAX_RANGES:
-        raise ValueError("probe unsupported: task would take the host fallback")
-    if any(ex.tp == dagpb.WINDOW for ex in dag.executors[1:]):
-        raise ValueError("probe unsupported: windowed tasks fuse blocks differently")
-    schema = RowSchema(scan.storage_schema)
-    slots = [c.column_id for c in scan.columns if not c.is_handle]
-    cache = cache_for(store)
-    entry = cache.get(region, scan.table_id, schema, slots, read_ts)
-    bound = Binder(cache, scan.table_id, scan.columns, entry).bind_dag(dag)
-    rarr = _ranges_array(ranges, scan.table_id)
-    rj = jnp.asarray(rarr)
-    cacheable = entry.complete
-    agg_complete = any(
-        ex.tp in (dagpb.AGGREGATION, dagpb.STREAM_AGG) and ex.agg_mode == dagpb.AGG_COMPLETE
-        for ex in dag.executors[1:]
-    )
-
-    if _should_fuse_agg(dag, entry):
-        # production fuses agg blocks into one dispatch — probe the same
-        handles_blocks, cols_blocks, nvalids, nb = _fused_block_inputs(store, scan, cache, entry, region)
-        kernel = get_kernel(bound, _BLOCK, _DEFAULT_AGG_CAP, nb=nb, full_scan=_covers_all(rarr, entry))
-
-        def run_once():
-            return [
-                kernel.fn(
-                    tuple(handles_blocks),
-                    tuple(tuple(cb) for cb in cols_blocks),
-                    rj,
-                    nvalids,
-                )
-            ]
-
-    elif entry.n > _BLOCK and not agg_complete:
-        if dag.executors[1:] and dag.executors[-1].tp == dagpb.LIMIT:
-            # production streams blocks with early exit here; eager dispatch
-            # would time a pattern production never runs
-            raise ValueError("probe unsupported: LIMIT-last blocked tasks page lazily")
-        bounds = _block_bounds(entry.n)
-        kernel = get_kernel(bound, _BLOCK, _DEFAULT_AGG_CAP, full_scan=_covers_all(rarr, entry))
-        inputs = [
-            _block_device_inputs(store, scan, cache, entry, region, bi, lo, hi, cacheable)
-            for bi, (lo, hi) in enumerate(bounds)
-        ]
-        nvals = [jnp.asarray(hi - lo) for lo, hi in bounds]
-
-        def run_once():
-            return [kernel.fn(h, cols, rj, nvals[bi]) for bi, (h, cols) in enumerate(inputs)]
-
-    else:
-        n_pad = bucket_size(max(entry.n, 1))
-        hd, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad)
-        agg_cap = min(_DEFAULT_AGG_CAP, n_pad) if kernel_needs_agg(bound) else _DEFAULT_AGG_CAP
-        kernel = get_kernel(bound, n_pad, agg_cap, full_scan=_covers_all(rarr, entry))
-        nv = jnp.asarray(entry.n)
-
-        def run_once():
-            return [kernel.fn(hd, tuple(cols_dev), rj, nv)]
-
-    if kernel.kind == "agg":
-        # production retries overflowed caps with a 4x-larger kernel; a probe
-        # timing the too-small kernel would report a fantasy number
-        for pk in run_once():
-            buf = pk[0] if isinstance(pk, tuple) else pk
-            if int(jax.device_get(buf[0, 1])) > kernel.agg_cap:
-                raise ValueError("probe unsupported: agg cap overflow (production re-runs bigger)")
-
-    def sync(outs):
-        last = outs[-1]
-        jax.device_get((last[0] if isinstance(last, tuple) else last)[:1, :1])
-
-    return run_once, sync

@@ -2,8 +2,8 @@
 
 Reference parity: pkg/lightning local backend + IMPORT INTO (disttask) —
 bypasses per-statement SQL overhead and writes encoded rows straight through
-a transaction in batches. Used by bench/bootstrap; the SQL surface for it
-(IMPORT INTO) can layer on later.
+a transaction in batches. Used by the benchmark's load, the smoke and bootstrap; IMPORT INTO is its
+SQL surface.
 """
 
 from __future__ import annotations

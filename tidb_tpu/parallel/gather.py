@@ -81,10 +81,9 @@ _SHARD_OBS: dict = {"t0": 0.0, "sink": None}
 # host callback (the jax.debug.callback never enters the jaxpr) and its
 # executable persists in the compile cache — with one, every process compiled
 # every program again (12 compiles, 652 s of a 1,084 s set-up on a v5e;
-# builder's chip run, PR 28). A test, benchdaily's shard_probe_overhead_ms
-# lane or the multichip dryrun turn it on, and so does an enabled
-# ``mpp_shard_slow`` failpoint. Part of the compiled-program cache key, so the
-# two variants coexist.
+# builder's chip run, PR 28). A test or the multichip dryrun turns it on,
+# and so does an enabled ``mpp_shard_slow`` failpoint. Part of the
+# compiled-program cache key, so the two variants coexist.
 PROBES_ENABLED = False
 
 
@@ -1432,7 +1431,7 @@ class MPPGatherExec:
             chunk = build_executor(reader.plan, self.session).execute()
             # an intermediate fragment result crossed the host boundary —
             # the quantity the staged pipeline (SubplanReader.staged) keeps
-            # at zero; bench lanes and stage-chain tests assert on it
+            # at zero; the stage-chain tests assert on it
             from tidb_tpu.utils import metrics as _m
 
             _m.MPP_HOST_INTERMEDIATE.inc(
@@ -1608,8 +1607,8 @@ class MPPGatherExec:
                 from tidb_tpu.parallel import mesh as _mesh_mod
 
                 if _mesh_mod.FORCE_NDEV is not None:
-                    # scaling runs pin the mesh width (benchdaily scaling lanes,
-                    # ndev-parity tests) — same path, fewer shards
+                    # the ndev-parity tests pin the mesh width — same path,
+                    # fewer shards
                     devices = devices[: _mesh_mod.FORCE_NDEV]
                 if not devices:
                     raise MPPRetryExhausted("no alive devices for MPP")

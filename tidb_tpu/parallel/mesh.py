@@ -8,10 +8,10 @@ from typing import Optional, Sequence
 
 _MESH_CACHE: dict = {}
 
-# forced mesh width for scaling runs: the benchdaily scaling-curve lanes and
-# the stage-chain ndev-parity tests pin the SAME process to 1/2/4/8 devices
-# of the virtual CPU mesh (None = use every available device). Applies only
-# when the caller passes no explicit n_devices/devices.
+# forced mesh width, a test seam: the ndev-parity tests (test_mpp_stagechain,
+# test_mpp_q3) pin the SAME process to 1 or 4 devices of the virtual CPU mesh
+# (None = use every available device). Applies only when the caller passes no
+# explicit n_devices/devices.
 FORCE_NDEV: Optional[int] = None
 
 

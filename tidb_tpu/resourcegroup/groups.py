@@ -26,9 +26,7 @@ guessed):
     WRU = 1.0   × keys written
         + bytes written / 1 KiB
 
-``METERING_ENABLED`` is the process-wide kill switch the
-``metering_overhead_ms`` bench lane measures against — metering only, no
-admission enforcement (admission control is ROADMAP item 3's PR).
+Metering only, always on: no admission enforcement.
 """
 
 from __future__ import annotations
@@ -50,11 +48,6 @@ RRU_PER_CPU_MS = 1.0 / 3.0
 RRU_PER_XCHG_BYTE = 1.0 / 65536.0
 WRU_PER_KEY = 1.0
 WRU_PER_WRITE_BYTE = 1.0 / 1024.0  # 1 KiB written = 1 WRU
-
-# process-wide metering kill switch (bench: metering_overhead_ms measures
-# the on/off delta) — flips the session-side usage fold only; the store-
-# side traffic rings carry their own ``enabled`` flag
-METERING_ENABLED = True
 
 
 @dataclass

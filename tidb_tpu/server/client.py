@@ -1,4 +1,4 @@
-"""Minimal MySQL text-protocol client (the benchdb/test driver analog and
+"""Minimal MySQL text-protocol client (the test driver and
 the in-repo stand-in for mysql-client/pymysql in hermetic tests)."""
 
 from __future__ import annotations

@@ -375,7 +375,7 @@ class Server:
 
     def _alloc_server_id(self) -> int:
         """Cluster-unique server id from the store (the PD allocation role);
-        an embedded bench store without raw_cas just gets id 1. Ids of
+        an embedded store without raw_cas just gets id 1. Ids of
         closed servers (blank registration) are reclaimed so a long-lived
         store never exhausts the 8-bit id space."""
         store = self.db.store

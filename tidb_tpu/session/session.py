@@ -736,18 +736,13 @@ class Session:
             # accounting into a measured ResourceUsage → RUs (metering only;
             # ref: the resource-control RU model + RunawayChecker at
             # adapter.go:553)
-            from tidb_tpu.resourcegroup import groups as _rg
-
             gname = str(self.vars.get("tidb_resource_group", "default"))
             g = self._db.resource_groups.get(gname)
-            usage = None
-            ru = 0.0
-            if _rg.METERING_ENABLED:
-                usage = self._assemble_usage(
-                    dt, (_time.thread_time() - t0_cpu) * 1000.0,
-                    len(res.rows) or res.affected,
-                )
-                ru = usage.ru
+            usage = self._assemble_usage(
+                dt, (_time.thread_time() - t0_cpu) * 1000.0,
+                len(res.rows) or res.affected,
+            )
+            ru = usage.ru
             self._db.stmt_summary.record(
                 exec_sql, dt, len(res.rows) or res.affected, f"{self.user}@{self.host}",
                 float(self.vars.get("tidb_slow_log_threshold", 300)) / 1000.0,
@@ -764,9 +759,8 @@ class Session:
             if topsql is not None and ru:
                 topsql.note_ru(sql_digest().split("|")[0], ru)
             if g is not None:
-                if usage is not None:
-                    g.consume(ru)
-                    self._db.resource_groups.charge(g.name, usage)
+                g.consume(ru)
+                self._db.resource_groups.charge(g.name, usage)
                 if g.exec_elapsed_s and dt > g.exec_elapsed_s and not self._runaway_fired:
                     self._db.resource_groups.record_runaway(g.name, g.action, exec_sql[:256])
             self._audit_stmt(exec_sql, "ok", dt)
@@ -1947,14 +1941,11 @@ class Session:
                 build_executor(plan, self).execute()
             finally:
                 coll, self.runtime_stats = self.runtime_stats, None
+            # the RU the run just metered, as a trailing plan row (the
+            # wall/cpu terms belong to execute(); this shows the
+            # statement-shape charge: scans, cop RPCs, exchanges)
             text = explain_plan(plan, stats=coll)
-            from tidb_tpu.resourcegroup import groups as _rg
-
-            if _rg.METERING_ENABLED:
-                # the RU the run just metered, as a trailing plan row (the
-                # wall/cpu terms belong to execute(); this shows the
-                # statement-shape charge: scans, cop RPCs, exchanges)
-                text += f"\nru: {self._assemble_usage(0.0, 0.0, 0).ru:.2f}"
+            text += f"\nru: {self._assemble_usage(0.0, 0.0, 0).ru:.2f}"
         else:
             text = explain_plan(plan)
         return Result(columns=["plan"], rows=[(line,) for line in text.split("\n")])

@@ -108,7 +108,7 @@ class SourceFile:
         return False
 
 
-VERDICT_SCRIPTS = ("bench.py", "chip_smoke.py")
+VERDICT_SCRIPTS = ("chip_smoke.py",)
 
 
 class Tree:
@@ -227,7 +227,7 @@ def build_tree(root: Optional[str] = None) -> Tree:
                     p = os.path.join(base, n)
                     rel = os.path.relpath(p, root).replace(os.sep, "/")
                     corpus[rel] = _read(p)
-    for extra in ("bench.py", "chip_smoke.py", "__graft_entry__.py"):
+    for extra in ("chip_smoke.py", "__graft_entry__.py"):
         p = os.path.join(root, extra)
         if os.path.isfile(p):
             corpus[extra] = _read(p)

@@ -22,7 +22,7 @@ from tidb_tpu.tools.check.core import Finding, Tree, rule
 
 RULE = "dead-code"
 
-_SKIP_PREFIXES = ("test_", "visit_", "bench_")
+_SKIP_PREFIXES = ("test_", "visit_")
 
 
 def _candidates(sf):

@@ -63,9 +63,9 @@ def check_threads(tree: Tree) -> list:
     return out
 
 
-# CLI surfaces whose job IS stdout: the ecosystem tools, the bench
-# runners, and module entry points
-_PRINT_EXEMPT_PREFIXES = ("tidb_tpu/tools/", "tidb_tpu/bench/")
+# CLI surfaces whose job IS stdout: the ecosystem tools and module entry
+# points
+_PRINT_EXEMPT_PREFIXES = ("tidb_tpu/tools/",)
 
 
 @rule(
@@ -79,8 +79,8 @@ invisible to the log_search wire verb and the tools.diag bundle. The repo
 has a structured event log (utils/eventlog) precisely so load-bearing
 state transitions survive for post-hoc diagnosis — a print is a signal
 that dies at birth. Fix: emit an event (eventlog.on(level) gate + emit)
-or raise a typed error. CLI surfaces whose contract IS stdout — tools/,
-bench/, and __main__.py entry points — are exempt.
+or raise a typed error. CLI surfaces whose contract IS stdout — tools/
+and __main__.py entry points — are exempt.
 """,
 )
 def check_eventlog_discipline(tree: Tree) -> list:

@@ -3,8 +3,8 @@
 The twice-regressed bug class: ``assert`` statements vanish under
 ``python -O``/``PYTHONOPTIMIZE``, so an assert whose failure is
 load-bearing (a protocol check, a refusal, an input validation) silently
-becomes a no-op in optimized deployments. PR 2 caught benchdaily's grant
-check living inside an assert; PR 3 caught bench workers asserting instead
+becomes a no-op in optimized deployments. PR 2 caught a harness's grant
+check living inside an assert; PR 3 caught its workers asserting instead
 of raising — each found by hand in review. Outside ``tests/`` an assert may
 only narrow types; everything else must raise a typed error.
 """
@@ -47,8 +47,8 @@ def _is_narrowing(test: ast.expr) -> bool:
 assert whose failure matters at runtime — wire-protocol checks, refusals,
 input validation, state guards — silently stops checking in optimized
 deployments and the bug it guarded against proceeds as corruption.
-Incident: this class regressed twice in review (PR 2's benchdaily grant
-check, PR 3's bench worker guards), and the sweep that shipped with this
+Incident: this class regressed twice in review (PR 2's harness grant
+check, PR 3's worker guards), and the sweep that shipped with this
 rule converted ~18 more (chunk codec magic, txn double-finish, MySQL
 protocol greetings). Allowed: `assert x is not None` and
 `assert isinstance(x, T)` — pure type narrowing whose failure would raise

@@ -184,8 +184,8 @@ def min_level() -> int:
 
 
 def set_level(name) -> None:
-    """Re-floor the recorder in place (bench lanes flip info<->off; tests
-    drive debug). Accepts a level name or an int level."""
+    """Re-floor the recorder in place (tests drive debug). Accepts a level
+    name or an int level."""
     global _min_level
     if _log is None:
         _build()

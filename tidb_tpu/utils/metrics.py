@@ -332,7 +332,7 @@ MPP_HYBRID = REGISTRY.counter(
 # bytes of INTERMEDIATE fragment results that crossed the host boundary
 # (a subplan build side materialized through the Volcano executor and
 # re-uploaded) — the staged on-mesh pipeline exists to keep this at ZERO;
-# the scaling bench lane and the stage-chain tests assert on it
+# the stage-chain tests assert on it
 MPP_HOST_INTERMEDIATE = REGISTRY.counter(
     "tidb_tpu_mpp_intermediate_host_bytes_total",
     "Bytes of intermediate MPP fragment results moved through the host",

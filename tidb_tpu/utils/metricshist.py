@@ -96,9 +96,9 @@ class MetricsHistory:
                 return
             d = self._series[key] = deque(maxlen=ml)
         elif d.maxlen != ml:
-            # interval/retention changed on a live recorder (benchdaily's
-            # hostile-tick lane does this): re-bound the ring, or a series
-            # born under a fast tick keeps a huge maxlen forever
+            # interval/retention changed on a live recorder: re-bound the
+            # ring, or a series born under a fast tick keeps a huge maxlen
+            # forever
             d = self._series[key] = deque(d, maxlen=ml)
         d.append((t, v))
 
