@@ -92,6 +92,14 @@ class DeltaOverlay:
     _starts: np.ndarray | None = None
     _put_rows: np.ndarray | None = None  # indices into handles that are PUTs
     _minmax: dict = field(default_factory=dict)
+    # the base entry this overlay was read over: an overlay extends only one
+    # built over the same base (a merge installs another and prunes the log)
+    _base: object = field(default=None, repr=False, compare=False)
+    # rows of ``_buf`` no row reads any more: an extended overlay appends the
+    # rows it read again and leaves the superseded ones where they were
+    _dead: int = 0
+    # items of the base's change log this overlay was read after
+    _seen: int = 0
 
     @property
     def n(self) -> int:
@@ -451,6 +459,10 @@ class ColumnCache:
         ):
             dv = region.data_version  # BEFORE the change read: a commit that
             # lands in between surfaces as items and rejects this path
+            # the locks too before it: a commit stamped at or below read_ts
+            # placed its locks before read_ts was drawn, so each row of it is
+            # either locked here or in the log below (_extend_delta)
+            locked = self.store.locked_record_handles(table_id, read_ts)
             kind, payload = self.store.col_changes_since(region.region_id, table_id, old.built_ts)
             # identity re-check: install+prune are atomic under _mu, so if
             # the installed entry is still `old` HERE, no prune ran before
@@ -483,7 +495,7 @@ class ColumnCache:
                 if len(handles) and len(handles) <= cap:
                     complete = not pend and read_ts >= region.max_commit_ts
                     delta = self._delta_for(
-                        key, region, table_id, schema, slots, read_ts, handles, dv, complete
+                        key, old, region, table_id, schema, slots, read_ts, handles, payload, locked, dv, complete
                     )
                     if delta is not None:
                         self._ensure_slots(old, table_id, schema, slots)
@@ -518,27 +530,106 @@ class ColumnCache:
         _metrics.DEVICE_DELTA_ROWS.set(sum(len(d.handles) for d in self._deltas.values()))
 
     # -- delta build --------------------------------------------------------
-    def _delta_for(self, key, region, table_id, schema, slots, read_ts, handles, dv, complete):
+    def _delta_for(self, key, base, region, table_id, schema, slots, read_ts, handles, items, locked, dv, complete):
+        """The overlay of ``handles`` (the rows that ``items``, the change log
+        since ``base`` was built, touch up to ``read_ts``) at ``read_ts``: the
+        cached one where nothing was committed since it was read, else the
+        cached one EXTENDED by what was (:meth:`_extend_delta`: a point read
+        of those rows alone), else one read from nothing.
+        ``tidb_tpu_delta_overlay_total{how}`` counts which."""
         with self._mu:
             d = self._deltas.get(key)
-            if d is not None and (
-                d.data_version != dv
-                or read_ts < d.built_ts
-                or not d.complete
-                or len(d.handles) != len(handles)
-                or not np.array_equal(d.handles, handles)
-            ):
-                d = None
-        if d is None:
-            d = self._build_delta(region, table_id, handles, read_ts, dv, complete)
-            if d.complete:
-                with self._mu:
-                    self._deltas[key] = d
-                    self._merged.pop(key, None)  # the view of the previous delta
-                    self._update_delta_gauge_locked()
+            epoch = self.epoch
+        if d is not None and not (d._base is base and d.complete and read_ts >= d.built_ts):
+            d = None
+        how = "reused"
+        if d is None or d.data_version != dv or not np.array_equal(d.handles, handles):
+            how = "extended"
+            new = None if d is None else self._extend_delta(d, region, table_id, schema, read_ts, handles, items, locked, dv, complete)
+            if new is None or not self._install_delta(key, new, base, len(items), epoch):
+                how = "rebuilt"
+                new = self._build_delta(region, table_id, handles, read_ts, dv, complete)
+                self._install_delta(key, new, base, len(items))
+            d = new
+        _metrics.DELTA_OVERLAY.inc(how=how)
         if schema is not None and slots:
             self._decode_delta_slots(d, table_id, schema, slots)
         return d
+
+    def _install_delta(self, key, d: DeltaOverlay, base, seen: int, epoch: int | None = None) -> bool:
+        """Cache ``d`` (a complete one only) as the overlay over ``base`` that
+        was read after ``seen`` items of its log. False, with nothing cached,
+        where a dictionary was compacted since ``epoch``: an extended overlay
+        then carries the old codes, and is to be read again."""
+        d._base, d._seen = base, seen
+        with self._mu:
+            if epoch is not None and self.epoch != epoch:
+                return False
+            if d.complete:
+                self._deltas[key] = d
+                self._merged.pop(key, None)  # the view of the previous delta
+                self._update_delta_gauge_locked()
+        return True
+
+    def _extend_delta(self, d: DeltaOverlay, region, table_id, schema, read_ts, handles, items, locked, dv, complete):
+        """``d`` brought up to ``read_ts`` as a NEW overlay (a statement that
+        holds ``d`` goes on reading it). The store is asked only for the rows
+        of the items the log has gained since ``d`` read it: every commit
+        after ``d.built_ts``, and one stamped at or below it that was applied
+        later; for the ``locked`` rows (a commit decided but not applied yet:
+        the read resolves it, as it always did); and for any handle ``d`` does
+        not hold. Every other row's verdict, raw row and decoded lanes are
+        ``d``'s. None where ``d`` cannot be the start: a handle of it is not
+        among ``handles``, or its buffer would hold more dead rows than the
+        overlay has rows (the rebuild compacts it)."""
+        n = len(handles)
+        pos = np.searchsorted(handles, d.handles)
+        if d.n and (pos[-1] >= n or not np.array_equal(handles[pos], d.handles)):
+            return None
+        fresh = np.ones(n, dtype=bool)
+        fresh[pos] = False
+        # the log of one base only grows (its prune comes with the next base),
+        # so what d saw is a prefix of ``items``
+        again = np.asarray([h for ts, h, _ in items[d._seen:] if ts <= read_ts] + locked, dtype=np.int64)
+        if len(again):
+            i = np.minimum(np.searchsorted(handles, again), n - 1)
+            fresh[i[handles[i] == again]] = True
+        fpos = np.nonzero(fresh)[0]
+        dput = pos[d._put_rows]
+        dead = d._dead + int(np.count_nonzero(fresh[dput]))
+        if dead > n:
+            return None
+        # the rows to read, as an overlay of their own with every lane d has
+        f = self._build_delta(region, table_id, handles[fpos], read_ts, dv, complete)
+        carried = list(d.cols) if schema is not None else []
+        self._decode_delta_slots(f, table_id, schema, carried)
+        tomb = np.zeros(n, dtype=bool)
+        tomb[pos] = d.tomb
+        tomb[fpos] = f.tomb
+        put_rows = np.nonzero(~tomb)[0]
+        starts = np.zeros(n, dtype=np.int64)
+        starts[dput] = d._starts
+        starts[fpos[f._put_rows]] = f._starts + len(d._buf)
+        new = DeltaOverlay(
+            handles=handles,
+            tomb=tomb,
+            data_version=dv,
+            built_ts=read_ts,
+            complete=f.complete,
+            _buf=d._buf + f._buf,
+            _starts=starts[put_rows],
+            _put_rows=put_rows,
+            _dead=dead,
+        )
+        for s in carried:
+            dd, dvalid = d.cols[s]
+            fd, fvalid = f.cols[s]
+            data = np.zeros(n, dd.dtype)
+            valid = np.zeros(n, dtype=bool)
+            data[pos], valid[pos] = dd, dvalid
+            data[fpos], valid[fpos] = fd, fvalid
+            new.cols[s] = (data, valid)
+        return new
 
     def _build_delta(self, region, table_id, handles, read_ts, dv, complete) -> DeltaOverlay:
         """Point-read the touched handles at read_ts and decode them into an
@@ -558,6 +649,9 @@ class ColumnCache:
             from tidb_tpu.kv.kv import TxnAbortedError
 
             raise TxnAbortedError("delta build: lock resolution did not converge")
+        det = _ed.current_cop()
+        if det is not None:
+            det.delta_read += len(keys)
         tomb = np.fromiter((v is None for v in vals), dtype=bool, count=len(vals))
         put_rows = np.nonzero(~tomb)[0]
         chunks = [vals[i] for i in put_rows]

@@ -625,9 +625,11 @@ def _device_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, region: Re
         delta = None
     if delta is not None:
         det = _ed.current_cop()
-        if det is not None:
+        if det is None:
+            ph.note(delta_rows=delta.n)
+        else:
             det.delta_rows += delta.n
-        ph.note(delta_rows=delta.n)
+            ph.note(delta_rows=delta.n, delta_read=det.delta_read)
 
     binder_entry = entry if delta is None else _BinderView(entry, delta)
     binder = Binder(cache, scan.table_id, scan.columns, binder_entry)

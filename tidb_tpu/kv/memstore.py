@@ -902,6 +902,23 @@ class MemStore:
                 return ("none", None)
             return ("items", items)
 
+    def locked_record_handles(self, table_id: int, read_ts: int) -> list[int]:
+        """Handles of ``table_id``'s rows that hold a lock a read at
+        ``read_ts`` would stop at (:meth:`_check_lock`): a commit that is
+        decided but not applied to them yet. The column cache asks BEFORE it
+        reads the change log, so that a row it would otherwise carry over from
+        a cached overlay is read, and its lock resolved, instead."""
+        with self._mu:
+            if not self._locks:
+                return []
+            prefix = tablecodec.record_prefix(table_id)
+            return [
+                tablecodec.decode_record_key(k)[1]
+                for k, lock in self._locks.items()
+                if lock.start_ts <= read_ts and lock.op != OP_PESSIMISTIC_LOCK
+                and k.startswith(prefix) and tablecodec.is_record_key(k)
+            ]
+
     def col_changes_prune(self, region_id: int, table_id: int, upto_ts: int) -> None:
         """Forget changes at or below ``upto_ts`` — they were folded into a
         freshly merged columnar base."""

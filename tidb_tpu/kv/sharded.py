@@ -1159,6 +1159,9 @@ class ShardedStore:
     def col_changes_prune(self, region_id: int, table_id: int, upto_ts: int) -> None:
         return None  # nothing itemized coordinator-side, nothing to prune
 
+    def locked_record_handles(self, table_id: int, read_ts: int) -> list[int]:
+        return []  # asked for delta reads only, and the answer above rules them out
+
     # -- MPP: single-owner placement ----------------------------------------
     def mpp_ndev(self) -> int:
         fn = getattr(self.stores[0], "mpp_ndev", None)

@@ -56,7 +56,7 @@ class CopExecDetails:
         "region_id", "store", "queue_ms", "wire_ms", "proc_ms", "device_ms",
         "host_ms", "compile_ms", "h2d_bytes", "d2h_bytes", "dev_cache_hits",
         "dev_cache_misses", "engine", "degraded", "retries", "backoff_ms",
-        "resplits", "delta_rows", "merges", "keys_scanned", "bytes_scanned",
+        "resplits", "delta_rows", "delta_read", "merges", "keys_scanned", "bytes_scanned",
         "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions", "programs",
     )
 
@@ -86,6 +86,7 @@ class CopExecDetails:
         self.backoff_ms = 0.0  # cumulative Backoffer sleep charged to this task
         self.resplits = 0  # region re-splits (epoch changes)
         self.delta_rows = 0  # columnar delta-overlay rows this scan read through
+        self.delta_read = 0  # of them, rows point-read from the store for this scan (the rest were cached)
         self.merges = 0  # delta→base merges this task triggered (query-path)
         self.keys_scanned = 0  # store-side MVCC keys this task read (RU input)
         self.bytes_scanned = 0  # store-side bytes those keys carried
@@ -129,6 +130,8 @@ class CopExecDetails:
             out["rs"] = self.resplits
         if self.delta_rows:
             out["dlr"] = self.delta_rows
+        if self.delta_read:
+            out["dld"] = self.delta_read
         if self.merges:
             out["mg"] = self.merges
         if self.keys_scanned:
@@ -162,6 +165,7 @@ class CopExecDetails:
         self.backoff_ms += float(pb.get("bo", 0.0))
         self.resplits += int(pb.get("rs", 0))
         self.delta_rows += int(pb.get("dlr", 0))
+        self.delta_read += int(pb.get("dld", 0))
         self.merges += int(pb.get("mg", 0))
         self.keys_scanned += int(pb.get("sk", 0))
         self.bytes_scanned += int(pb.get("sb", 0))
@@ -184,7 +188,7 @@ class CopTasksSummary:
         "procs", "queue_ms", "wire_ms", "device_ms", "host_ms", "compile_ms",
         "h2d_bytes", "d2h_bytes", "dev_cache_hits", "dev_cache_misses",
         "engines", "degraded", "retries", "backoff_ms", "resplits",
-        "delta_rows", "merges", "keys_scanned", "bytes_scanned",
+        "delta_rows", "delta_read", "merges", "keys_scanned", "bytes_scanned",
         "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions", "programs",
     )
 
@@ -205,6 +209,7 @@ class CopTasksSummary:
         self.backoff_ms = 0.0
         self.resplits = 0
         self.delta_rows = 0
+        self.delta_read = 0
         self.merges = 0
         self.keys_scanned = 0
         self.bytes_scanned = 0
@@ -238,6 +243,7 @@ class CopTasksSummary:
         self.backoff_ms += d.backoff_ms
         self.resplits += d.resplits
         self.delta_rows += d.delta_rows
+        self.delta_read += d.delta_read
         self.merges += d.merges
         self.keys_scanned += d.keys_scanned
         self.bytes_scanned += d.bytes_scanned
@@ -290,7 +296,8 @@ class CopTasksSummary:
         if self.keys_scanned:
             parts.append(f"scan: {self.keys_scanned} keys/{self.bytes_scanned}B")
         if self.delta_rows:
-            parts.append(f"delta_rows: {self.delta_rows}")  # scan paid the delta path
+            # scan paid the delta path; delta_read of the rows came from the store, the rest from the cached overlay
+            parts.append(f"delta_rows: {self.delta_rows}, delta_read: {self.delta_read}")
         if self.merges:
             parts.append(f"merges: {self.merges}")
         if self.degraded:

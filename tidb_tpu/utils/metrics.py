@@ -284,6 +284,12 @@ DEVICE_DELTA_ROWS = REGISTRY.gauge(
     "tidb_tpu_device_delta_rows",
     "Committed rows pending in columnar delta overlays (not yet merged)",
 )
+DELTA_OVERLAY = REGISTRY.counter(
+    "tidb_tpu_delta_overlay_total",
+    "Delta overlays served to a read, by how each was obtained (reused = the cached one; "
+    "extended = the cached one plus a point read of the rows committed since; rebuilt = every touched row read)",
+    ("how",),
+)
 DEVICE_MERGE_SECONDS = REGISTRY.histogram(
     "tidb_tpu_device_merge_seconds",
     "Delta→base merge wall (rebuild + dirty-block accounting) per region",
