@@ -275,7 +275,7 @@ def test_mpp_input_lanes_are_staged_sharded_over_the_mesh(q3db):
     for ent in gather._MPP_DEV_CACHE.values():
         if isinstance(ent, dict):  # per-column pool of a plain reader
             lanes.append(ent["live"])
-            lanes += [a for d, v, _b in ent["cols"].values() for a in (d, v)]
+            lanes += [a for d, v, *_ in ent["cols"].values() for a in (d, v)]
         else:  # whole-reader entry: (lanes, n, bounds)
             lanes += list(ent[0])
     assert lanes

@@ -71,7 +71,7 @@ def _fold(lkey, lvalid, rkey, rvalid, hi):
     acc = [jnp.asarray(lkey), jnp.ones(len(lkey), bool), jnp.asarray(lvalid)]
     rcols = [jnp.asarray(rkey), jnp.ones(len(rkey), bool), jnp.arange(len(rkey)), jnp.ones(len(rkey), bool), jnp.asarray(rvalid)]
     took: dict = {}
-    acc, mask, dropped, overflow, _ = mpp._fold_join(jax, jnp, join, 1, acc, jnp.asarray(lvalid), rcols, jnp.asarray(rvalid), None, took)
+    acc, mask, dropped, overflow, _ = mpp._fold_join(mpp._Exchange(jax, 1), jnp, join, acc, jnp.asarray(lvalid), rcols, jnp.asarray(rvalid), None, took)
     assert int(dropped) == 0 and int(overflow) == 0
     return np.asarray(mask), np.asarray(acc[3 + 2]), took
 
@@ -98,7 +98,7 @@ def test_unbounded_keys_keep_the_sort_merge_lookup():
     acc = [jnp.asarray(lkey), jnp.ones(len(lkey), bool), jnp.asarray(lvalid)]
     rcols = [jnp.asarray(rkey), jnp.ones(len(rkey), bool), jnp.asarray(rvalid)]
     took: dict = {}
-    _, mask, _, _, _ = mpp._fold_join(jax, jnp, join, 1, acc, jnp.asarray(lvalid), rcols, jnp.asarray(rvalid), None, took)
+    _, mask, _, _, _ = mpp._fold_join(mpp._Exchange(jax, 1), jnp, join, acc, jnp.asarray(lvalid), rcols, jnp.asarray(rvalid), None, took)
     assert not took and np.asarray(mask).tolist() == (_numpy_lookup(lkey, lvalid, rkey, rvalid) >= 0).tolist()
 
 

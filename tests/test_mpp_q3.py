@@ -130,7 +130,7 @@ def test_the_shipped_fragment_program_holds_no_host_callback(tpch, session, monk
 
     def newest_program_text():
         session.query(tpl.first_text)
-        fn, _ = list(gather._MPP_FN_CACHE.values())[-1]
+        fn, *_ = list(gather._MPP_FN_CACHE.values())[-1]
         return fn.as_text()
 
     assert gather.PROBES_ENABLED is False
@@ -228,27 +228,26 @@ def test_rehearsal_of_the_cell_is_correct_and_prints_its_metrics(tmp_path):
     assert any(label == "mpp_gather" for label, _ in line["breakdown"]["idle_gaps"])
 
 
-@pytest.mark.parametrize("chips", [1, 4])
-def test_gathers_on_fewer_devices_than_the_cell_asks_for_come_out_not_on_device(tmp_path, chips):
+@pytest.mark.parametrize("chips,cell", [(1, "tpch_sf2_mpp.q3_1c"), (4, "tpch_sf2_mpp4.q3_1c")])
+def test_gathers_on_fewer_devices_than_the_cell_asks_for_come_out_not_on_device(tmp_path, chips, cell):
     """The benchmark's own fault `mpp_fewer_devices` (`benchmark/tests/faults.py`)
-    under this cell, in a copy of the checkout whose cell asks for ``chips``.
-    One chip: none is left, the gather gives up and the host executor answers.
-    Four: the gather runs on three. Answers right, `correct` false. Tier-1's
-    carrier of `benchmark/tests/test_faults.py::test_gathers_on_fewer_devices...`,
-    whose helper cannot build its two-table cell while `mpp_gather_p50_ms.py`
-    is a file of the benchmark's (PERF.md section 7 a)."""
+    under the two shipped Q3 cells, as `BENCHMARK.json` holds them: the one-chip
+    `tpch_sf2_mpp.q3_1c` and the four-chip `tpch_sf2_mpp4.q3_1c` (PR 33), in a
+    copy of the checkout's benchmark. One chip: none is left, the gather gives
+    up and the host executor answers. Four: the gather runs on three. Answers
+    right, `correct` false. Tier-1's carrier of
+    `benchmark/tests/test_faults.py::test_gathers_on_fewer_devices...`, whose
+    helper cannot build its two-table cell while `mpp_gather_p50_ms.py` is a
+    file of the benchmark's (PERF.md section 7 a)."""
     root = tmp_path / "checkout"
     shutil.copytree(BENCH, root / "benchmark", ignore=shutil.ignore_patterns("__pycache__", "test_*", "recorded_*"))
     os.symlink(os.path.join(ROOT, "tidb_tpu"), root / "tidb_tpu")
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    for cell in bench["workloads"]:
-        if cell["name"] == "tpch_sf2_mpp.q3_1c":
-            cell["chips"] = chips
-    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    shutil.copy(os.path.join(ROOT, "BENCHMARK.json"), root / "BENCHMARK.json")
+    with open(root / "BENCHMARK.json") as f:
+        assert {w["name"]: w["chips"] for w in json.load(f)["workloads"]}[cell] == chips
 
     def drive(*entry):
-        cmd = [sys.executable, *entry, "--workload", "tpch_sf2_mpp.q3_1c", "--seed", "2147483777", "--seconds", "2", "--trace", "0",
+        cmd = [sys.executable, *entry, "--workload", cell, "--seed", "2147483777", "--seconds", "2", "--trace", "0",
                "--platform", "cpu", "--scale", str(SF)]
         env = dict(os.environ, XLA_FLAGS=f"--xla_force_host_platform_device_count={chips}", JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
         p = subprocess.run(cmd, capture_output=True, text=True, timeout=600, cwd=root, env=env)

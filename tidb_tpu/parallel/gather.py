@@ -1300,20 +1300,91 @@ def _scan_schema(reader: PhysTableReader) -> Schema:
     return out
 
 
-def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: int, arms=None):
-    """MPPJoin chain → DistJoinSpec list with power-of-two bucketed caps and
-    JOINT per-key value bounds (both sides must pack identically). Shared by
-    the outer plan chain and the join chains inside device stages. left_keys
-    of later joins need no rebase: after join ji the accumulated lane layout
-    = probe lanes + build lanes, and ``lane_of`` is computed over the full
-    reader list. Key-validity lanes enforce NULL-key semantics (inner-join
-    keys must be non-NULL to match). ``arms``: ``PhysMPPGather.arm_folds`` of
-    the outer chain (a stage's chain folds none)."""
+def _plan_col_reader(readers: list, joins: list, pos: int):
+    """(reader index, column index) of accumulated plan-schema position ``pos``."""
+    for ri, r in enumerate(readers):
+        if ri and joins[ri - 1].kind not in ("inner", "left", "right"):
+            continue
+        if pos < len(r.schema):
+            return ri, pos
+        pos -= len(r.schema)
+    return None
+
+
+def _in_place(readers, joins, arms, held, ndev: int) -> list:
+    """Per join: None, or what lets the fragment leave its rows where they
+    are (``DistJoinSpec.exchange`` "local"): {"lows", "highs": the key range
+    each shard's probe rows span, "halo": the most build rows one shard must
+    send another, "codes": the widest such range}. Read off what the shards
+    HOLD (``held``: per reader its rows a shard and, per column, each shard's
+    low, high and whether it lies in order there; None for a staged reader):
+    a fact table stored in its dimension's key order, both dealt over the
+    mesh in that order, meets its build rows on its own shard but for a
+    sliver at each cut. Taken where the build side is unique on ONE integer
+    key and lies in key order on every shard, the probe rows have not been
+    moved by an earlier fold, and the slivers a shard receives are no more
+    than its own build rows (a probe that lies in no order spans the whole
+    domain on every shard: every pair overlaps whole, and the planner's
+    exchange stands). A unique integer key holds at most one row a value, so
+    the overlap of two ranges bounds the rows in it."""
+    from tidb_tpu.parallel.mpp import DIRECT_DOMAIN_MAX, keeps_rows
+
+    out: list = [None] * len(joins)
+    if ndev == 1:
+        return out
+    still = True  # reader 0's rows are where they were dealt
+    for ji, join in enumerate(joins):
+        arm = bool(arms and arms[ji])
+        ok = (
+            join.unique
+            and join.kind in ("inner", "left", "semi", "anti")
+            and len(join.eq) == 1
+            and not join.str_keys
+            and held[ji + 1] is not None
+            and (arm or still)
+        )
+        src = _plan_col_reader(readers, joins, join.eq[0][0]) if ok else None
+        if src is not None and src[0] == (ji if arm else 0) and held[src[0]] is not None:
+            probe, build = held[src[0]]["cols"][src[1]], held[ji + 1]["cols"][join.eq[0][1]]
+            if probe is not None and build is not None and build[2]:
+                (plo, phi, _), (blo, bhi, _), rows = probe, build, held[ji + 1]["rows"]
+                halo = 0
+                for sh in range(ndev):
+                    for d in range(ndev):
+                        if sh != d:
+                            halo = max(halo, min(min(bhi[sh], phi[d]) - max(blo[sh], plo[d]) + 1, rows[sh]))
+                codes = max(max(hi - lo + 1 for lo, hi in zip(plo, phi)), 1)
+                if (ndev - 1) * halo <= max(rows) and codes <= DIRECT_DOMAIN_MAX:
+                    out[ji] = {"lows": plo, "highs": phi, "halo": max(halo, 0), "codes": codes}
+        if not arm:
+            still = still and keeps_rows(join.kind, join.unique, "local" if out[ji] else join.exchange, ndev)
+    return out
+
+
+def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: int, arms=None, placed=None, n_operands: int = 0):
+    """MPPJoin chain → (DistJoinSpec list, the ``local`` joins' range
+    operands) with power-of-two bucketed caps and JOINT per-key value bounds (both sides
+    must pack identically). Shared by the outer plan chain and the join
+    chains inside device stages. left_keys of later joins need no rebase:
+    after join ji the accumulated lane layout = probe lanes + build lanes,
+    and ``lane_of`` is computed over the full reader list. Key-validity lanes
+    enforce NULL-key semantics (inner-join keys must be non-NULL to match).
+    ``arms``: ``PhysMPPGather.arm_folds`` of the outer chain (a stage's chain
+    folds none). ``placed``: :func:`_in_place`'s verdicts (a stage's chain has
+    none); a join it admits runs "local" whatever the planner chose, its
+    ranges the program's operand ``n_operands`` + k."""
     from tidb_tpu.parallel.mpp import DistJoinSpec
 
     shard = lambda n: max(_pow2(2 * ((max(n, 1) + ndev - 1) // ndev)), 64)
-    probe_cap = shard(nrows[0])
-    specs = []
+    # a hash exchange's per-destination capacity: a shard holds rows/ndev and
+    # sends each destination a 1/ndev of them; a quarter over that for an
+    # uneven key, then the next power of two (the caps are compile-key
+    # components). Past it the program counts what it dropped and the gather
+    # grows the cap (x4: one shard's all going to one destination is x ndev
+    # of the even share)
+    dest = lambda rows: max(_pow2(-(-5 * max(rows, 1) // (4 * ndev * ndev))), 64) if ndev > 1 else shard(rows)
+    probe_cap, probe_rows = shard(nrows[0]), nrows[0]
+    specs, ranges = [], []
     for ji, join in enumerate(joins):
         build_cap = shard(nrows[ji + 1])
         lane_eq_l = [lane_of[lp] for lp, _ in join.eq]
@@ -1326,18 +1397,30 @@ def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: 
             kb.append(
                 (min(lb[0], rb[0]), max(lb[1], rb[1])) if lb is not None and rb is not None else None
             )
+        here = placed[ji] if placed is not None and kb[0] is not None else None
+        how = {"exchange": join.exchange}
+        if here is not None:
+            # the ranges as packed codes (``mpp._pack_keys``: the key less the joint low bound)
+            ranges.append(np.array([[lo - kb[0][0], hi - kb[0][0]] for lo, hi in zip(here["lows"], here["highs"])], dtype=np.int64))
+            how = dict(
+                exchange="local",
+                halo_cap=_pow2(max(here["halo"], 64)),
+                local_codes=_pow2(here["codes"]),
+                range_operand=n_operands + len(ranges) - 1,
+                right_live=2 * len(bounds_by_reader[ji + 1]),
+            )
         specs.append(
             DistJoinSpec(
                 left_keys=lane_eq_l,
                 right_keys=lane_eq_r,
                 kind=join.kind,
-                exchange=join.exchange,
-                left_row_cap=probe_cap,
-                right_row_cap=build_cap,
+                left_row_cap=dest(probe_rows),
+                right_row_cap=dest(nrows[ji + 1]),
                 unique=join.unique,
                 out_cap=max(_pow2(probe_cap), 1024),
                 key_bounds=tuple(kb),
                 arm=bool(arms and arms[ji]),
+                **how,
             )
         )
         if join.kind == "right":
@@ -1345,12 +1428,14 @@ def _make_join_specs(joins, nrows, bounds_acc, bounds_by_reader, lane_of, ndev: 
             # (possibly) unmatched build rows to the accumulated layout
             base = specs[-1].out_cap if not join.unique else probe_cap
             probe_cap = base + build_cap
+            probe_rows += nrows[ji + 1]
         elif not join.unique and join.kind in ("inner", "left"):
             probe_cap = specs[-1].out_cap
+            probe_rows = probe_cap * ndev
     for spec in specs:
         spec.left_key_valid = tuple(k + 1 for k in spec.left_keys)
         spec.right_key_valid = tuple(k + 1 for k in spec.right_keys)
-    return specs
+    return specs, ranges
 
 
 def _stage_parts_of(sub: SubplanReader):
@@ -1643,6 +1728,9 @@ class MPPGatherExec:
                             compiles=getattr(self, "_compiles", 0),
                             stages=getattr(self, "_n_stages", 1),
                             stage_bytes=getattr(self, "_stage_bytes", []),
+                            exchange=self._exchange[0],
+                            xchg_bytes=self._exchange[1],
+                            xchg_rows=self._exchange[2],
                         ),
                     )
                     return out
@@ -1791,6 +1879,9 @@ class MPPGatherExec:
                 compiles=int(e.get("compiles", 0)),
                 stages=int(e.get("stages", 1)),
                 stage_bytes=[int(b) for b in (e.get("stage_bytes") or [])],
+                exchange=str(e.get("exchange", "")),
+                xchg_bytes={str(k): int(v) for k, v in (e.get("xchg_bytes") or {}).items()},
+                xchg_rows=int(e.get("xchg_rows", 0)),
             ),
         )
         return chunk
@@ -1805,6 +1896,8 @@ class MPPGatherExec:
             DistJoinSpec,
             DistTopNSpec,
             build_dist_pipeline,
+            compiled_exchange_bytes,
+            keeps_rows,
         )
 
         p = self.plan
@@ -1863,32 +1956,65 @@ class MPPGatherExec:
             n = len(chunk)
             # power-of-two per-shard padding (masked validity): input SHAPES
             # bucket, so same-shape queries at nearby sizes — and grow-and-
-            # retry attempts — trace and compile ONE program
-            per = _pow2(max((n + ndev - 1) // ndev, 8))
+            # retry attempts — trace and compile ONE program. The rows are
+            # dealt evenly, in order: shard s holds rows [s*q, (s+1)*q) and
+            # then its padding, so no shard computes on padding alone while
+            # another is full (12.0M rows padded to 4 x 4.19M laid end to end
+            # left the fourth shard empty)
+            q = (n + ndev - 1) // ndev
+            per = _pow2(max(q, 8))
             tot = per * ndev
+            cuts = [(min(sh * q, n), min((sh + 1) * q, n)) for sh in range(ndev)]
+
+            def deal(a):
+                out = np.zeros(tot, dtype=a.dtype)
+                for sh, (lo, hi) in enumerate(cuts):
+                    out[sh * per : sh * per + hi - lo] = a[lo:hi]
+                return out
+
             arrays = []
             bounds = []
+            by_shard = []  # per column: what each shard holds of it, (lows, highs, in order) — None for a float lane
             for c in chunk.columns:
-                d = np.zeros(tot, dtype=c.data.dtype)
-                d[:n] = c.data
-                v = np.zeros(tot, dtype=bool)
-                v[:n] = c.validity
-                arrays.append(np.where(v, d, 0))
+                v = deal(c.validity[:n])
+                arrays.append(np.where(v, deal(c.data[:n]), 0))
                 arrays.append(v)
                 # per-column value bounds power the packed narrow-lane sorts
                 # in the fragment program (mpp._pack_keys)
                 if np.issubdtype(c.data.dtype, np.floating):
                     bounds.append(None)
-                else:
-                    lv = c.data[: n][c.validity[: n]]
-                    bounds.append((int(lv.min()), int(lv.max())) if lv.size else (0, 0))
-            live = np.zeros(tot, dtype=bool)
-            live[:n] = True
-            arrays.append(live)
-            return arrays, n, widen_bounds(bounds)
+                    by_shard.append(None)
+                    continue
+                lv = c.data[: n][c.validity[: n]]
+                bounds.append((int(lv.min()), int(lv.max())) if lv.size else (0, 0))
+                if ndev == 1:
+                    by_shard.append(None)  # one shard: nothing is ever placed by them
+                    continue
+                lows, highs, ordered = [], [], True
+                for lo, hi in cuts:
+                    part, ok = c.data[lo:hi], c.validity[lo:hi]
+                    if not ok.all():
+                        part, ordered = part[ok], False  # a NULL's slot holds 0
+                    lows.append(int(part.min()) if part.size else 0)
+                    highs.append(int(part.max()) if part.size else -1)
+                    ordered = ordered and bool((part[1:] >= part[:-1]).all())
+                by_shard.append((lows, highs, ordered))
+            arrays.append(deal(np.ones(n, dtype=bool)))
+            return arrays, n, widen_bounds(bounds), {"rows": [hi - lo for lo, hi in cuts], "cols": by_shard}
+
+        def pooled(pool, want):
+            lanes = [x for s in want for x in pool["cols"][s][:2]]
+            return (
+                lanes + [pool["live"]],
+                pool["n"],
+                [pool["cols"][s][2] for s in want],
+                {"rows": pool["rows"], "cols": [pool["cols"][s][3] for s in want]},
+            )
 
         def dev_side(reader):
-            """Padded device-resident input lanes, cached per table state —
+            """(padded device-resident input lanes, rows, per-column bounds,
+            what each shard holds: its rows and each column's range and
+            order there), cached per table state —
             steady-state MPP queries re-read and re-upload nothing (same
             identity scheme as the coprocessor engine's device cache). Plain
             readers pool lanes PER COLUMN, so two queries scanning
@@ -1962,32 +2088,25 @@ class MPPGatherExec:
                 pool = _MPP_DEV_CACHE.get(ckey)
                 want = [oc.slot for oc in reader.schema]
                 if pool is not None and all(s in pool["cols"] for s in want):
-                    lanes, bs = [], []
-                    for s in want:
-                        d, v, b = pool["cols"][s]
-                        lanes += [d, v]
-                        bs.append(b)
-                    return (lanes + [pool["live"]], pool["n"], bs)
-            arrays, n, bounds = pad_side(self._reader_arrays(reader))
+                    return pooled(pool, want)
+            arrays, n, bounds, held = pad_side(self._reader_arrays(reader))
             if ckey is not None:
                 if pool is None:
-                    pool = {"n": n, "live": put(arrays[-1]), "cols": {}}
+                    pool = {"n": n, "live": put(arrays[-1]), "cols": {}, "rows": held["rows"]}
                     with _MPP_CACHE_MU:
                         # a racing gather may have installed the pool first:
                         # adopt the winner so both share one resident copy
                         pool = _MPP_DEV_CACHE.setdefault(ckey, pool)
-                lanes = []
                 for i, s in enumerate(want):
                     ent = pool["cols"].get(s)
                     if ent is None:
                         # upload ONLY the columns the pool lacks — the
                         # overlap with earlier queries stays resident
-                        ent = (put(arrays[2 * i]), put(arrays[2 * i + 1]), bounds[i])
+                        ent = (put(arrays[2 * i]), put(arrays[2 * i + 1]), bounds[i], held["cols"][i])
                         pool["cols"][s] = ent
-                    lanes += [ent[0], ent[1]]
-                dev = (lanes + [pool["live"]], pool["n"], [pool["cols"][s][2] for s in want])
+                dev = pooled(pool, want)
             else:
-                dev = ([put(a) for a in arrays], n, bounds)
+                dev = ([put(a) for a in arrays], n, bounds, held)
             with _MPP_CACHE_MU:
                 if key is not None:
                     _MPP_DEV_CACHE[key] = dev
@@ -2005,11 +2124,15 @@ class MPPGatherExec:
             for ri, r in enumerate(p.readers)
         ]
         flat_sides = [x for ri, side in enumerate(sides) for x in (side if stage_parts[ri] is not None else [side])]
+        shard_rows = [sum(h["rows"][sh] for _, _, _, h in flat_sides) for sh in range(ndev)]
         ph.note(
-            rows_valid=sum(n for _, n, _ in flat_sides),
-            rows_padded=sum(int(lanes[-1].shape[0]) for lanes, _, _ in flat_sides),
+            rows_valid=sum(n for _, n, _, _ in flat_sides),
+            rows_padded=sum(int(lanes[-1].shape[0]) for lanes, _, _, _ in flat_sides),
             h2d=h2d[0],
             cache="miss" if h2d[0] else "hit",
+            ndev=ndev,
+            shard_rows_max=max(shard_rows),
+            shard_rows_min=min(shard_rows),
         )
         ph.to("program")
         stats = self.session._db.stats
@@ -2031,7 +2154,7 @@ class MPPGatherExec:
         bounds_by_reader = []
         for ri, side in enumerate(sides):
             if stage_parts[ri] is not None:
-                for arrays, _, _ in side:
+                for arrays, _, _, _ in side:
                     all_lanes.extend(arrays)
                 # build-row proxy for the consumer join's caps: the stage
                 # emits ≤ group_cap live slots per shard
@@ -2039,7 +2162,7 @@ class MPPGatherExec:
                 # finalize lanes carry no static value bounds
                 bounds_by_reader.append([None] * len(p.readers[ri].schema))
             else:
-                arrays, n, bs = side
+                arrays, n, bs, _ = side
                 all_lanes.extend(arrays)
                 nrows.append(n)
                 bounds_by_reader.append(bs)
@@ -2222,9 +2345,13 @@ class MPPGatherExec:
         # expansion capacity from the probe row count with 2× headroom —
         # power-of-two bucketed so the caps (compile-key components) land on
         # the same grid for nearby sizes and for grow-and-retry attempts
-        join_specs = _make_join_specs(
-            p.joins, nrows, all_bounds, bounds_by_reader, lane_of, ndev, arms=p.arm_folds
+        held = [None if stage_parts[ri] is not None else sides[ri][3] for ri in range(len(p.readers))]
+        join_specs, ranges = _make_join_specs(
+            p.joins, nrows, all_bounds, bounds_by_reader, lane_of, ndev, arms=p.arm_folds,
+            placed=_in_place(p.readers, p.joins, p.arm_folds, held, ndev), n_operands=len(operands),
         )
+        operands = operands + ranges  # the literals, then each local join's [ndev, 2] key ranges
+        exchanges = ",".join(s.exchange for s in join_specs)  # what the program runs, join by join
 
         # device-stage runtimes: each staged build side carries its own
         # selections, internal join specs, agg-input mapper, and finalize
@@ -2341,14 +2468,14 @@ class MPPGatherExec:
             s_conds = [self._bind_conditions(sr) for sr in s_readers]
             s_ncols = [len(sr.schema) for sr in s_readers]
             s_selec = [side_selection(s_conds[i], s_ncols[i]) for i in range(len(s_readers))]
-            s_nrows = [n for _, n, _ in blocks]
-            s_bounds = [bs for _, _, bs in blocks]
+            s_nrows = [n for _, n, _, _ in blocks]
+            s_bounds = [bs for _, _, bs, _ in blocks]
             s_nlanes, s_lane_of = _lane_layout(s_readers, s_joins)
             s_acc_bounds = list(s_bounds[0])
             for ji, join in enumerate(s_joins):
                 if join.kind in ("inner", "left", "right"):
                     s_acc_bounds.extend(s_bounds[ji + 1])
-            s_specs = _make_join_specs(s_joins, s_nrows, s_acc_bounds, s_bounds, s_lane_of, ndev)
+            s_specs, _ = _make_join_specs(s_joins, s_nrows, s_acc_bounds, s_bounds, s_lane_of, ndev)
             s_total = _plan_schema_len(s_readers, s_joins)
             s_kb = []
             for g in s_gb:
@@ -2393,6 +2520,15 @@ class MPPGatherExec:
 
         group_cap = 0
         slot_join = None if has_stages else _slot_join(p.readers, p.joins, p.arm_folds, agg)
+        # the slot join put every group on one shard (hash: by its key) or
+        # left it on at most two (local: astride a cut; the root's merge of
+        # partials adds them), and no later fold moves the rows again
+        placed = (
+            ndev > 1
+            and slot_join is not None
+            and join_specs[slot_join].exchange in ("hash", "local")
+            and all(s.arm or keeps_rows(s.kind, s.unique, s.exchange, ndev) for s in join_specs[slot_join + 1 :])
+        )
         family = f"mpp_j{len(p.joins)}_" + (f"agg_g{len(agg.group_by)}" if agg is not None else "topn")
         if agg is not None:
             # a dispatching client may ship its stats-informed cap with the
@@ -2400,6 +2536,10 @@ class MPPGatherExec:
             group_cap = _pow2(
                 int(getattr(self, "_group_cap_hint", None) or self._initial_group_cap(nrows[0]))
             )
+            if placed:
+                # the cap is a shard's: where the groups are dealt over the
+                # shards by key, each holds a 1/ndev of them
+                group_cap = max(group_cap // ndev, 64)
         if agg is not None:
             nk = 2 * len(agg.group_by) if agg.group_by else 2
             ndk = 2 if dist_arg is not None else 0
@@ -2438,6 +2578,7 @@ class MPPGatherExec:
                     n_dkeys=ndk,
                     distinct_mask=dmask if ndk else (),
                     slot_join=slot_join,
+                    placed=placed,
                 )
                 if agg is not None
                 else None
@@ -2516,21 +2657,25 @@ class MPPGatherExec:
                     n_operands=len(operands),
                     bind_operands=bind_operands,
                     name=family,
+                    count_rows=True,
                 )
                 # traced and compiled here, not at the first call: the program
                 # phase owns the compile, the dispatch phase only enqueues
                 fn = fn.lower(*all_lanes, *operands).compile()
-                ph.note(cache="miss", compile_us=int((_t.perf_counter() - t_build) * 1e6))
+                # what the collectives move between chips a run, by kind, as COMPILED
+                # (lanes nothing reads are gone from it)
+                xchg_bytes = compiled_exchange_bytes(fn.as_text(), ndev) if ndev > 1 else {}
+                ph.note(cache="miss", compile_us=int((_t.perf_counter() - t_build) * 1e6), exchange=exchanges.replace(",", "+"))
                 # the sink is baked into the compiled program's closures: a
                 # cache hit must attribute warn counts via the ORIGINAL sink
                 with _MPP_CACHE_MU:
-                    _MPP_FN_CACHE[fn_key] = (fn, warn_sink)
+                    _MPP_FN_CACHE[fn_key] = (fn, warn_sink, xchg_bytes)
                     while len(_MPP_FN_CACHE) > 64:
                         _MPP_FN_CACHE.pop(next(iter(_MPP_FN_CACHE)))
             else:
                 _met.MPP_PROGRAM_CACHE.inc(result="hit")
-                ph.note(cache="hit")
-                fn, warn_sink = cached
+                ph.note(cache="hit", exchange=exchanges.replace(",", "+"))
+                fn, warn_sink, xchg_bytes = cached
             ph.to("dispatch", label=f"mpp-pipeline[{ndev}dev]", kernel=family)
             with _MESH_EXEC_LOCK:
                 import time as _t
@@ -2561,6 +2706,8 @@ class MPPGatherExec:
                 # grow-and-retry attempts overwrite: the SUCCESSFUL run wins
                 self._shard_obs = sorted(shard_obs)
             wtotal = int(arrs.pop())  # the warn-count slot (always present)
+            xchg_rows = int(arrs.pop())  # valid rows the exchanges carried
+            ph.note(xchg_bytes=sum(xchg_bytes.values()), xchg_rows=xchg_rows)
             if has_stages:
                 # per-stage exchanged bytes (staged-reader order) — feeds
                 # EXPLAIN ANALYZE's mpp_task line and the multichip dryrun
@@ -2589,6 +2736,7 @@ class MPPGatherExec:
                 for s in join_specs:
                     s.left_row_cap *= 4
                     s.right_row_cap *= 4
+                    s.halo_cap *= 4
                 for st in stage_runtimes:
                     if st is not None:
                         for s in st.spec.joins:
@@ -2603,6 +2751,9 @@ class MPPGatherExec:
                         st.spec.group_cap *= 4
                         for s in st.spec.joins:
                             s.out_cap *= 4
+        for kind, nbytes in xchg_bytes.items():
+            _met.MPP_EXCHANGE_BYTES.inc(nbytes, kind=kind)
+        self._exchange = (exchanges, dict(xchg_bytes), xchg_rows)
         ph.to("merge")
         out = self._merge(arrs[:-2], agg) if agg is not None else self._rows_chunk(arrs[:-2])
         ph.note(groups=len(out))

@@ -17,6 +17,7 @@ the exchange can never overflow.
 from __future__ import annotations
 
 import functools
+import re
 from dataclasses import dataclass, replace
 from typing import Callable, Sequence
 
@@ -57,6 +58,11 @@ class DistAggSpec:
     # slot, so the partial aggregate reduces by slot (:func:`_slot_partial`)
     # where the fragment took the direct-address lookup for that join
     slot_join: int | None = None
+    # every group's rows lie on ONE shard already (the slot join repartitioned
+    # by key), or on so few that the root's merge of partials is the merge
+    # (rows in place, a group astride a shard boundary): no exchange of group
+    # slots and no second reduction on the mesh. The gather's decision
+    placed: bool = False
 
 
 def _pack_keys(jnp, keys, bounds):
@@ -205,7 +211,14 @@ class DistJoinSpec:
     lanes) — left indices address the accumulated probe-side lane layout,
     right indices the build reader's local lanes.
     ``exchange``: "hash" (both sides shuffled by key owner — all_to_all) or
-    "broadcast" (right side replicated — all_gather).
+    "broadcast" (right side replicated — all_gather), or "local" (the
+    gather saw that each shard's probe rows span a narrow key range and that
+    the build side lies in key order: the probe stays, a shard keeps its own
+    build rows and is sent the few of every other shard's that fall in its
+    range — ``halo_cap`` rows a pair at the most — and builds its table over
+    ``local_codes`` keys from its range's low end, operand ``range_operand``
+    = [ndev, 2] packed key codes, the (low, high) of each shard's probe rows;
+    ``right_live`` = the build block's lane that says which rows exist).
     ``row_cap``: static per-destination receive capacity for hash exchange
     (overflow is reported, never silently dropped on the result path);
     ``left_row_cap``/``right_row_cap`` size the two sides independently —
@@ -219,7 +232,7 @@ class DistJoinSpec:
     # inner | left | semi | anti (ref: mpp_exec.go join types; outer fills
     # NULL build lanes, semi/anti filter the probe and append nothing)
     kind: str = "inner"
-    exchange: str = "hash"  # hash | broadcast
+    exchange: str = "hash"  # hash | broadcast | local
     row_cap: int = 4096
     left_row_cap: int | None = None
     right_row_cap: int | None = None
@@ -237,6 +250,10 @@ class DistJoinSpec:
     # first (the gather decides: a unique inner join whose probe keys all lie
     # in that build side, ``PhysMPPGather.arm_folds``)
     arm: bool = False
+    halo_cap: int = 0
+    local_codes: int = 0
+    range_operand: int = -1
+    right_live: int = -1
 
 
 def _combine_keys(jnp, keys):
@@ -283,40 +300,143 @@ def _exact_pair_lanes(jnp, lcomps, rcomps):
     return accl, accr, span
 
 
-def _route_rows(jax, jnp, arrays, valid, owner, ndev, cap):
+EXCHANGE_SCOPE = "mpp.exchange"
+EXCHANGE_KINDS = ("hash", "broadcast", "local", "groups")
+_COLLECTIVES = ("all-to-all", "all-gather", "all-reduce", "reduce-scatter", "collective-permute")
+_HLO_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+
+
+class _Exchange:
+    """The fragment program's collectives, every one under the named scopes
+    ``mpp.exchange`` / ``<kind>`` (``hash``: both sides of a join by key
+    owner, ``broadcast``: a build side to every shard, ``local``: a build
+    side's slivers, ``groups``: group slots to their owners, and the
+    replicated result): what :func:`compiled_exchange_bytes` tells them by."""
+
+    def __init__(self, jax, ndev: int):
+        self.jax, self.ndev = jax, ndev
+
+    def _scope(self, kind: str):
+        return self.jax.named_scope(f"{EXCHANGE_SCOPE}/{kind}")
+
+    def all_to_all(self, kind: str, buf):
+        """``buf`` [ndev, cap]: row d goes to shard d; → [ndev * cap], what every shard sent here."""
+        with self._scope(kind):
+            return self.jax.lax.all_to_all(buf, "dp", split_axis=0, concat_axis=0, tiled=False).reshape(-1)
+
+    def all_gather(self, kind: str, x):
+        with self._scope(kind):
+            return self.jax.lax.all_gather(x, "dp").reshape((-1,) + x.shape[1:])
+
+    def psum(self, kind: str, x):
+        with self._scope(kind):
+            return self.jax.lax.psum(x, "dp")
+
+
+def compiled_collectives(text: str) -> list[tuple[str, int, str]]:
+    """(operation, result bytes, op_name) of every collective in a compiled
+    program's HLO text. An asynchronous pair counts once, at its ``-start``,
+    whose result repeats the operand first: the last shape is the buffer."""
+    out = []
+    for m in re.finditer(r"^\s*\S+ = (\(.*?\)|\S+) ((?:%s)(?:-start)?)\(.*$" % "|".join(_COLLECTIVES), text, re.M):
+        shapes = re.findall(r"(\w+)\[([\d,]*)\]", m.group(1))
+        if m.group(2).endswith("-start") and len(shapes) > 1:
+            shapes = shapes[len(shapes) // 2 :]
+        nbytes = 0
+        for dtype, dims in shapes:
+            n = 1
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            nbytes += n * _HLO_BYTES[dtype]
+        name = re.search(r'op_name="([^"]*)"', m.group(0))
+        out.append((m.group(2), nbytes, name.group(1) if name else ""))
+    return out
+
+
+def compiled_exchange_bytes(text: str, ndev: int) -> dict:
+    """kind -> bytes a compiled fragment program moves between chips a run:
+    over its collectives, the result buffer x (ndev - 1): what leaves a chip
+    for another (the buffer's share that is not its own, (ndev - 1) / ndev)
+    summed over the chips. Padding counts; a lane the compiler dropped does
+    not. The kind is read off the scope the collective was traced under
+    (:class:`_Exchange`; one that lies under none counts as ``groups``)."""
+    out: dict = {}
+    for _op, nbytes, name in compiled_collectives(text):
+        _, _, tail = name.partition(EXCHANGE_SCOPE + "/")
+        kind = tail.split("/", 1)[0]
+        kind = kind if kind in EXCHANGE_KINDS else "groups"
+        out[kind] = out.get(kind, 0) + nbytes * (ndev - 1)
+    return out
+
+
+def _send_runs(xc, jnp, kind, lanes, first, last, cap, to=None, rows=None):
+    """Send destination d the rows [first[d], last[d]) of every lane, ``cap``
+    of them at the most: a destination's rows are one contiguous run, so its
+    send buffer is a slice, never a gather. ``to`` [ndev] / ``rows`` [n]
+    narrow it to some destinations / some rows of a run. Returns (what every
+    shard sent here, lane by lane; which of it holds a row; the rows sent)."""
+    jax, n = xc.jax, lanes[0].shape[0]
+    # a slice may not run off the end: it starts early instead, and what it
+    # then holds of the run before is masked
+    at = jnp.minimum(first, n - cap)
+    pos = at[:, None] + jnp.arange(cap, dtype=jnp.int32)[None, :]
+    ok = (pos >= first[:, None]) & (pos < last[:, None])
+
+    def blocks(x):  # [ndev, cap]: cap rows of x from each start
+        return jnp.stack([jax.lax.dynamic_slice(x, (at[d],), (cap,)) for d in range(xc.ndev)])
+
+    if to is not None:
+        ok = ok & to[:, None]
+    if rows is not None:
+        ok = ok & blocks(rows)
+    got = [xc.all_to_all(kind, jnp.where(ok, blocks(x), jnp.zeros((), x.dtype))) for x in lanes]
+    return got, xc.all_to_all(kind, ok), ok.sum()
+
+
+def _route_rows(xc, jnp, arrays, valid, owner, cap, kind="hash"):
     """Hash-exchange rows to owner shards (all_to_all with static per-dest
     capacity). Returns (received arrays, received valid, locally dropped).
 
-    Scatter-free: rows sort by destination, then every send-buffer slot
-    *gathers* its row (slot (d, r) ← sorted position start_d + r). TPU
-    lowers large scatters to a serialized loop; gathers vectorize."""
+    The lanes ride ONE sort by destination; a destination's rows are then a
+    contiguous run (:func:`_send_runs`): no scatter (TPU lowers large
+    scatters to a serialized loop) and no gather of a send buffer's size per
+    lane (element by element, ~116M a second on a v5e). A lane nothing reads
+    afterwards leaves the sort too."""
+    jax, ndev = xc.jax, xc.ndev
     if ndev == 1:
         # single-shard mesh: every row is already home — the exchange is the
         # identity and padding to ``cap`` would only add work
         return list(arrays), valid, jnp.int64(0)
-    n = valid.shape[0]
-    order = jnp.argsort(jnp.where(valid, owner, ndev), stable=True)
-    so = jnp.where(valid, owner, ndev)[order]
-    sv = valid[order]
+    cap = min(cap, valid.shape[0])  # one shard cannot send a destination more rows than it holds
+    dest = jnp.where(valid, owner, ndev).astype(jnp.int32)
+    flags = [a.dtype == jnp.bool_ for a in arrays]
+    so, *lanes = jax.lax.sort((dest, *(a.astype(jnp.int8) if f else a for a, f in zip(arrays, flags))), num_keys=1, is_stable=True)
     # per-destination block starts: ndev+1 searchsorted queries, not n
-    starts = jnp.searchsorted(so, jnp.arange(ndev + 1))
-    rank = jnp.arange(n) - starts[jnp.clip(so, 0, ndev)]
-    dropped = (sv & (rank >= cap)).sum()
-    dest = jnp.arange(ndev * cap) // cap
-    slot = jnp.arange(ndev * cap) % cap
-    src = starts[dest] + slot
-    src_c = jnp.clip(src, 0, n - 1)
-    ok = (src < n) & (so[src_c] == dest) & sv[src_c]
+    starts = jnp.searchsorted(so, jnp.arange(ndev + 1, dtype=jnp.int32)).astype(jnp.int32)
+    dropped = jnp.maximum(starts[1:] - starts[:-1] - cap, 0).sum().astype(jnp.int64)
+    got, ok, _ = _send_runs(xc, jnp, kind, lanes, starts[:-1], starts[1:], cap)
+    return [rx.astype(bool) if f else rx for rx, f in zip(got, flags)], ok, dropped
 
-    def exchange(buf):
-        return jax.lax.all_to_all(
-            buf.reshape(ndev, cap), "dp", split_axis=0, concat_axis=0, tiled=False
-        ).reshape(ndev * cap)
 
-    gidx = order[src_c]  # slot → original row, one composed gather index
-    out_arrays = [exchange(jnp.where(ok, x[gidx], 0)) for x in arrays]
-    out_valid = exchange(ok)
-    return out_arrays, out_valid, dropped
+def _send_slivers(xc, jnp, rcols, rvalid, rkey, live, rng, cap):
+    """The ``local`` exchange: every shard keeps its build rows and appends
+    what the others send it: of each other shard's build rows, those whose
+    key lies in the range this shard's probe rows span (``rng`` [ndev, 2],
+    low and high, a shard without rows high < low). ``rkey`` over the rows
+    that exist (``live``) does not decrease (the gather saw it), so what a
+    destination needs is one contiguous run; more than ``cap`` rows of it are
+    counted as dropped and the gather grows the cap. Returns (lanes, valid,
+    dropped, rows sent)."""
+    jax, ndev = xc.jax, xc.ndev
+    cap = min(cap, rvalid.shape[0])
+    sk = jnp.where(live, rkey, jnp.iinfo(rkey.dtype).max)
+    lo, hi = rng[:, 0].astype(rkey.dtype), rng[:, 1].astype(rkey.dtype)
+    first = jnp.searchsorted(sk, lo, side="left").astype(jnp.int32)
+    last = jnp.searchsorted(sk, hi, side="right").astype(jnp.int32)
+    others = (jnp.arange(ndev) != jax.lax.axis_index("dp")) & (hi >= lo)
+    dropped = jnp.where(others, jnp.maximum(last - first - cap, 0), 0).sum().astype(jnp.int64)
+    got, ok, sent = _send_runs(xc, jnp, "local", rcols, first, last, cap, to=others, rows=rvalid)
+    return [jnp.concatenate([c, rx]) for c, rx in zip(rcols, got)], jnp.concatenate([rvalid, ok]), dropped, sent
 
 
 def _sorted_lookup(jnp, rk_s, lkey):
@@ -580,16 +700,28 @@ class StageRuntime:
         self.chain_filters = chain_filters  # [(chain position, mask fn)]
 
 
-def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None):
+def keeps_rows(kind: str, unique: bool, exchange: str, ndev: int) -> bool:
+    """Does a fold of this kind leave every probe row where it was, once?
+    (A hash exchange moves them, an outer or a non-unique join adds rows.)
+    The by-slot aggregate and the gather's placing of joins both ask."""
+    return (ndev == 1 or exchange != "hash") and kind in ("inner", "semi", "anti") and unique
+
+
+def _fold_join(xc, jnp, join, acc, mask, rcols, rvalid, pf, slot_out=None, operands=()):
     """Fold ONE build side into the accumulated probe layout — the per-join
     body of the fragment pipeline, shared by the outer chain and the join
     chains INSIDE device stages. Returns (acc, mask, dropped, overflow,
-    xbytes) deltas accumulated into the caller's counters. ``slot_out``: a
-    dict that receives ``slot`` (each probe row's build row) and ``build``
-    (the build lanes it indexes) where the direct-address lookup ran."""
+    moved) deltas accumulated into the caller's counters; ``moved`` =
+    [bytes, rows] of the valid rows this fold handed to an exchange (the
+    lanes' own widths; what the buffers hold, :func:`compiled_exchange_bytes`).
+    ``slot_out``: a dict that receives ``slot`` (each probe row's build row)
+    and ``build`` (the build lanes it indexes) where the direct-address
+    lookup ran, and under a ``local`` exchange ``order`` (each matched probe
+    row's key code, -1 else). ``operands``: the program's, for a ``local`` join's ranges."""
+    jax, ndev = xc.jax, xc.ndev
     dropped = jnp.int64(0)
     overflow = jnp.int64(0)
-    xbytes = jnp.int64(0)
+    moved = jnp.zeros(2, jnp.int64)
     kb = tuple(join.key_bounds) if join.key_bounds else None
 
     def join_lane(comps, _kb=kb):
@@ -597,6 +729,9 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None
         if p is None:
             return _combine_keys(jnp, comps), None
         return p
+
+    def width(lanes):
+        return sum(a.dtype.itemsize for a in lanes)
 
     kind = join.kind
     lkeys = [acc[i] for i in join.left_keys]
@@ -611,15 +746,21 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None
         mask = mask & lkv
     lkey, ncodes = join_lane(lkeys)
     rkey, _ = join_lane(rkeys)
-    if join.exchange == "hash":
-        # NULL-key survivors route to shard 0 (they match nothing)
-        lowner = jnp.where(lkv, jnp.abs(lkey).astype(jnp.int64) % ndev, 0)
-        rowner = jnp.abs(rkey).astype(jnp.int64) % ndev
+    if join.exchange == "hash" and ndev > 1:
+        if ncodes is not None:
+            # packed codes are dense in [0, ncodes): code % ndev owns a key and
+            # code // ndev is dense among a shard's own, so the table a shard
+            # builds spans the keys it owns, a 1/ndev of the domain
+            lowner, rowner = lkey % ndev, rkey % ndev
+        else:
+            lowner, rowner = jnp.abs(lkey) % ndev, jnp.abs(rkey) % ndev
+        lowner = jnp.where(lkv, lowner, 0)  # NULL-key survivors route to shard 0 (they match nothing)
         lcap = join.left_row_cap or join.row_cap
         rcap = join.right_row_cap or join.row_cap
-        xbytes = xbytes + mask.sum() * (8 * len(acc)) + rvalid.sum() * (8 * len(rcols))
-        acc, mask, d1 = _route_rows(jax, jnp, acc, mask, lowner, ndev, lcap)
-        rcols, rvalid, d2 = _route_rows(jax, jnp, rcols, rvalid, rowner, ndev, rcap)
+        nl, nr = mask.sum().astype(jnp.int64), rvalid.sum().astype(jnp.int64)
+        moved = moved + jnp.stack([nl * width(acc) + nr * width(rcols), nl + nr])
+        acc, mask, d1 = _route_rows(xc, jnp, acc, mask, lowner, lcap)
+        rcols, rvalid, d2 = _route_rows(xc, jnp, rcols, rvalid, rowner, rcap)
         dropped = dropped + d1 + d2
         lkeys = [acc[i] for i in join.left_keys]
         rkeys = [rcols[i] for i in join.right_keys]
@@ -628,10 +769,27 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None
             lkv = lkv & acc[vl].astype(bool)
         lkey, ncodes = join_lane(lkeys)
         rkey, _ = join_lane(rkeys)
-    else:  # broadcast: replicate the build side on every shard
-        xbytes = xbytes + rvalid.sum() * (8 * len(rcols) * max(ndev - 1, 0))
-        rcols = [jax.lax.all_gather(c, "dp").reshape(-1) for c in rcols]
-        rvalid = jax.lax.all_gather(rvalid, "dp").reshape(-1)
+        if ncodes is not None:
+            lkey, rkey, ncodes = lkey // ndev, rkey // ndev, -(-ncodes // ndev)
+    elif join.exchange == "local" and ndev > 1:
+        rng = operands[join.range_operand]
+        rcols, rvalid, d2, sent = _send_slivers(
+            xc, jnp, rcols, rvalid, rkey, rcols[join.right_live].astype(bool), rng, join.halo_cap
+        )
+        dropped = dropped + d2
+        moved = moved + jnp.stack([sent * width(rcols), sent]).astype(jnp.int64)
+        rkeys = [rcols[i] for i in join.right_keys]
+        rkey, _ = join_lane(rkeys)
+        # the table spans this shard's range only: codes from its low end
+        low = rng[jax.lax.axis_index("dp"), 0].astype(lkey.dtype)
+        lkey, rkey, ncodes = lkey - low, rkey - low, join.local_codes
+        lkv = lkv & (lkey >= 0) & (lkey < ncodes)
+        rvalid = rvalid & (rkey >= 0) & (rkey < ncodes)
+    elif ndev > 1:  # broadcast: replicate the build side on every shard
+        nr = rvalid.sum().astype(jnp.int64)
+        moved = moved + jnp.stack([nr * width(rcols), nr])
+        rcols = [xc.all_gather("broadcast", c) for c in rcols]
+        rvalid = xc.all_gather("broadcast", rvalid)
         rkeys = [rcols[i] for i in join.right_keys]
         rkey, _ = join_lane(rkeys)
     rlive = rvalid  # post-selection build rows (right joins preserve
@@ -682,7 +840,7 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None
             jax, jnp, rkey, rkeys, rvalid, lkey, lkeys, probe_live, dead_b, dead_p
         )
         if join.exchange == "broadcast":
-            cnt_b = jax.lax.psum(cnt_b, "dp")
+            cnt_b = xc.psum("broadcast", cnt_b)
             emit = jax.lax.axis_index("dp") == 0
             unmatched = rlive & (cnt_b == 0) & emit
         else:
@@ -722,6 +880,11 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None
             gathered = [rc[at] for rc in rcols]  # lanes nothing reads are never gathered
             if slot_out is not None:
                 slot_out.update(slot=slot, build=rcols)
+                if join.exchange == "local" and ndev > 1:
+                    # slivers sit behind a shard's own build rows, so the slots of
+                    # probe rows in key order step back where the keys do not:
+                    # the key's code says which rows are one run
+                    slot_out["order"] = jnp.where(match, lkey, -1).astype(jnp.int32)
         else:
             gathered, match = _local_unique_join(
                 jax, jnp, lkey, lkeys, probe_live, rkey, rkeys, rcols, rvalid, dead_b, dead_p
@@ -745,15 +908,16 @@ def _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf, slot_out=None
         overflow = overflow + of
         mask = newmask
         acc = out_l + out_r
-    return acc, mask, dropped, overflow, xbytes
+    return acc, mask, dropped, overflow, moved
 
 
-def _exchange_group_slots(jax, jnp, ndev, cap, pkeys, psums, pcnt, route_keys=None):
+def _exchange_group_slots(xc, jnp, cap, pkeys, psums, pcnt, route_keys=None):
     """Hash-exchange per-shard group SLOTS to their key owners — the
     fragment-boundary ``all_to_all`` between a partial agg and its merge
     (shared by the final agg tail and inter-stage repartitions). Routes by
     ``route_keys`` (default: every key lane); returns (rxkeys, rxsums,
     rxcnt, slot_overflow)."""
+    ndev = xc.ndev
     h = _combine_keys(jnp, route_keys if route_keys is not None else pkeys)
     owner = jnp.where(pcnt > 0, jnp.abs(h) % ndev, ndev - 1)
     order = jnp.argsort(owner, stable=True)
@@ -767,9 +931,7 @@ def _exchange_group_slots(jax, jnp, ndev, cap, pkeys, psums, pcnt, route_keys=No
         return buf.at[so * cap + rank].set(x[order])
 
     def exchange(buf):
-        return jax.lax.all_to_all(
-            buf.reshape(ndev, cap), "dp", split_axis=0, concat_axis=0, tiled=False
-        ).reshape(ndev * cap)
+        return xc.all_to_all("groups", buf.reshape(ndev, cap))
 
     rxkeys = [exchange(bucketize(k)) for k in pkeys]
     rxsums = [exchange(bucketize(s)) for s in psums]
@@ -777,13 +939,13 @@ def _exchange_group_slots(jax, jnp, ndev, cap, pkeys, psums, pcnt, route_keys=No
     return rxkeys, rxsums, rxcnt, of_slots
 
 
-def _run_stage(jax, jnp, stage: StageRuntime, block, ndev):
+def _run_stage(xc, jnp, stage: StageRuntime, block):
     """Execute one DEVICE stage over its readers' input lane block: fold the
     stage's join chain, run the two-phase grouped agg (partial →
     group-owner all_to_all → merge), finalize to build lanes. The returned
     lanes are per-shard ``group_cap`` slots, DEVICE-RESIDENT — the consumer
     join's exchange re-partitions them on the new key without any host
-    round-trip. Returns (out_lanes, out_valid, dropped, overflow, xbytes)."""
+    round-trip. Returns (out_lanes, out_valid, dropped, overflow, moved)."""
     spec = stage.spec
 
     def _chain(pos, acc, mask):
@@ -800,15 +962,15 @@ def _run_stage(jax, jnp, stage: StageRuntime, block, ndev):
     mask = _chain(0, acc, mask)
     dropped = jnp.int64(0)
     overflow = jnp.int64(0)
-    xbytes = jnp.int64(0)
+    moved = jnp.zeros(2, jnp.int64)
     for ji, join in enumerate(spec.joins):
         rcols = list(block[soffs[ji + 1] : soffs[ji + 2]])
         rvalid = jnp.ones(rcols[0].shape[0], dtype=bool)
         if stage.selections[ji + 1] is not None:
             rvalid = stage.selections[ji + 1](*rcols)
         pf = stage.pair_filters[ji] if stage.pair_filters is not None else None
-        acc, mask, d, of, xb = _fold_join(jax, jnp, join, ndev, acc, mask, rcols, rvalid, pf)
-        dropped, overflow, xbytes = dropped + d, overflow + of, xbytes + xb
+        acc, mask, d, of, mv = _fold_join(xc, jnp, join, acc, mask, rcols, rvalid, pf)
+        dropped, overflow, moved = dropped + d, overflow + of, moved + mv
         mask = _chain(ji + 1, acc, mask)
     acols = stage.agg_inputs(acc)
     keys = list(acols[: spec.n_keys])
@@ -817,11 +979,10 @@ def _run_stage(jax, jnp, stage: StageRuntime, block, ndev):
         jnp, keys, vals, mask, spec.group_cap, spec.key_bounds, spec.val_kinds
     )
     # the inter-stage repartition: live group slots cross the mesh ONCE,
-    # 8 B per lane per slot (keys + sums + count) — all on ICI
-    xbytes = xbytes + (pcnt > 0).sum() * jnp.int64(8 * (len(pkeys) + len(psums) + 1))
-    rxkeys, rxsums, rxcnt, of_slots = _exchange_group_slots(
-        jax, jnp, ndev, spec.group_cap, pkeys, psums, pcnt
-    )
+    # each lane at its own width (keys + sums + count) — all on ICI
+    nslots = (pcnt > 0).sum().astype(jnp.int64)
+    moved = moved + jnp.stack([nslots * sum(a.dtype.itemsize for a in (*pkeys, *psums, pcnt)), nslots])
+    rxkeys, rxsums, rxcnt, of_slots = _exchange_group_slots(xc, jnp, spec.group_cap, pkeys, psums, pcnt)
     mkeys, msums_cnt, _, of3 = _segment_partial(
         jnp,
         rxkeys,
@@ -835,7 +996,7 @@ def _run_stage(jax, jnp, stage: StageRuntime, block, ndev):
     # trailing live lane keeps the block layout identical to a plain
     # reader's (2*ncols data/valid pairs + live), so the accumulated lane
     # offsets downstream stay uniform
-    return out_lanes + [out_valid], out_valid, dropped, overflow + of1 + of_slots + of3, xbytes
+    return out_lanes + [out_valid], out_valid, dropped, overflow + of1 + of_slots + of3, moved
 
 
 @dataclass
@@ -871,6 +1032,7 @@ def build_dist_pipeline(
     n_operands: int = 0,
     bind_operands: Callable | None = None,
     name: str = "mpp",
+    count_rows: bool = False,
 ):
     """The generalized MPP pipeline in ONE jitted shard_map (ref: §3.3 —
     fragments: scan→sel→[exchange→join]*→(partial agg→hash exchange→merge |
@@ -903,14 +1065,20 @@ def build_dist_pipeline(
     ``n_operands`` scalars follow the lanes: the literals of the readers'
     pushed conditions, handed to ``bind_operands`` at trace time so that the
     selections read them as traced values — one program serves every literal.
+    A ``local`` join's ranges ([ndev, 2] each) follow them, replicated too
+    (``DistJoinSpec.range_operand`` indexes the whole operand list).
     ``name`` names the XLA module (``jit_<name>``); the stages carry the
-    scopes ``mpp.build``, ``mpp.probe``, ``mpp.agg``, ``mpp.topn`` and
-    ``mpp.exchange``."""
+    scopes ``mpp.build``, ``mpp.probe``, ``mpp.agg``, ``mpp.topn``, and every
+    collective lies under ``mpp.exchange`` (:class:`_Exchange`).
+
+    ``count_rows``: the program emits one more replicated output before the
+    warn count: the valid rows its exchanges carried, all shards summed."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     ndev = mesh.devices.size
+    xc = _Exchange(jax, ndev)
     cap = agg.group_cap if agg is not None else 0
     n_readers = len(n_lanes)
     offs = [sum(n_lanes[:i]) for i in range(n_readers + 1)]
@@ -926,8 +1094,9 @@ def build_dist_pipeline(
         return mask
 
     def step(*cols):
+        operands = cols[offs[-1] :]
         if bind_operands is not None:
-            bind_operands(cols[offs[-1] :])
+            bind_operands(operands)
         acc = list(cols[offs[0] : offs[1]])
         mask = jnp.ones(acc[0].shape[0], dtype=bool)
         if selections[0] is not None:
@@ -935,9 +1104,10 @@ def build_dist_pipeline(
         mask = _apply_chain(0, acc, mask)
         dropped = jnp.int64(0)
         overflow = jnp.int64(0)
-        # per-shard exchanged-byte estimate (8 B per lane per routed row);
-        # DCE'd when no shard_probe consumes it
-        xbytes = jnp.int64(0)
+        # per-shard [bytes, rows] of the valid rows handed to an exchange (a
+        # lane at its own width); DCE'd where neither a shard_probe nor
+        # ``count_rows`` consumes it
+        moved = jnp.zeros(2, jnp.int64)
         # per-stage exchanged bytes (reader order), replicated output when
         # any stage exists — the dryrun/EXPLAIN per-stage breakdown
         stage_xb: list = []
@@ -946,11 +1116,11 @@ def build_dist_pipeline(
             block = list(cols[offs[ji + 1] : offs[ji + 2]])
             stage = stages[ji + 1] if stages is not None else None
             if stage is not None:
-                rcols, rvalid, d_s, of_s, xb_s = _run_stage(jax, jnp, stage, block, ndev)
+                rcols, rvalid, d_s, of_s, mv_s = _run_stage(xc, jnp, stage, block)
                 dropped = dropped + d_s
                 overflow = overflow + of_s
-                xbytes = xbytes + xb_s
-                stage_xb.append(xb_s)
+                moved = moved + mv_s
+                stage_xb.append(mv_s[0])
             else:
                 rcols = block
                 rvalid = jnp.ones(rcols[0].shape[0], dtype=bool)
@@ -980,9 +1150,9 @@ def build_dist_pipeline(
                 left_row_cap=before.right_row_cap,
             )
             with jax.named_scope("mpp.build"):
-                bl, bv, d, of, xb = _fold_join(jax, jnp, arm, ndev, *builds[ji - 1], *builds[ji], None)
+                bl, bv, d, of, mv = _fold_join(xc, jnp, arm, *builds[ji - 1], *builds[ji], None, None, operands)
             builds[ji - 1] = (bl, bv)
-            dropped, overflow, xbytes = dropped + d, overflow + of, xbytes + xb
+            dropped, overflow, moved = dropped + d, overflow + of, moved + mv
         slot = None  # by-slot aggregate: (each row's build slot, that build's lanes, where they start)
         for ji, join in enumerate(joins):
             if join.arm:
@@ -990,32 +1160,32 @@ def build_dist_pipeline(
                 continue
             pf = pair_filters[ji] if pair_filters is not None else None
             took = {} if agg is not None and agg.slot_join == ji else None
-            if slot is not None and not (
-                (ndev == 1 or join.exchange != "hash") and join.kind in ("inner", "semi", "anti") and join.unique
-            ):
+            if slot is not None and not keeps_rows(join.kind, join.unique, join.exchange, ndev):
                 slot = None  # this fold moves or multiplies the probe rows
             n_before = len(acc)
             with jax.named_scope("mpp.probe"):
-                acc, mask, d, of, xb = _fold_join(jax, jnp, join, ndev, acc, mask, *builds[ji], pf, took)
-            dropped, overflow, xbytes = dropped + d, overflow + of, xbytes + xb
+                acc, mask, d, of, mv = _fold_join(xc, jnp, join, acc, mask, *builds[ji], pf, took, operands)
+            dropped, overflow, moved = dropped + d, overflow + of, moved + mv
             if took:
-                slot = (took["slot"], took["build"], n_before)
+                slot = (took["slot"], took["build"], n_before, took.get("order"))
             mask = _apply_chain(ji + 1, acc, mask)
         if agg is not None:
             with jax.named_scope("mpp.agg"):
-                outs, local_rows = _agg_tail(acc, mask, dropped, overflow, slot)
+                outs, local_rows, sent = _agg_tail(acc, mask, dropped, overflow, slot)
         else:
             with jax.named_scope("mpp.topn"):
-                outs, local_rows = _topn_tail(acc, mask, dropped, overflow)
+                outs, local_rows, sent = _topn_tail(acc, mask, dropped, overflow)
         if shard_probe is not None:
             # effect-only host callback; local_rows depends on the shard's
             # tail reduction, so the probe fires after this shard's compute
             # but BEFORE the synchronizing gathers equalize finish times
-            jax.debug.callback(shard_probe, jax.lax.axis_index("dp"), local_rows, xbytes)
+            jax.debug.callback(shard_probe, jax.lax.axis_index("dp"), local_rows, moved[0] + sent[0])
         if stage_xb:
             # per-stage exchange bytes, summed across shards — rides home as
             # one replicated vector (staged-reader order)
-            outs = (*outs, jax.lax.psum(jnp.stack(stage_xb), "dp"))
+            outs = (*outs, xc.psum("groups", jnp.stack(stage_xb)))
+        if count_rows:
+            outs = (*outs, xc.psum("groups", moved[1] + sent[1]))
         if warn_sink is not None:
             # device warnings born inside the fragment (division by 0 in a
             # selection/agg argument) ride ONE replicated count output —
@@ -1024,7 +1194,7 @@ def build_dist_pipeline(
             wtotal = jnp.int64(0)
             for _code, _msg, c in warn_sink.items:
                 wtotal = wtotal + jnp.asarray(c, jnp.int64)
-            outs = (*outs, jax.lax.psum(wtotal, "dp"))
+            outs = (*outs, xc.psum("groups", wtotal))
         return outs
 
     def _topn_tail(joined, mask, dropped, overflow):
@@ -1052,14 +1222,16 @@ def build_dist_pipeline(
             overflow = overflow + jnp.maximum(cnt - out_n, 0)
         outs = []
         for di, vi in topn.out_lanes:
-            outs.append(jax.lax.all_gather(joined[di][head], "dp").reshape(-1))
+            outs.append(xc.all_gather("groups", joined[di][head]))
             v = joined[vi][head] if vi is not None else jnp.ones(out_n, jnp.int64)
-            outs.append(jax.lax.all_gather(v, "dp").reshape(-1))
-        glive = jax.lax.all_gather(mask[perm][:out_n], "dp").reshape(-1)
-        total = jax.lax.psum(cnt, "dp")
-        gdropped = jax.lax.psum(dropped, "dp")
-        goverflow = jax.lax.psum(overflow, "dp")
-        return (*outs, glive, total, gdropped, goverflow), cnt
+            outs.append(xc.all_gather("groups", v))
+        hlive = mask[perm][:out_n]
+        glive = xc.all_gather("groups", hlive)
+        total = xc.psum("groups", cnt)
+        gdropped = xc.psum("groups", dropped)
+        goverflow = xc.psum("groups", overflow)
+        nlive = hlive.sum().astype(jnp.int64)  # this shard's head rows in the gathered result
+        return (*outs, glive, total, gdropped, goverflow), cnt, jnp.stack([nlive * sum(o.dtype.itemsize for o in outs), nlive])
 
     def _agg_tail(joined, mask, dropped, overflow, slot=None):
         acols = agg_inputs(joined) if agg_inputs is not None else joined
@@ -1070,8 +1242,10 @@ def build_dist_pipeline(
             # slot, and the group's key lanes read where the group is known —
             # build lanes at its slot, probe lanes at its first row — so no
             # build lane is ever gathered out to the probe's row count
-            idx, build, at = slot
-            gslot, grow, psums, pcnt, of1 = _slot_partial(jax, jnp, idx, mask, vals, cap)
+            idx, build, at, order = slot
+            gslot, grow, psums, pcnt, of1 = _slot_partial(jax, jnp, idx if order is None else order, mask, vals, cap)
+            if order is not None:
+                gslot = jnp.maximum(idx[grow], 0)  # the runs were told by key code: a group's build row is its first probe row's
             head = [a[grow] for a in joined[:at]] + [b[gslot] for b in build]
             head += [a[grow] for a in joined[at + len(build) :]]
             pkeys = [jnp.where(pcnt > 0, k, 0) for k in agg_inputs(head)[:G]]
@@ -1080,17 +1254,17 @@ def build_dist_pipeline(
             # IS the dedup (ref: TiFlash two-phase distinct aggregation)
             keys = list(acols[: G + D])
             pkeys, psums, pcnt, of1 = _segment_partial(jnp, keys, vals, mask, cap, agg.key_bounds, agg.val_kinds)
-        if ndev == 1:
+        sent = jnp.int64(0)  # live group slots handed to the exchange, then to the gathered result
+        if ndev == 1 or (agg.placed and not D):
             # one shard: its partial groups are the groups — nothing to
-            # exchange, nothing to merge
+            # exchange, nothing to merge. The same where the gather placed
+            # every group on one shard (``DistAggSpec.placed``)
             mkeys, msums_cnt, of_slots, of3 = pkeys, psums + [pcnt], 0, 0
         else:
             # route by GROUP keys only: every (g, *) slot lands on g's owner
             # shard, where x dedups globally
-            with jax.named_scope("mpp.exchange"):
-                rxkeys, rxsums, rxcnt, of_slots = _exchange_group_slots(
-                    jax, jnp, ndev, cap, pkeys, psums, pcnt, route_keys=pkeys[:G]
-                )
+            sent = (pcnt > 0).sum().astype(jnp.int64)
+            rxkeys, rxsums, rxcnt, of_slots = _exchange_group_slots(xc, jnp, cap, pkeys, psums, pcnt, route_keys=pkeys[:G])
             mkeys, msums_cnt, _, of3 = _segment_partial(jnp, rxkeys, rxsums + [rxcnt], rxcnt > 0, cap, agg.key_bounds, tuple(agg.val_kinds) + ("sum",))
         if D:
             # stage 3: per-g reduction over the deduped (g, x) slots — the
@@ -1118,16 +1292,18 @@ def build_dist_pipeline(
             out_keys, gcnt_local = fkeys, fsums[-1]
         else:
             out_keys, out_sums, gcnt_local = mkeys, list(msums_cnt[:-1]), msums_cnt[-1]
-        gkeys = [jax.lax.all_gather(k, "dp").reshape(ndev * cap) for k in out_keys]
-        gsums = [jax.lax.all_gather(s, "dp").reshape(ndev * cap) for s in out_sums]
-        gcnt = jax.lax.all_gather(gcnt_local, "dp").reshape(ndev * cap)
-        total = jax.lax.psum(mask.sum(), "dp")
-        gdropped = jax.lax.psum(dropped, "dp")
-        goverflow = jax.lax.psum(overflow + of1 + of_slots + of3, "dp")
+        gkeys = [xc.all_gather("groups", k) for k in out_keys]
+        gsums = [xc.all_gather("groups", s) for s in out_sums]
+        gcnt = xc.all_gather("groups", gcnt_local)
+        total = xc.psum("groups", mask.sum())
+        gdropped = xc.psum("groups", dropped)
+        goverflow = xc.psum("groups", overflow + of1 + of_slots + of3)
         # shard-local live groups after the merge stage — the shard probe's
         # "rows produced" (depends on this shard's heavy reductions)
         local_rows = (gcnt_local > 0).sum()
-        return (*gkeys, *gsums, gcnt, total, gdropped, goverflow), local_rows
+        sent = sent + local_rows.astype(jnp.int64)
+        width = sum(o.dtype.itemsize for o in (*out_keys, *out_sums, gcnt_local))
+        return (*gkeys, *gsums, gcnt, total, gdropped, goverflow), local_rows, jnp.stack([sent * width, sent])
 
     if agg is not None:
         if agg.n_dkeys:
@@ -1139,6 +1315,8 @@ def build_dist_pipeline(
     extra = ()
     if stages is not None and any(s is not None for s in stages):
         extra += (P(None),)  # per-stage exchange-bytes vector
+    if count_rows:
+        extra += (P(),)  # valid rows the exchanges carried
     if warn_sink is not None:
         extra += (P(),)
     step.__name__ = step.__qualname__ = name  # the XLA module is jit_<name>

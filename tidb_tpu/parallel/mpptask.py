@@ -381,6 +381,9 @@ class MPPTaskManager:
                     "compiles": det.compiles if det is not None else 0,
                     "stages": det.stages if det is not None else 1,
                     "stage_bytes": det.stage_bytes if det is not None else [],
+                    "exchange": det.exchange if det is not None else "",
+                    "xchg_bytes": det.xchg_bytes if det is not None else {},
+                    "xchg_rows": det.xchg_rows if det is not None else 0,
                 }
             except Exception as e:  # travels the wire as (kind, message)
                 task["kind"] = type(e).__name__

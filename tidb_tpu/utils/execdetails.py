@@ -315,10 +315,10 @@ class MPPExecDetails:
     WHICH device inside the collective was slow."""
 
     __slots__ = ("n_fragments", "ndev", "wall_ms", "rows", "retries", "store", "shards", "compiles",
-                 "stages", "stage_bytes")
+                 "stages", "stage_bytes", "exchange", "xchg_bytes", "xchg_rows")
 
     def __init__(self, n_fragments=0, ndev=0, wall_ms=0.0, rows=0, retries=0, store="", shards=None,
-                 compiles=0, stages=1, stage_bytes=None):
+                 compiles=0, stages=1, stage_bytes=None, exchange="", xchg_bytes=None, xchg_rows=0):
         self.n_fragments = n_fragments
         self.ndev = ndev
         self.wall_ms = wall_ms
@@ -334,6 +334,13 @@ class MPPExecDetails:
         # inter-stage exchanged bytes (all on ICI — zero host bytes)
         self.stages = stages
         self.stage_bytes = stage_bytes or []
+        # what the program RAN, join by join ("local,broadcast": the gather may
+        # leave in place what the planner meant to move), the bytes its
+        # collectives move between chips by kind (as compiled; padding counts)
+        # and the valid rows in them
+        self.exchange = exchange
+        self.xchg_bytes = dict(xchg_bytes or {})
+        self.xchg_rows = xchg_rows
 
     def shard_summary(self) -> "tuple | None":
         """(max_ms, min_ms, p95_ms, slowest_shard_id) or None."""
@@ -356,6 +363,11 @@ class MPPExecDetails:
             parts.append(
                 "stage_bytes: [" + ", ".join(str(int(b)) for b in self.stage_bytes) + "]"
             )
+        if self.exchange and self.ndev > 1:
+            parts.append(f"exchange: {self.exchange}")
+            parts.append("xchg_bytes: " + str(sum(self.xchg_bytes.values())) + " ("
+                         + ", ".join(f"{k} {v}" for k, v in sorted(self.xchg_bytes.items()) if v) + ")")
+            parts.append(f"xchg_rows: {self.xchg_rows}")
         ss = self.shard_summary()
         if ss is not None:
             mx, mn, p95, slowest = ss

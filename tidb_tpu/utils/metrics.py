@@ -328,6 +328,18 @@ MPP_PROGRAM_CACHE = REGISTRY.counter(
     "MPP fragment-program cache lookups by outcome",
     ("result",),
 )
+# what the fragment programs' collectives move between the chips of the mesh
+# (parallel/mpp.compiled_exchange_bytes of the program a gather ran:
+# each collective's buffer x (ndev - 1) / ndev a chip, all chips summed;
+# padding counts): hash = both sides of a join repartitioned by key,
+# broadcast = a build side replicated, local = the slivers of a build side
+# stored in key order that another shard's probe rows reach into, groups =
+# group slots to their owners and the replicated result
+MPP_EXCHANGE_BYTES = REGISTRY.counter(
+    "tidb_tpu_mpp_exchange_bytes_total",
+    "Bytes the MPP fragment programs' collectives moved between chips, by exchange kind",
+    ("kind",),
+)
 # cross-store × cross-chip hybrid gathers: a straddling gather (tables on
 # multiple store shards) ran on the coordinator's mesh with per-owner wire
 # reads instead of degrading to the host join
