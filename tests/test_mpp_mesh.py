@@ -105,6 +105,10 @@ def test_q3_agrees_with_the_plain_reference_under_every_exchange(tpch, answers, 
     assert tpl.ref.same(rows, tpl.ref.state(tpl.ref_columns(cols), drawn)), (plan, drawn, rows)
     (d,) = details  # ONE gather, on exactly four devices, no second attempt
     assert d.ndev == NDEV and d.retries == 0 and d.exchange == PLANS[plan][2]
+    # (PR 34) `lineitem` in key order is probed by blocks; shuffled, some block spans more than two rows of
+    # the bitmap and the whole lane takes the element gather (a hash exchange sorts a shard's rows by
+    # destination, not by key). The program sees which, and answers the same
+    assert d.probe == ("blocked,blocked" if plan == "local" else "gather,blocked"), d.render()
 
 
 @pytest.mark.parametrize("plan", list(PLANS))

@@ -121,6 +121,35 @@ def test_eight_parameter_sets_are_one_fragment_program(answers, ndev):
     assert built[0] == 1 and sum(built) == 1, built  # the first statement builds it, the other seven find it
 
 
+@pytest.mark.parametrize("ndev", [1, 4])
+@pytest.mark.parametrize("k", range(8))
+def test_every_probe_row_of_q3_is_answered_by_blocks(answers, ndev, k):
+    """(PR 34) `lineitem` lies in `l_orderkey` order, so every block of its
+    probe rows reads two adjacent rows of `orders`' presence bitmap: the
+    program sees it in its data, under every parameter set, on one shard and
+    on four (the bitmap a shard's own table's, slivers in). The arm (`orders`
+    probing `customer` by `o_custkey`, in no order) is blocked here too: SF
+    0.01's 1,500 customer codes lie in ONE row of the bitmap; SF2's 300,000
+    span 73, and the arm takes the element gather (on the chip: 4 of 5 probe
+    rows blocked; a shuffled `lineitem` here: `tests/test_mpp_mesh.py`)."""
+    (d,) = answers[ndev][k][2]
+    assert d.probe == "blocked,blocked", d.render()
+
+
+def test_explain_analyze_and_the_metrics_page_say_how_the_probes_were_answered(tpch, session):
+    from tidb_tpu.utils import metrics
+
+    _, tpl, _ = tpch
+    before = {how: metrics.MPP_PROBE_ROWS.get(how=how) for how in ("blocked", "gather")}
+    ran = "\n".join(str(r[0]) for r in session.query("EXPLAIN ANALYZE " + tpl.first_text))
+    assert "mpp_task: {" in ran and "probe: blocked,blocked" in ran, ran
+    blocked, gathered = (metrics.MPP_PROBE_ROWS.get(how=how) - before[how] for how in ("blocked", "gather"))
+    # the padded lanes of `lineitem` (~60k rows) and of `orders` (15k), whatever the mesh the suite runs on
+    assert 75_000 <= blocked <= 2 * (65_536 + 16_384) and gathered == 0, (blocked, gathered)
+    page = metrics.REGISTRY.render()
+    assert 'tidb_tpu_mpp_probe_rows_total{how="blocked"}' in page and 'tidb_tpu_mpp_probe_rows_total{how="gather"}' in page
+
+
 def test_the_shipped_fragment_program_holds_no_host_callback(tpch, session, monkeypatch):
     """Probes off as shipped: nothing in the program calls back into Python, so
     its executable can persist in the compile cache; probes on, something does."""
@@ -203,6 +232,9 @@ def test_what_the_spans_say(profiled):
     assert {st["cache"] for _, _, st in profiled["mpp.program"]} == {"hit"}
     assert {st["kernel"] for _, _, st in profiled["mpp.dispatch"]} == {"mpp_j2_agg_g3"}
     assert all(int(st["groups"]) > 10 for _, _, st in profiled["mpp.merge"])
+    for _, _, st in profiled["mpp.fetch"]:
+        # (PR 34) the padded probe rows of both folds (lineitem's ~64k, orders' ~16k), all answered by blocks at this scale
+        assert int(st["probe_rows_blocked"]) == int(st["probe_rows"]) >= 75_000
 
 
 def test_rehearsal_of_the_cell_is_correct_and_prints_its_metrics(tmp_path):
@@ -219,8 +251,9 @@ def test_rehearsal_of_the_cell_is_correct_and_prints_its_metrics(tmp_path):
     assert {k: c["value"] for k, c in line["checks"].items()} == {"answers_wrong": 0, "statements_failed": 0, "not_on_device": 0}
     m = line["metrics"]
     # every new per-layer metric but `mpp_kernel_ms`, which reads the TPU's `XLA Modules` line: no such line here
-    for name in ("mpp_gather_p50_ms", "mpp_lanes_ms", "mpp_dispatch_ms", "mpp_fetch_ms", "mpp_merge_ms", "mpp_padded_ratio"):
+    for name in ("mpp_gather_p50_ms", "mpp_lanes_ms", "mpp_dispatch_ms", "mpp_fetch_ms", "mpp_merge_ms", "mpp_padded_ratio", "mpp_probe_blocked_pct"):
         assert name in m, sorted(m)
+    assert m["mpp_probe_blocked_pct"]["value"] == pytest.approx(100.0)  # at SF2 80: `customer`'s codes then span 73 rows of the bitmap, the arm gathers
     assert "mpp_kernel_ms" not in m and "scan_roofline" not in m  # no chip, no kernel time, no share
     assert m["compiles_in_window"]["value"] == 0 and 1.0 <= m["mpp_padded_ratio"]["value"] <= 2.0
     phases = sum(m[n]["value"] for n in ("mpp_lanes_ms", "mpp_dispatch_ms", "mpp_fetch_ms", "mpp_merge_ms"))

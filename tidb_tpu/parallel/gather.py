@@ -1731,6 +1731,7 @@ class MPPGatherExec:
                             exchange=self._exchange[0],
                             xchg_bytes=self._exchange[1],
                             xchg_rows=self._exchange[2],
+                            probe=self._exchange[3],
                         ),
                     )
                     return out
@@ -1882,6 +1883,7 @@ class MPPGatherExec:
                 exchange=str(e.get("exchange", "")),
                 xchg_bytes={str(k): int(v) for k, v in (e.get("xchg_bytes") or {}).items()},
                 xchg_rows=int(e.get("xchg_rows", 0)),
+                probe=str(e.get("probe", "")),
             ),
         )
         return chunk
@@ -1898,6 +1900,7 @@ class MPPGatherExec:
             build_dist_pipeline,
             compiled_exchange_bytes,
             keeps_rows,
+            probe_paths,
         )
 
         p = self.plan
@@ -2706,8 +2709,11 @@ class MPPGatherExec:
                 # grow-and-retry attempts overwrite: the SUCCESSFUL run wins
                 self._shard_obs = sorted(shard_obs)
             wtotal = int(arrs.pop())  # the warn-count slot (always present)
-            xchg_rows = int(arrs.pop())  # valid rows the exchanges carried
-            ph.note(xchg_bytes=sum(xchg_bytes.values()), xchg_rows=xchg_rows)
+            counts = np.asarray(arrs.pop())
+            xchg_rows = int(counts[0])  # valid rows the exchanges carried
+            probed = counts[1:].reshape(-1, 2)  # join by join: probe rows of a direct-address lookup, of them answered by blocks
+            probe_rows, probe_blocked = (int(x) for x in probed.sum(axis=0))
+            ph.note(xchg_bytes=sum(xchg_bytes.values()), xchg_rows=xchg_rows, probe_rows=probe_rows, probe_rows_blocked=probe_blocked)
             if has_stages:
                 # per-stage exchanged bytes (staged-reader order) — feeds
                 # EXPLAIN ANALYZE's mpp_task line and the multichip dryrun
@@ -2753,7 +2759,9 @@ class MPPGatherExec:
                             s.out_cap *= 4
         for kind, nbytes in xchg_bytes.items():
             _met.MPP_EXCHANGE_BYTES.inc(nbytes, kind=kind)
-        self._exchange = (exchanges, dict(xchg_bytes), xchg_rows)
+        _met.MPP_PROBE_ROWS.inc(probe_blocked, how="blocked")
+        _met.MPP_PROBE_ROWS.inc(probe_rows - probe_blocked, how="gather")
+        self._exchange = (exchanges, dict(xchg_bytes), xchg_rows, probe_paths(probed))
         ph.to("merge")
         out = self._merge(arrs[:-2], agg) if agg is not None else self._rows_chunk(arrs[:-2])
         ph.note(groups=len(out))

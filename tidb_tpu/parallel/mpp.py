@@ -459,32 +459,102 @@ def _sorted_lookup(jnp, rk_s, lkey):
 DIRECT_DOMAIN_MAX = 1 << 27
 
 
-def _direct_lookup(jnp, lkey, lvalid, rkey, rvalid, n_codes):
-    """The build row of each probe row, -1 where it has none, through a
-    direct-address table over the key's domain: one scatter of the build
-    side's row numbers, one gather by the probe keys, no sort. Keys are
-    packed codes in [0, n_codes) (:func:`_pack_keys` over the JOINT bounds of
-    both sides, so equal codes are equal keys); the build side holds a code
-    at most once. On one v5e (builder's chip run, PR 29): 25 ms to scatter 4M
-    rows into 12M slots, 145 ms to gather 16M int32; the sort-merge lookup
-    sorts build + probe concatenated, twice."""
-    m = rkey.shape[0]
-    table = jnp.full(n_codes, -1, jnp.int32).at[jnp.where(rvalid, rkey, n_codes)].set(
-        jnp.arange(m, dtype=jnp.int32), mode="drop"
+def _direct_table(jnp, rkey, rvalid, n_codes):
+    """The direct-address table over a key's domain: each code's build row,
+    -1 where the build side holds none. One scatter of the build side's row
+    numbers, no sort. Keys are packed codes in [0, n_codes) (:func:`_pack_keys`
+    over the JOINT bounds of both sides, so equal codes are equal keys); the
+    build side holds a code at most once. On one v5e (builder's chip run,
+    PR 29): 25 ms to scatter 4M rows into 12M slots."""
+    return jnp.full(n_codes, -1, jnp.int32).at[jnp.where(rvalid, rkey, n_codes)].set(
+        jnp.arange(rkey.shape[0], dtype=jnp.int32), mode="drop"
     )
+
+
+def _direct_lookup(jnp, lkey, lvalid, rkey, rvalid, n_codes):
+    """The build row of each probe row, -1 where it has none: one gather from
+    :func:`_direct_table` by the probe keys, element by element (145 ms for
+    16M int32 on one v5e, PR 29, sorted or not; the sort-merge lookup sorts
+    build + probe concatenated, twice). What a probe row carries where build
+    lanes are read at the probe's row count; a join read only for "did the
+    row match" and "which group" asks :func:`_probe_match` instead."""
+    return _table_rows(jnp, _direct_table(jnp, rkey, rvalid, n_codes), lkey, lvalid)
+
+
+def _table_rows(jnp, table, lkey, lvalid):
+    """``table`` at each valid row's key code, one element a row; -1 for the others."""
     return jnp.where(lvalid, table[jnp.where(lvalid, lkey, 0)], -1)
 
 
+# the blocked probe: probe rows a block, and the codes a row of the table's
+# presence bitmap holds (128 words, the chip's lanes, of 32 codes each). A
+# block reads the two rows from its least live code's on: 4,097 to 8,192 codes,
+# where 128 rows of a fact table in its dimension's key order span 128 keys
+# (TPC-H's order keys, 8 of every 32 values: at most ~540 codes)
+PROBE_BLOCK = 128
+_ROW_CODES = 128 * 32
+
+
+def _probe_match(jax, jnp, table, lkey, live):
+    """Which probe rows have a build row in ``table`` (:func:`_direct_table`),
+    bit for bit ``_direct_lookup(...) >= 0``, and how it was found. Probe rows
+    in key order (`lineitem` by `l_orderkey`) are answered by BLOCKS: the live
+    codes of ``PROBE_BLOCK`` consecutive rows lie in two adjacent rows of the
+    table's presence bits, packed 32 codes a word and 128 words a row, so a
+    block fetches two whole rows (a gather of rows, 2.3 ms for 131,072 blocks
+    on one v5e where the 16.7M single elements take 145, PR 34) and every
+    probe row picks its bit out of them. Where any block's live codes span
+    more (an unsorted probe: `orders` by `o_custkey`), the whole lane takes
+    the element gather. Which, the program sees in the data, as
+    :func:`_slot_partial` does. Dead rows (padding and NULL keys hold code 0)
+    count in no span. Returns (match, slot, blocked): ``slot`` is each row's
+    build row, -1 without, gathered only where something reads it."""
+    n = lkey.shape[0]
+    B = min(PROBE_BLOCK, n)
+    nb = -(-n // B)
+    k = jnp.pad(lkey, (0, nb * B - n)).reshape(nb, B)
+    l = jnp.pad(live, (0, nb * B - n)).reshape(nb, B)
+    lo = jnp.where(l, k, jnp.iinfo(k.dtype).max).min(axis=1)
+    hi = jnp.where(l, k, -1).max(axis=1)  # -1: a block with no live row
+    r = jnp.where(hi >= 0, lo // _ROW_CODES, 0)  # the bitmap row of each block's least live code
+    fits = jnp.all(hi // _ROW_CODES - r <= 1)
+
+    def gather():
+        slot = _table_rows(jnp, table, lkey, live)
+        return slot >= 0, slot
+
+    def blocked():
+        nr = -(-table.shape[0] // _ROW_CODES) + 1  # one row more: every block has a second
+        bits = jnp.pad(table >= 0, (0, nr * _ROW_CODES - table.shape[0])).reshape(nr * 128, 32)
+        rows = (bits.astype(jnp.uint32) << jnp.arange(32, dtype=jnp.uint32)).sum(axis=1, dtype=jnp.uint32).reshape(nr, 128)
+        win = jnp.concatenate([rows[r], rows[r + 1]], axis=1)  # [nb, 256]
+        off = k - (r * _ROW_CODES)[:, None]  # a live row's: in [0, 2 rows of codes)
+        word = jnp.where((off >> 5)[:, :, None] == jnp.arange(256, dtype=off.dtype), win[:, None, :], jnp.uint32(0)).sum(axis=2, dtype=jnp.uint32)
+        match = (l & (((word >> (off & 31).astype(jnp.uint32)) & 1) == 1)).reshape(-1)[:n]
+        return match, _table_rows(jnp, table, lkey, match)
+
+    match, slot = jax.lax.cond(fits, blocked, gather)
+    return match, slot, fits
+
+
+def probe_paths(probed) -> str:
+    """"blocked,gather": how each join's direct-address lookup answered its
+    probe rows, from the program's [probe rows, of them by blocks] a join
+    (shards summed: "mixed" where they differ; "-" where no such lookup ran)."""
+    return ",".join("-" if not n else "blocked" if b == n else "mixed" if b else "gather" for n, b in probed)
+
+
 def _slot_partial(jax, jnp, slot, mask, vals, cap):
-    """Grouped partial sums where a group IS a build slot (``slot``: each
-    probe row's build row from :func:`_direct_lookup`). Probe rows whose live
-    slots never step back (a fact table stored in its dimension's key order:
-    `lineitem` by `l_orderkey`) are reduced in place, as runs; anything else
-    is sorted by slot first, the value lanes riding the sort. Which, the
-    program sees in the data (one running maximum). Returns (slot, first
-    probe row, sums, counts, overflow) of the first ``cap`` groups; a group
-    holds rows iff its count > 0, and ``overflow`` counts groups past
-    ``cap`` as :func:`_segment_partial` does."""
+    """Grouped partial sums where a group IS a build slot. ``slot`` names
+    each probe row's, -1 for a row of none: the join key's code as
+    :func:`_fold_join` hands it (``code``; one code, one build row), or the
+    build row itself. Probe rows whose live slots never step back (a fact
+    table stored in its dimension's key order: `lineitem` by `l_orderkey`)
+    are reduced in place, as runs; anything else is sorted by slot first, the
+    value lanes riding the sort. Which, the program sees in the data (one
+    running maximum). Returns (slot, first probe row, sums, counts, overflow)
+    of the first ``cap`` groups; a group holds rows iff its count > 0, and
+    ``overflow`` counts groups past ``cap`` as :func:`_segment_partial` does."""
     n = slot.shape[0]
     live = mask & (slot >= 0)
     s = jnp.where(live, slot, -1)
@@ -714,10 +784,12 @@ def _fold_join(xc, jnp, join, acc, mask, rcols, rvalid, pf, slot_out=None, opera
     moved) deltas accumulated into the caller's counters; ``moved`` =
     [bytes, rows] of the valid rows this fold handed to an exchange (the
     lanes' own widths; what the buffers hold, :func:`compiled_exchange_bytes`).
-    ``slot_out``: a dict that receives ``slot`` (each probe row's build row)
-    and ``build`` (the build lanes it indexes) where the direct-address
-    lookup ran, and under a ``local`` exchange ``order`` (each matched probe
-    row's key code, -1 else). ``operands``: the program's, for a ``local`` join's ranges."""
+    ``slot_out``: a dict that receives, where the direct-address lookup ran,
+    ``table`` (each key code's build row), ``code`` (each matched probe row's
+    key code, -1 else: a probe row carries its match and its group, never its
+    build row), ``build`` (the build lanes ``table`` indexes) and ``probe``
+    ([probe rows, of them answered by blocks], :func:`_probe_match`).
+    ``operands``: the program's, for a ``local`` join's ranges."""
     jax, ndev = xc.jax, xc.ndev
     dropped = jnp.int64(0)
     overflow = jnp.int64(0)
@@ -874,17 +946,16 @@ def _fold_join(xc, jnp, join, acc, mask, rcols, rvalid, pf, slot_out=None, opera
         if ncodes is not None and ncodes <= DIRECT_DOMAIN_MAX:
             # bounded keys, a domain that fits: no sort (the bounds decide)
             with jax.named_scope("mpp.build"):
-                slot = _direct_lookup(jnp, lkey, probe_live, rkey, rvalid, ncodes)
-            match = slot >= 0
-            at = jnp.maximum(slot, 0)
-            gathered = [rc[at] for rc in rcols]  # lanes nothing reads are never gathered
+                table = _direct_table(jnp, rkey, rvalid, ncodes)
+                match, slot, blocked = _probe_match(jax, jnp, table, lkey, probe_live)
+            gathered = [rc[jnp.maximum(slot, 0)] for rc in rcols]  # lanes nothing reads are never gathered, nor is ``slot`` then
             if slot_out is not None:
-                slot_out.update(slot=slot, build=rcols)
-                if join.exchange == "local" and ndev > 1:
-                    # slivers sit behind a shard's own build rows, so the slots of
-                    # probe rows in key order step back where the keys do not:
-                    # the key's code says which rows are one run
-                    slot_out["order"] = jnp.where(match, lkey, -1).astype(jnp.int32)
+                # the key's code says which rows are one group's run, on one
+                # shard as on four (slivers sit behind a shard's own build rows,
+                # so build rows step back where the keys do not)
+                n = lkey.shape[0]
+                slot_out.update(table=table, code=jnp.where(match, lkey, -1).astype(jnp.int32), build=rcols,
+                                probe=jnp.stack([n, n * blocked]).astype(jnp.int64))
         else:
             gathered, match = _local_unique_join(
                 jax, jnp, lkey, lkeys, probe_live, rkey, rkeys, rcols, rvalid, dead_b, dead_p
@@ -1072,7 +1143,9 @@ def build_dist_pipeline(
     collective lies under ``mpp.exchange`` (:class:`_Exchange`).
 
     ``count_rows``: the program emits one more replicated output before the
-    warn count: the valid rows its exchanges carried, all shards summed."""
+    warn count, all shards summed: the valid rows its exchanges carried, then
+    join by join [probe rows, of them answered by blocks] (:func:`_probe_match`;
+    0, 0 for a join that took no direct-address lookup)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
@@ -1135,6 +1208,7 @@ def build_dist_pipeline(
         # table that already says which orders pass. The lanes it gathers land
         # where the chain would have put them, so the accumulated layout, and
         # every filter placed in it, is as written.
+        probes = [jnp.zeros(2, jnp.int64)] * len(joins)  # per join: [probe rows, of them answered by blocks] where a direct-address lookup ran
         lane_off = [len(acc)]  # where join ji's build lanes start in the accumulated layout
         for ji, join in enumerate(joins):
             lane_off.append(lane_off[-1] + (len(builds[ji][0]) if join.kind in ("inner", "left", "right") else 0))
@@ -1149,25 +1223,28 @@ def build_dist_pipeline(
                 left_key_valid=tuple(k - lo for k in join.left_key_valid),
                 left_row_cap=before.right_row_cap,
             )
+            took = {}
             with jax.named_scope("mpp.build"):
-                bl, bv, d, of, mv = _fold_join(xc, jnp, arm, *builds[ji - 1], *builds[ji], None, None, operands)
+                bl, bv, d, of, mv = _fold_join(xc, jnp, arm, *builds[ji - 1], *builds[ji], None, took, operands)
             builds[ji - 1] = (bl, bv)
+            probes[ji] = took.get("probe", probes[ji])
             dropped, overflow, moved = dropped + d, overflow + of, moved + mv
-        slot = None  # by-slot aggregate: (each row's build slot, that build's lanes, where they start)
+        slot = None  # by-slot aggregate: (the join's table, each matched row's key code, that build's lanes, where they start)
         for ji, join in enumerate(joins):
             if join.arm:
                 mask = _apply_chain(ji + 1, acc, mask)
                 continue
             pf = pair_filters[ji] if pair_filters is not None else None
-            took = {} if agg is not None and agg.slot_join == ji else None
+            took = {}
             if slot is not None and not keeps_rows(join.kind, join.unique, join.exchange, ndev):
                 slot = None  # this fold moves or multiplies the probe rows
             n_before = len(acc)
             with jax.named_scope("mpp.probe"):
                 acc, mask, d, of, mv = _fold_join(xc, jnp, join, acc, mask, *builds[ji], pf, took, operands)
             dropped, overflow, moved = dropped + d, overflow + of, moved + mv
-            if took:
-                slot = (took["slot"], took["build"], n_before, took.get("order"))
+            probes[ji] = took.get("probe", probes[ji])
+            if "table" in took and agg is not None and agg.slot_join == ji:
+                slot = (took["table"], took["code"], took["build"], n_before)
             mask = _apply_chain(ji + 1, acc, mask)
         if agg is not None:
             with jax.named_scope("mpp.agg"):
@@ -1185,7 +1262,7 @@ def build_dist_pipeline(
             # one replicated vector (staged-reader order)
             outs = (*outs, xc.psum("groups", jnp.stack(stage_xb)))
         if count_rows:
-            outs = (*outs, xc.psum("groups", moved[1] + sent[1]))
+            outs = (*outs, xc.psum("groups", jnp.concatenate([(moved[1] + sent[1])[None], *probes])))
         if warn_sink is not None:
             # device warnings born inside the fragment (division by 0 in a
             # selection/agg argument) ride ONE replicated count output —
@@ -1238,14 +1315,15 @@ def build_dist_pipeline(
         G, D = agg.n_keys, agg.n_dkeys
         vals = [acols[i] for i in agg.sums]
         if slot is not None and not D and all(k == "sum" for k in agg.val_kinds):
-            # a group IS a build slot of the direct-address join: sums by
-            # slot, and the group's key lanes read where the group is known —
-            # build lanes at its slot, probe lanes at its first row — so no
-            # build lane is ever gathered out to the probe's row count
-            idx, build, at, order = slot
-            gslot, grow, psums, pcnt, of1 = _slot_partial(jax, jnp, idx if order is None else order, mask, vals, cap)
-            if order is not None:
-                gslot = jnp.maximum(idx[grow], 0)  # the runs were told by key code: a group's build row is its first probe row's
+            # a group IS a build slot of the direct-address join: sums by the
+            # key's code, and the group's key lanes read where the group is
+            # known — build lanes at its build row (the table at its code: a
+            # gather of ``cap`` elements), probe lanes at its first row — so no
+            # build lane, and no build row, is ever gathered out to the probe's
+            # row count
+            table, code, build, at = slot
+            gcode, grow, psums, pcnt, of1 = _slot_partial(jax, jnp, code, mask, vals, cap)
+            gslot = jnp.maximum(table[gcode], 0)
             head = [a[grow] for a in joined[:at]] + [b[gslot] for b in build]
             head += [a[grow] for a in joined[at + len(build) :]]
             pkeys = [jnp.where(pcnt > 0, k, 0) for k in agg_inputs(head)[:G]]

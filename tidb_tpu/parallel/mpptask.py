@@ -384,6 +384,7 @@ class MPPTaskManager:
                     "exchange": det.exchange if det is not None else "",
                     "xchg_bytes": det.xchg_bytes if det is not None else {},
                     "xchg_rows": det.xchg_rows if det is not None else 0,
+                    "probe": det.probe if det is not None else "",
                 }
             except Exception as e:  # travels the wire as (kind, message)
                 task["kind"] = type(e).__name__

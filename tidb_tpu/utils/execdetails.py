@@ -315,10 +315,10 @@ class MPPExecDetails:
     WHICH device inside the collective was slow."""
 
     __slots__ = ("n_fragments", "ndev", "wall_ms", "rows", "retries", "store", "shards", "compiles",
-                 "stages", "stage_bytes", "exchange", "xchg_bytes", "xchg_rows")
+                 "stages", "stage_bytes", "exchange", "xchg_bytes", "xchg_rows", "probe")
 
     def __init__(self, n_fragments=0, ndev=0, wall_ms=0.0, rows=0, retries=0, store="", shards=None,
-                 compiles=0, stages=1, stage_bytes=None, exchange="", xchg_bytes=None, xchg_rows=0):
+                 compiles=0, stages=1, stage_bytes=None, exchange="", xchg_bytes=None, xchg_rows=0, probe=""):
         self.n_fragments = n_fragments
         self.ndev = ndev
         self.wall_ms = wall_ms
@@ -341,6 +341,10 @@ class MPPExecDetails:
         self.exchange = exchange
         self.xchg_bytes = dict(xchg_bytes or {})
         self.xchg_rows = xchg_rows
+        # how each join's direct-address lookup answered its probe rows, join
+        # by join: "blocked" (a bitmap window a block of rows in key order),
+        # "gather" (an element a row), "mixed" (shards differ), "-" (no such lookup)
+        self.probe = probe
 
     def shard_summary(self) -> "tuple | None":
         """(max_ms, min_ms, p95_ms, slowest_shard_id) or None."""
@@ -368,6 +372,8 @@ class MPPExecDetails:
             parts.append("xchg_bytes: " + str(sum(self.xchg_bytes.values())) + " ("
                          + ", ".join(f"{k} {v}" for k, v in sorted(self.xchg_bytes.items()) if v) + ")")
             parts.append(f"xchg_rows: {self.xchg_rows}")
+        if self.probe.strip("-,"):
+            parts.append(f"probe: {self.probe}")
         ss = self.shard_summary()
         if ss is not None:
             mx, mn, p95, slowest = ss

@@ -340,6 +340,15 @@ MPP_EXCHANGE_BYTES = REGISTRY.counter(
     "Bytes the MPP fragment programs' collectives moved between chips, by exchange kind",
     ("kind",),
 )
+# probe rows of the fragment programs' direct-address lookups, by how the
+# program answered "did the row match" (parallel/mpp._probe_match, which sees
+# it in the data): blocked = a window of the table's presence bitmap a block
+# of rows in key order, gather = an element of the table a row
+MPP_PROBE_ROWS = REGISTRY.counter(
+    "tidb_tpu_mpp_probe_rows_total",
+    "Padded probe rows of MPP direct-address lookups, by how they were answered",
+    ("how",),
+)
 # cross-store × cross-chip hybrid gathers: a straddling gather (tables on
 # multiple store shards) ran on the coordinator's mesh with per-owner wire
 # reads instead of degrading to the host join
