@@ -16,6 +16,10 @@ re-runs its region alone; sidecar, spans and counter agree on the calls."""
 
 import dataclasses
 import glob
+import json
+import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -478,3 +482,106 @@ def test_host_engine_takes_the_old_path(served):
         s.execute("SET tidb_isolation_read_engines = 'tpu'")
     assert summary.num == summary.regions >= 4 and summary.engines == {"host": summary.num}
     assert _counts()[0] == batched
+
+
+# -- the big task (ISSUE 35): more regions of one shape than one call holds -----------
+
+
+@pytest.mark.parametrize("k,n_pad,counts", [
+    (240, 262144, [64, 64, 64, 48]),  # tpch_sf10's lineitem: three calls at the ladder's top and a smaller rung
+    (241, 262144, [64, 64, 64, 56]),
+    (65, 262144, [64, 1]),  # a rest of one takes the single-region program
+    (66, 262144, [64, 8]), (67, 262144, [64, 8]), (71, 262144, [64, 8]), (72, 262144, [64, 8]), (73, 262144, [64, 16]),
+    (46, 262144, [48]), (64, 262144, [64]), (1, 262144, [1]), (2, 262144, [8]),
+    (5, 524288, [8]), (33, 524288, [32, 1]),  # 2^24 padded rows a call: 32 regions of 524,288
+    (3, 1 << 22, [1, 1, 1]),  # too large for a step of 8 to fit
+])
+def test_map_counts_by_value(k, n_pad, counts):
+    assert tpu_engine._map_counts(k, n_pad) == counts
+    assert 0 <= sum(counts) - k < tpu_engine._MAP_STEP  # every region has a slot, and padding is a rest's
+
+
+@pytest.fixture(scope="module")
+def many():
+    """More than 64 clean regions of one padded shape."""
+    db, s = _mk_db(rows=34_600, split=1000)
+    for text in SHAPES.values():
+        s.query(text)
+    return db, s
+
+
+def _dispatches(s, text, tmp_path):
+    """(rows, the summary, the stats of the statement's ``tidb:exec.dispatch`` spans)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        rows, summary = _summary(s, text)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(f"{tmp_path}/plugins/profile/*/*.xplane.pb")
+    name = tracing.PREFIX + "exec.dispatch"
+    return rows, summary, [dict(ev.stats) for plane in ProfileData.from_file(path).planes for line in plane.lines
+                           for ev in line.events if ev.name == name]
+
+
+@pytest.mark.parametrize("shape", sorted(SHAPES))
+def test_more_regions_than_a_call_holds_are_still_one_task(many, shape, monkeypatch, tmp_path):
+    db, s = many
+    cache = tpu_engine.cache_for(db.store)
+    tid = db.catalog.table("test", "t").id
+    by_shape: dict = {}
+    for r in db.store.regions():
+        entry = cache.head(r, tid, db.store.current_ts())
+        if entry is not None:
+            by_shape[tpu_engine.bucket_size(entry.n)] = by_shape.get(tpu_engine.bucket_size(entry.n), 0) + 1
+    n_pad = max(by_shape, key=by_shape.get)
+    assert by_shape[n_pad] > 64, by_shape
+    monkeypatch.setattr(tpu_engine, "_MAP_ROWS", 64 * n_pad)  # the ladder's top: 64 regions a call, as 2^24 rows are 64 of 262,144
+    want = [c for n_pad, k in sorted(by_shape.items()) for c in tpu_engine._map_counts(k, n_pad)]
+    assert want.count(64) == 1 and len(want) >= 2
+    rows, summary, (dispatch,) = _dispatches(s, SHAPES[shape], tmp_path)
+    assert rows == _host(s, SHAPES[shape])
+    assert summary.num == 1 and summary.regions == sum(by_shape.values()) > 64  # ONE task, every region in it
+    assert summary.programs == int(dispatch["regions"]) == len(want)
+    assert int(dispatch["pad_slots"]) == sum(want) - summary.regions > 0  # a rest of 2-7 is padded to 8
+    assert {m for pad, _, m in _mapped_keys() if pad == n_pad} >= set(c for c in want if c > 1)  # a program a rung
+
+
+def test_rehearsal_of_the_sf10_cell_is_correct_and_prints_its_metrics(tmp_path):
+    """`tpch_sf10.q1q6_1c` as the driver runs it, on the CPU at SF 0.02."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    cmd = [sys.executable, os.path.join(root, "benchmark", "run.py"), "--workload", "tpch_sf10.q1q6_1c", "--seed", "2147483951",
+           "--seconds", "3", "--trace", "1", "--platform", "cpu", "--scale", "0.02"]
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=1", JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
+    p = subprocess.run(cmd, capture_output=True, text=True, timeout=600, cwd=root, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["attempted"] >= 4 and line["rehearsal"] is True, line
+    assert {k: c["value"] for k, c in line["checks"].items()} == {"answers_wrong": 0, "statements_failed": 0, "not_on_device": 0}
+    m = line["metrics"]
+    for name in ("frontend_ms", "cop_host_ms", "h2d_bytes_per_stmt", "colcache_merges", "cop_regions_per_task", "cop_programs_per_task",
+                 "exec_bind_ms", "exec_dispatch_ms", "load_rows_per_s"):
+        assert name in m, sorted(m)
+    assert m["load_rows_per_s"]["unit"] == "rows/s" and m["load_rows_per_s"]["value"] > 10_000  # 1.53M rows: no rate is claimed here
+    assert m["compiles_in_window"]["value"] == 0 and m["cop_regions_per_task"]["value"] >= 1
+    assert "scan_roofline" not in m  # no chip, no share
+    assert "generated {'customer': 3000, 'orders': 30000, 'lineitem': " in p.stderr
+
+
+def test_a_program_that_loads_row_at_a_time_is_refused_the_sf10_cell_at_once():
+    """The cell's generator ends the run of a program without the array loader
+    (every commit before PR 35) before a table is made, exit code 4: such a
+    run cannot end inside the check's limit, and one stopped there refuses a PR."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import runpy, sys, tidb_tpu.utils.chunk as chunk; del chunk.Dictionary.encode_many; "
+            "sys.argv = ['benchmark/run.py', '--workload', 'tpch_sf10.q1q6_1c', '--seed', '2147483951', '--seconds', '3', '--platform', 'cpu', '--scale', '0.02']; "
+            "runpy.run_path('benchmark/run.py', run_name='__main__')")
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=300, cwd=root,
+                       env=dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=1"))
+    assert p.returncode == 4, p.stderr[-3000:]
+    assert "loads row at a time" in p.stderr and "generated" not in p.stderr and p.stdout.strip() == ""

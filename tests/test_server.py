@@ -234,3 +234,35 @@ def test_warning_count_on_the_wire(srv):
         assert c.warning_count == 0
     finally:
         c.close()
+
+
+def test_a_closed_server_lets_go_of_its_database():
+    """``close()`` has to wake the thread inside ``accept()``: left blocked, it
+    holds the server, the DB and the whole store for as long as the process
+    lives (ISSUE 35: at SF10 ~13 GB that the benchmark's reference then works
+    beside)."""
+    import gc
+    import threading
+    import time
+    import weakref
+
+    db = tidb_tpu.open()
+    db.execute("CREATE TABLE held (id BIGINT PRIMARY KEY, v BIGINT)")
+    db.execute("INSERT INTO held VALUES (1, 10), (2, 20)")
+    server = Server(db)
+    port = server.start()
+    c = Client(port=port, db="test")
+    assert c.query("SELECT SUM(v) FROM held") == [("30",)]
+    accept = server._accept_thread
+    alive = weakref.ref(db.store)
+    c.close()
+    server.close()
+    accept.join(timeout=5)
+    assert not accept.is_alive()
+    del db, server, c
+    for _ in range(50):  # the connection's thread ends on its closed socket
+        gc.collect()
+        if alive() is None:
+            break
+        time.sleep(0.1)
+    assert alive() is None, [t.name for t in threading.enumerate()]

@@ -819,7 +819,7 @@ def _run_all(ph, calls: list) -> list:
     and transfer only the live slice (_probe_slice_rows)."""
     import jax
 
-    ph.to("dispatch", kernel=calls[0][0].family, regions=len(calls))
+    ph.to("dispatch", kernel=calls[0][0].family, regions=len(calls), pad_slots=sum(kernel.m - len(live) for kernel, _, live in calls if kernel.m > 1))
     packed = [kernel.fn(*args) for kernel, args, _ in calls]
     _count_programs(calls)
     ph.to("fetch")

@@ -8,6 +8,7 @@ SQL surface.
 
 from __future__ import annotations
 
+import time
 from typing import Sequence
 
 import numpy as np
@@ -17,6 +18,8 @@ from tidb_tpu.kv import tablecodec
 from tidb_tpu.kv.rowcodec import RowSchema, encode_row
 from tidb_tpu.session.session import DB
 from tidb_tpu.types import TypeKind
+from tidb_tpu.utils import metrics as _metrics
+from tidb_tpu.utils import tracing as _tracing
 
 
 def bulk_load(db: DB, table_name: str, columns: Sequence[Sequence], db_name: str = "test", batch: int = 200_000, handle_base: int | None = None, on_existing: str | None = None) -> int:
@@ -132,6 +135,7 @@ def _ingest_columnar(db: DB, physical_id: int, t, phys_cols, handles: np.ndarray
     cols: dict[int, tuple[np.ndarray, np.ndarray]] = {}
     dicts: dict = {}
     string_slots: list[int] = []
+    no_null = np.ones(n, dtype=bool)  # one for every column without a NULL: a block's lanes are never written to
     for pos, (c, vals) in enumerate(zip(t.columns, phys_cols)):
         k = c.ftype.kind
         if k in (TypeKind.STRING, TypeKind.JSON):
@@ -139,7 +143,7 @@ def _ingest_columnar(db: DB, physical_id: int, t, phys_cols, handles: np.ndarray
             dicts[pos] = cache.dictionary(t.id, pos)  # before ingest_lock
         elif isinstance(vals, np.ndarray):
             dt = np.float64 if k == TypeKind.FLOAT else np.int64
-            cols[pos] = (vals.astype(dt, copy=False), np.ones(n, dtype=bool))
+            cols[pos] = (vals.astype(dt, copy=False), no_null)
         else:
             valid = np.fromiter((v is not None for v in vals), dtype=bool, count=n)
             dt = np.float64 if k == TypeKind.FLOAT else np.int64
@@ -150,11 +154,13 @@ def _ingest_columnar(db: DB, physical_id: int, t, phys_cols, handles: np.ndarray
     # encode string codes and append the block under one cache lock: a
     # concurrent ensure_sorted_dict compaction between encode and ingest
     # would remap every block EXCEPT this not-yet-visible one
-    with cache.ingest_lock():
+    with cache.ingest_lock(), _tracing.region("load.ingest", table=t.name, rows=n, strings=len(string_slots)) as span:
+        t0 = time.perf_counter()
+        known = sum(len(d) for d in dicts.values())
         for pos in string_slots:
             raw = phys_cols[pos]
             if isinstance(raw, np.ndarray) and raw.dtype.kind == "S":
-                valid = np.ones(n, dtype=bool)
+                valid = no_null
                 safe = raw
             else:
                 arr = np.asarray(raw, dtype=object)
@@ -162,14 +168,19 @@ def _ingest_columnar(db: DB, physical_id: int, t, phys_cols, handles: np.ndarray
                 safe = np.where(valid, arr, b"") if n else arr
             dic = dicts[pos]
             if n:
-                uniq, inv = np.unique(safe, return_inverse=True)
-                code_of = np.fromiter((dic.encode(bytes(u)) for u in uniq), dtype=np.int32, count=len(uniq))
-                data = code_of[inv.reshape(-1)].astype(np.int32, copy=False)
-                data = np.where(valid, data, 0).astype(np.int32, copy=False)
+                data = np.where(valid, dic.encode_many(safe), 0).astype(np.int32, copy=False)
             else:
                 data = np.empty(0, np.int32)
             cols[pos] = (data, valid)
+        t1 = time.perf_counter()
         db.store.ingest_columnar(physical_id, handles, cols, schema, dicts, on_existing=on_existing)
+        t2 = time.perf_counter()
+        if span is not None:
+            regions = db.store.pd.regions_in_ranges([tablecodec.handle_range(physical_id, int(handles.min()), int(handles.max()))]) if n else []
+            span.note(dict_new=sum(len(d) for d in dicts.values()) - known, regions=len(regions))
+    _metrics.BULK_LOAD_ROWS.inc(n, table=t.name)
+    _metrics.BULK_LOAD_SECONDS.inc(t1 - t0, phase="encode")
+    _metrics.BULK_LOAD_SECONDS.inc(t2 - t1, phase="ingest")
 
 
 def _bulk_load_partitioned(db: DB, t, phys_cols, n: int, schema: RowSchema, handle_base: int | None = None, on_existing: str | None = None) -> int:

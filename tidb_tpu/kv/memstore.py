@@ -24,6 +24,7 @@ import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
+from itertools import repeat
 from typing import Iterator, Optional, Sequence
 
 import numpy as np
@@ -85,6 +86,23 @@ class _ChangeLog:
             self.lost_max_ts = ts
             return
         self.items.append((ts, handle, op))
+
+    def note_many(self, ts: int, handles: np.ndarray, op: str) -> None:
+        """``note`` for every handle of an array, in its order."""
+        if not len(handles):
+            return
+        lo, hi = int(handles.min()), int(handles.max())
+        self.lo = lo if self.lo is None else min(self.lo, lo)
+        self.hi = hi if self.hi is None else max(self.hi, hi)
+        if self.lost:
+            self.lost_max_ts = max(self.lost_max_ts, ts)
+        elif len(self.items) + len(handles) > _CHANGE_ITEMS_CAP:
+            # the handle that finds the log full clears it, as ``note`` does
+            self.items.clear()
+            self.lost = True
+            self.lost_max_ts = ts
+        else:
+            self.items.extend(zip(repeat(ts), handles.tolist(), repeat(op)))
 
     def note_span(self, ts: int, lo: int, hi: int) -> None:
         """Bulk change too large to itemize: watermark only."""
@@ -292,11 +310,14 @@ class StableBlock:
     device. ``schema`` lets point reads re-encode a row on demand.
     """
 
-    __slots__ = ("table_id", "handles", "cols", "schema", "dicts", "commit_ts")
+    __slots__ = ("table_id", "handles", "lo", "hi", "cols", "schema", "dicts", "commit_ts")
 
     def __init__(self, table_id: int, handles: np.ndarray, cols: dict, schema, dicts: dict, commit_ts: int):
         self.table_id = table_id
-        self.handles = handles  # ascending int64
+        self.handles = handles  # ascending int64, never empty
+        # its first and last handle: a table is thousands of blocks, and who
+        # asks for a handle range passes over most of them without a search
+        self.lo, self.hi = int(handles[0]), int(handles[-1])
         self.cols = cols
         self.schema = schema
         self.dicts = dicts
@@ -883,8 +904,7 @@ class MemStore:
             if hi - lo > _CHANGE_ITEMS_CAP:
                 log.note_span(ts, int(handles[lo]), int(handles[hi - 1]))
             else:
-                for h in handles[lo:hi]:
-                    log.note(ts, int(h), OP_PUT)
+                log.note_many(ts, handles[lo:hi], OP_PUT)
 
     def col_changes_since(self, region_id: int, table_id: int, after_ts: int):
         """Changes with commit_ts > after_ts for one (region, table):
@@ -1037,7 +1057,10 @@ class MemStore:
             if hlo >= hhi:
                 continue
             for b in blocks:
-                n += int(np.searchsorted(b.handles, hhi)) - int(np.searchsorted(b.handles, hlo))
+                if hlo <= b.lo and b.hi < hhi:
+                    n += len(b.handles)
+                elif b.lo < hhi and b.hi >= hlo:
+                    n += int(np.searchsorted(b.handles, hhi)) - int(np.searchsorted(b.handles, hlo))
         r.key_count = n
 
     def _stable_handles_in(self, r: Region) -> tuple[int | None, np.ndarray | None]:
@@ -1050,6 +1073,8 @@ class MemStore:
                 continue
             parts = []
             for b in blocks:
+                if b.hi < hlo or b.lo >= hhi:
+                    continue
                 lo = int(np.searchsorted(b.handles, hlo))
                 hi = int(np.searchsorted(b.handles, hhi))
                 if lo < hi:
@@ -1402,7 +1427,7 @@ class MemStore:
         present = np.zeros(len(handles), dtype=bool)
         lo, hi = int(handles[0]), int(handles[-1])
         for b in self._stable.get(table_id, ()):
-            if not len(b.handles) or int(b.handles[-1]) < lo or int(b.handles[0]) > hi:
+            if b.hi < lo or b.lo > hi:
                 continue
             i = np.searchsorted(b.handles, handles)
             i = np.minimum(i, len(b.handles) - 1)
@@ -1432,7 +1457,7 @@ class MemStore:
         out = []
         with self._mu:
             for block in self._stable.get(table_id, ()):
-                if block.commit_ts > read_ts:
+                if block.commit_ts > read_ts or block.hi < hlo or block.lo >= hhi:
                     continue
                 lo = int(np.searchsorted(block.handles, hlo, side="left"))
                 hi = int(np.searchsorted(block.handles, hhi, side="left"))

@@ -552,6 +552,12 @@ class Server:
             self.close_registration()
         if self._lsock is not None:
             try:
+                # close() alone leaves a thread inside accept() blocked for good,
+                # and that thread holds the server, its DB and the store
+                self._lsock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
+            try:
                 self._lsock.close()
             except OSError:
                 pass

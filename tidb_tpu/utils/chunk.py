@@ -18,8 +18,10 @@ wire codec: codec.go:42/101). Redesigned for TPU:
 
 from __future__ import annotations
 
+import operator
 import struct
 from dataclasses import dataclass, field
+from itertools import filterfalse, repeat
 from typing import Iterable, Sequence
 
 import numpy as np
@@ -40,6 +42,23 @@ from tidb_tpu.types.datum import (
 # ---------------------------------------------------------------------------
 
 
+# an index of more keys than this is searched with the batch's hashes in order:
+# a binary search a row misses the cache at every step once the keys outgrow it,
+# and the sort of 20,000 hashes costs less than the misses (4.2 -> 2.4 ms a batch
+# of a 65,536-string comment pool; below it the sort is the dearer of the two)
+_SEARCH_IN_ORDER = 4096
+
+
+def _hash_rows(a: np.ndarray) -> np.ndarray:
+    """One uint64 a row of a fixed-width bytes array: its 8-byte words, NUL
+    padded, each times an odd constant of its place, summed. A value hashes
+    the same whatever the width of the array that holds it."""
+    n, nw = len(a), -(-a.dtype.itemsize // 8)
+    words = a.astype(f"S{nw * 8}").view(np.uint64).reshape(n, nw)
+    mult = np.arange(1, nw + 1, dtype=np.uint64) * np.uint64(0x9E3779B97F4A7C15) | np.uint64(1)
+    return words[:, 0] * mult[0] if nw == 1 else words.dot(mult)
+
+
 class Dictionary:
     """Append-only bytes→code dictionary.
 
@@ -48,7 +67,7 @@ class Dictionary:
     string comparisons; appends after compaction clear ``sorted`` again.
     """
 
-    __slots__ = ("_values", "_index", "sorted", "ci_sorted", "_mu")
+    __slots__ = ("_values", "_index", "sorted", "ci_sorted", "_mu", "_np", "_np_rows")
 
     def __init__(self, values: Sequence[bytes] = ()):  # noqa: D107
         import threading
@@ -63,6 +82,11 @@ class Dictionary:
         # encode() appends; concurrent cop/partition worker threads share
         # table-level dictionaries, so the mutation is locked
         self._mu = threading.Lock()
+        # encode_many's index over ``_values[:n]`` in arrays, built on its
+        # first use: (the list it was built from, n, hashes in order, their
+        # codes, the values by code); the rows looked up since it was built
+        self._np: tuple | None = None
+        self._np_rows = 0
 
     def __len__(self) -> int:
         return len(self._values)
@@ -87,6 +111,99 @@ class Dictionary:
                 if code > 0:
                     self.ci_sorted = False
         return code
+
+    def encode_many(self, values: np.ndarray) -> np.ndarray:
+        """int32 codes of a batch's values (an array of fixed-width ``S`` or
+        of bytes objects), leaving the state that ``encode`` leaves when it is
+        called on the batch's distinct values in sorted order: the ones not
+        known yet are appended in that order. No Python object a row for a
+        fixed-width array: its rows find their codes through ``_np``, and
+        only the rows unknown there are sorted and looked up in ``_index``."""
+        n = len(values)
+        if n and values.dtype.kind == "S":
+            self._np_rows += n
+            snap = self._np
+            # re-index the values appended since, once the rows looked up
+            # since the last time pay for a pass over the index
+            if snap is None or snap[0] is not self._values or (len(self._values) > snap[1] and self._np_rows * 4 >= snap[1]):
+                with self._mu:
+                    self._reindex()
+            codes = self._lookup(values)
+        else:
+            codes = np.full(n, -1, dtype=np.int32)
+        miss = np.flatnonzero(codes < 0)
+        if len(miss):
+            uniq, inv = np.unique(values if len(miss) == n else values[miss], return_inverse=True)
+            codes[miss] = self._encode_distinct(uniq)[inv.reshape(-1)]
+        return codes
+
+    def _encode_distinct(self, uniq: np.ndarray) -> np.ndarray:
+        """Codes of distinct values through ``_index``, one hash lookup each
+        at C speed; unknown ones are appended in the order given, under the
+        lock, with ``encode``'s effect on the flags."""
+        vals = uniq.tolist()  # an S array hands out plain bytes, NUL padding stripped
+        codes = np.fromiter(map(self._index.get, vals, repeat(-1)), dtype=np.int32, count=len(vals))
+        miss = np.flatnonzero(codes < 0)
+        if len(miss):
+            with self._mu:
+                index, known = self._index, self._values
+                wanted = list(map(bytes, uniq[miss].tolist()))
+                base = len(known)
+                # a racing encoder may have added some since the lookup
+                fresh = list(filterfalse(index.__contains__, dict.fromkeys(wanted)))
+                known.extend(fresh)
+                index.update(zip(fresh, range(base, base + len(fresh))))
+                if self.sorted:
+                    seq = known[max(base - 1, 0):]
+                    self.sorted = not any(map(operator.gt, seq, seq[1:]))
+                if len(known) > 1 and fresh:
+                    self.ci_sorted = False
+                codes[miss] = np.fromiter(map(index.__getitem__, wanted), dtype=np.int32, count=len(wanted))
+        return codes
+
+    def _reindex(self) -> None:
+        """Bring ``_np`` up to ``_values`` (caller holds ``_mu``): the values'
+        hashes in order with their codes, and the values by code as one
+        fixed-width array, which makes a hit exact. Readers take the tuple
+        whole, so it is replaced, never changed."""
+        known = self._values
+        snap = self._np
+        if snap is None or snap[0] is not known:  # first use, or compact() re-coded everything
+            snap = (known, 0, np.empty(0, np.uint64), np.empty(0, np.int32), np.empty(0, "S1"))
+        _, n0, keys, codes, vals = snap
+        tail = known[n0:]
+        if tail:
+            arr = np.array(tail, dtype="S")
+            # a value that ends in NUL has no fixed-width form: no row of an S array is it
+            exact = np.char.str_len(arr) == np.fromiter(map(len, tail), dtype=np.int64, count=len(tail))
+            tk = _hash_rows(arr)[exact]
+            tc = np.arange(n0, n0 + len(tail), dtype=np.int32)[exact]
+            order = np.argsort(tk, kind="stable")
+            at = np.searchsorted(keys, tk[order])
+            keys = np.insert(keys, at, tk[order])
+            codes = np.insert(codes, at, tc[order])
+            vals = np.concatenate([vals, arr])
+        self._np = (known, n0 + len(tail), keys, codes, vals)
+        self._np_rows = 0
+
+    def _lookup(self, values: np.ndarray) -> np.ndarray:
+        """Codes of an S array's rows by ``_np``; -1 where it does not hold
+        the value (two values of one hash: the later one is never found here
+        and takes the ``_index`` path every time)."""
+        _, _, keys, codes, vals = self._np
+        if not len(keys):
+            return np.full(len(values), -1, dtype=np.int32)
+        k = _hash_rows(values)
+        if len(keys) > _SEARCH_IN_ORDER:
+            order = np.argsort(k)
+            pos = np.empty(len(k), dtype=np.intp)
+            pos[order] = np.searchsorted(keys, k[order])
+        else:
+            pos = np.searchsorted(keys, k)
+        pos[pos == len(keys)] = 0
+        cand = codes[pos]
+        hit = (keys[pos] == k) & (vals[cand] == values)
+        return np.where(hit, cand, np.int32(-1))
 
     def try_encode(self, value: "bytes | str") -> int:
         """Encode without inserting; returns -1 if absent (predicate constants

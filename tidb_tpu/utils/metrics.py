@@ -299,6 +299,17 @@ DEVICE_TRANSFER = REGISTRY.counter(
     "Host<->device bytes moved by the cop engines",
     ("dir",),
 )
+# the bulk load (executor/load._ingest_columnar): what every process pays
+# before its first statement
+BULK_LOAD_ROWS = REGISTRY.counter(
+    "tidb_tpu_bulk_load_rows_total", "Rows ingested as columnar stable blocks by the bulk loader", ("table",)
+)
+BULK_LOAD_SECONDS = REGISTRY.counter(
+    "tidb_tpu_bulk_load_seconds_total",
+    "Seconds in the bulk loader's columnar ingest (encode = string columns to dictionary codes; "
+    "ingest = MemStore.ingest_columnar with its change-log notes and region splits)",
+    ("phase",),
+)
 LOCK_WAIT_SECONDS = REGISTRY.counter(
     "tidb_tpu_lock_wait_seconds_total",
     "Seconds threads spent blocked on a contended served-path lock (utils/tracing.TracedLock)",
