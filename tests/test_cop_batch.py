@@ -95,6 +95,12 @@ def _summary(s, text):
     return rows, s.exec_summary
 
 
+def _forget(db):
+    """Drop the store's resolved batch tasks (ISSUE 36): the statement after is
+    resolved anew, through ``_batch_path`` and ``_exec_single``, as a first one."""
+    tpu_engine.cache_for(db.store)._resolved.clear()
+
+
 def _counts():
     return metrics.COP_REGIONS.get(path="batched"), metrics.COP_REGIONS.get(path="single")
 
@@ -271,6 +277,7 @@ def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypat
     monkeypatch.setattr(tpu_engine, "get_kernel", lambda dag, *a, **kw: bound.append((dag, a, kw)) or real_get(dag, *a, **kw))
     for shape in sorted(SHAPES):
         del sent[:], bound[:]
+        _forget(db)
         s.query(SHAPES[shape])
         (kernel, (slots, _, _)), = [c for c in sent if c[0].m > 1]
         dag, (n_pad, agg_cap), kw = next(b for b in bound if b[2]["m"] == kernel.m)
@@ -375,12 +382,13 @@ def test_batch_that_fails_as_a_whole_falls_back_to_a_task_a_region(served, monke
     want, clean = _summary(s, text)
     real = tpu_engine._exec_single
 
-    def broken(ph, store, dag, bound, scan, cache, parts, warn=None):
+    def broken(ph, store, dag, bound, scan, cache, parts, warn=None, keep=None):
         if len(parts) > 1:
             raise RuntimeError("chaos: the device dropped the batch")
-        return real(ph, store, dag, bound, scan, cache, parts, warn)
+        return real(ph, store, dag, bound, scan, cache, parts, warn, keep)
 
     monkeypatch.setattr(tpu_engine, "_exec_single", broken)
+    _forget(db)  # a batch that fails on its resolved task's way: tests/test_cop_resolved.py
     got, summary = _summary(s, text)
     assert got == want
     assert summary.num == summary.regions == clean.regions  # no batch result; every region its own task
@@ -544,6 +552,7 @@ def test_more_regions_than_a_call_holds_are_still_one_task(many, shape, monkeypa
     monkeypatch.setattr(tpu_engine, "_MAP_ROWS", 64 * n_pad)  # the ladder's top: 64 regions a call, as 2^24 rows are 64 of 262,144
     want = [c for n_pad, k in sorted(by_shape.items()) for c in tpu_engine._map_counts(k, n_pad)]
     assert want.count(64) == 1 and len(want) >= 2
+    _forget(db)  # the calls kept were made under another ladder
     rows, summary, (dispatch,) = _dispatches(s, SHAPES[shape], tmp_path)
     assert rows == _host(s, SHAPES[shape])
     assert summary.num == 1 and summary.regions == sum(by_shape.values()) > 64  # ONE task, every region in it

@@ -22,6 +22,7 @@ from __future__ import annotations
 import os
 import threading
 import time as _time
+from collections import OrderedDict
 from dataclasses import dataclass, field
 from typing import Optional, Sequence
 
@@ -202,6 +203,37 @@ class RegionColumns:
         return known
 
 
+class ResolvedTask:
+    """What a batch cop task over clean regions derived on its way to the
+    device, kept for the next task of the same key (``ColumnCache.resolved``).
+    The cache's part: ``kept`` — ``(place in the batch, (region_id, table_id),
+    entry)`` of every region it served, the entry being the head the task was
+    resolved over; ``left`` — place and key of every region that left it for
+    want of a head entry (written since, never built); ``epoch`` — the
+    dictionary epoch it was bound under; ``width`` — bytes a row its reads count
+    for (the heatmap's). The engine's (``tpu_engine._exec_single``): ``calls``
+    — the program calls as ``_run_all`` takes them, kernels and device
+    arguments; ``answered`` — the ``(region's place among kept, kernel)`` each
+    of their results is, in order; ``keys`` — the device LRU's keys of the
+    arrays the calls hold. Immutable once published: any number of tasks read
+    one at once."""
+
+    __slots__ = ("kept", "left", "epoch", "width", "regions", "calls", "answered", "keys", "__weakref__")
+
+    def __init__(self, kept: tuple, left: tuple, epoch: int, width: int):
+        self.kept, self.left, self.epoch, self.width = kept, left, epoch, width
+        self.regions = frozenset(ekey for _, ekey, _ in kept)
+        self.calls: list = []
+        self.answered: list = []
+        self.keys: list = []
+
+
+# resolved tasks a store keeps, the least recently used going first: a task is
+# references only (entries, kernels, device arrays the LRU owns), and a
+# template's parameter sets are a task each over the same arrays
+RESOLVED_TASKS = 64
+
+
 class ColumnCache:
     """Per-store singleton (both engines share it; the TPU engine layers a
     device-array cache keyed by the same (region, version) identity)."""
@@ -220,6 +252,8 @@ class ColumnCache:
         self._alias: dict[int, int] = {}  # partition physical id → logical id
         # bumped whenever a dictionary is compacted: device caches must drop
         self.epoch = 0
+        # resolved batch tasks by their key, the last used last (``resolved``)
+        self._resolved: "OrderedDict[tuple, ResolvedTask]" = OrderedDict()
 
     def resident_bytes(self) -> int:
         """Host bytes pinned by cached column entries (base entries, delta
@@ -437,6 +471,70 @@ class ColumnCache:
         if entry is not None and entry.data_version == region.data_version and read_ts >= entry.built_ts:
             return entry
         return None
+
+    # -- resolved batch tasks ---------------------------------------------------
+    def resolved(self, key: tuple, batch: list, read_ts: int) -> tuple[Optional[ResolvedTask], str]:
+        """The resolved task kept under ``key`` where it still holds for
+        ``batch`` (``[(region, ranges), ...]``, the regions and ranges the key
+        names) at ``read_ts``, and how the lookup went: ``hit``; ``miss``, no
+        task under the key; ``stale``, one that no longer holds, dropped here."""
+        with self._mu:
+            task = self._resolved.get(key)
+            if task is not None:
+                self._resolved.move_to_end(key)
+        if task is None:
+            return None, "miss"
+        if self._still_holds(task, batch, read_ts):
+            return task, "hit"
+        self.unresolve(key, task)
+        return None, "stale"
+
+    def _still_holds(self, task: ResolvedTask, batch: list, read_ts: int) -> bool:
+        """Every region the task served passes what :meth:`head` tests, on the
+        very entry the task was resolved over; every region that left still has
+        no head (one merged since belongs in the batch again); no dictionary
+        was compacted. Integer and identity compares, no lock a region served
+        (a dict lookup is atomic), nothing built: a write, a split, a merge, a
+        rebuild, ``invalidate_table`` or an older snapshot fails one of them."""
+        if task.epoch != self.epoch:
+            return False
+        entries = self._entries
+        for at, ekey, entry in task.kept:
+            if entries.get(ekey) is not entry or entry.data_version != batch[at][0].data_version or read_ts < entry.built_ts:
+                return False
+        return all(self.head(batch[at][0], ekey[1], read_ts) is None for at, ekey in task.left)
+
+    def resolve(self, key: tuple, task: ResolvedTask) -> None:
+        """Publish ``task`` under ``key``, in place of what was there."""
+        with self._mu:
+            self._resolved[key] = task
+            self._resolved.move_to_end(key)
+            while len(self._resolved) > RESOLVED_TASKS:
+                self._resolved.popitem(last=False)
+
+    def unresolve(self, key: tuple, task: ResolvedTask | None = None) -> None:
+        """Drop the resolved task under ``key`` (``task`` given: only if it is
+        still that one)."""
+        with self._mu:
+            if task is None or self._resolved.get(key) is task:
+                self._resolved.pop(key, None)
+
+    def unresolve_regions(self, regions: set) -> None:
+        """Drop every resolved task that served one of ``regions``
+        (``(region_id, table_id)``): the device LRU let go of an array of theirs."""
+        with self._mu:
+            for k in [k for k, t in self._resolved.items() if not t.regions.isdisjoint(regions)]:
+                del self._resolved[k]
+
+    def note_served(self, task: ResolvedTask) -> None:
+        """The cop-serve traffic seam of :meth:`get_split`, for the regions a
+        resolved task serves without asking for their entries again."""
+        note = getattr(self.store, "note_region_read", None)
+        if note is not None:
+            width = task.width
+            for _, (region_id, table_id), entry in task.kept:
+                if entry.n:
+                    note(region_id, table_id, entry.n, entry.n * width)
 
     def _get_split_once(self, key, region, table_id, schema, slots, read_ts):
         """One get_split attempt; None = a concurrent merge replaced the
@@ -1047,6 +1145,7 @@ class ColumnCache:
             for key in [k for k in self._dicts if k[0] == table_id]:
                 del self._dicts[key]
             self.epoch += 1
+            self._resolved.clear()  # bound under the epoch before, every one
             self._update_delta_gauge_locked()
         drop = getattr(self.store, "col_changes_drop", None)
         if drop is not None:
@@ -1066,6 +1165,12 @@ def cache_for(store: MemStore) -> ColumnCache:
             c = ColumnCache(store)
             _CACHES[store] = c
         return c
+
+
+def caches() -> list:
+    """``[(store, cache), ...]`` of the stores that are alive."""
+    with _CACHES_MU:
+        return list(_CACHES.items())
 
 
 def peek_resident_bytes(store, table_id: int) -> int:

@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import threading
 import time as _time
+import weakref
 from collections import OrderedDict
 from typing import NamedTuple
 
@@ -40,7 +41,7 @@ import numpy as np
 
 from tidb_tpu.copr import dagpb
 from tidb_tpu.copr.binder import Binder, UnsupportedForDevice
-from tidb_tpu.copr.colcache import DEVICE_BLOCK_ROWS, cache_for, hbm_budget
+from tidb_tpu.copr.colcache import DEVICE_BLOCK_ROWS, ResolvedTask, cache_for, caches, hbm_budget
 from tidb_tpu.copr.host_engine import execute_dag as host_execute_dag
 from tidb_tpu.kv import KeyRange, tablecodec
 from tidb_tpu.kv.memstore import MemStore, Region
@@ -125,9 +126,15 @@ class _DeviceLRU:
         self._mu = _tracing.TracedLock("device_lru", threading.Lock())
         self._entries: "OrderedDict[tuple, tuple]" = OrderedDict()  # key → (pair, nbytes)
         self.total = 0
+        # resolved batch tasks used since anything was last looked up here: a use
+        # of theirs is a use of every array they hold, noted in one step
+        # (``touch``) and applied to the order before anything is moved or evicted
+        self._touched: dict = {}  # id → weak reference
 
     def get(self, key):
         with self._mu:
+            if self._touched:
+                self._settle()
             hit = self._entries.get(key)
             if hit is None:
                 return None
@@ -135,7 +142,10 @@ class _DeviceLRU:
             return hit[0]
 
     def put(self, key, pair, nbytes: int):
+        evicted = []
         with self._mu:
+            if self._touched:
+                self._settle()
             old = self._entries.pop(key, None)
             if old is not None:
                 self.total -= old[1]
@@ -147,19 +157,60 @@ class _DeviceLRU:
                     break
                 del self._entries[k]
                 self.total -= nb
+                evicted.append(k)
+        _unresolve_evicted(evicted)
 
     def evict_superseded(self, ident, ver_epoch):
         """Drop stale epochs/versions of the same column — each write bumps
         data_version and stale device arrays would leak HBM forever. Sibling
         blocks of the *current* (version, epoch) stay resident."""
         with self._mu:
-            for k in [
+            evicted = [
                 k
                 for k in self._entries
                 if k[: len(ident)] == ident and k[len(ident) : len(ident) + 2] != ver_epoch
-            ]:
+            ]
+            for k in evicted:
                 self.total -= self._entries[k][1]
                 del self._entries[k]
+        _unresolve_evicted(evicted)
+
+    def touch(self, holder) -> None:
+        """``holder`` (its ``keys``: entries of this LRU it holds the arrays of)
+        was used: they are as recent as it is. One step a task, whatever it
+        holds; the order is brought up to date by the next lookup or put."""
+        with self._mu:
+            self._touched.pop(id(holder), None)
+            self._touched[id(holder)] = weakref.ref(holder)
+
+    def holds(self, keys) -> bool:
+        with self._mu:
+            return all(k in self._entries for k in keys)
+
+    def _settle(self) -> None:
+        for ref in self._touched.values():
+            holder = ref()
+            if holder is not None:
+                for k in holder.keys:
+                    if k in self._entries:
+                        self._entries.move_to_end(k)
+        self._touched.clear()
+
+
+def _unresolve_evicted(keys: list) -> None:
+    """A resolved batch task holds its regions' device arrays beside the LRU,
+    which owns them: whatever the LRU lets go of takes the tasks that hold an
+    array of the same region with it, or they would pin its HBM behind the
+    budget's back. Outside the LRU's lock."""
+    if not keys:
+        return
+    gone: dict = {}
+    for k in keys:
+        gone.setdefault(k[0], set()).add((k[1], k[2]))
+    for store, cache in caches():
+        regions = gone.get(getattr(store, "nonce", None))
+        if regions:
+            cache.unresolve_regions(regions)
 
 
 _DEVICE_LRU = _DeviceLRU(hbm_budget())
@@ -551,15 +602,36 @@ def _batch_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, batch: list
     a task of its own, beside this one. So does every region if the batch
     fails as a whole: each then meets the fault alone, under the client's
     re-split / degrade policy. Partials stay per region; the root's final
-    aggregation merges them as it merges tasks."""
+    aggregation merges them as it merges tasks.
+
+    What all of that comes to — who stays, the bound DAG's kernels, the
+    regions' device arrays, call by call — is the same the next time the same
+    DAG meets the same regions unchanged, so it is kept beside the column cache
+    (``colcache.ResolvedTask``) under the DAG's fingerprint, the table and the
+    batch's regions and ranges: a task that finds it, and finds every entry
+    still the head it was (``ColumnCache.resolved``), sends the kept calls
+    again (``_run_resolved``) and derives nothing."""
     scan = dag.executors[0]
     ph.to("bind")
+    cache = cache_for(store)
+    key = (dag.fingerprint(), scan.table_id, tuple([(region.region_id, *ranges) for region, ranges in batch]))
+    task, how = cache.resolved(key, batch, read_ts)
+    ph.note(resolved=how)
+    _metrics.COP_TASK_RESOLVED.inc(how=how)
+    det = _ed.current_cop()
+    if det is not None:
+        det.resolved = how
+    if task is not None:
+        return _run_resolved(ph, dag, scan, cache, key, task, batch, warn, leave)
     schema = RowSchema(scan.storage_schema)
     slots = [c.column_id for c in scan.columns if not c.is_handle]
-    cache = cache_for(store)
     parts, kept, left = [], [], []
-    for region, ranges in batch:
-        part = None
+    # the resolved task's: place, key in the cache and entry of every region that stays,
+    # place and key of every one that leaves; nothing is kept once a region leaves that
+    # has a head (a slot to decode, an entry not complete): it may stay the next time
+    served, gone, keepable = [], [], True
+    for at, (region, ranges) in enumerate(batch):
+        part = head = None
         try:
             head = cache.head(region, scan.table_id, read_ts) if len(ranges) <= MAX_RANGES else None
             if head is not None and all(s in head.cols for s in slots):
@@ -571,25 +643,70 @@ def _batch_path(ph: _Phases, store: MemStore, dag: dagpb.DAGRequest, batch: list
             part = None
         if part is None:
             left.append((region, ranges))
+            gone.append((at, (region.region_id, scan.table_id)))
+            keepable = keepable and head is None
         else:
             parts.append(part)
             kept.append((region, ranges))
+            served.append((at, (region.region_id, scan.table_id), part.entry))
     if left:
         leave(left)
     if not parts:
         return None
+    resolving = None
     try:
         bound = Binder(cache, scan.table_id, scan.columns, _BinderView(*(p.entry for p in parts))).bind_dag(dag)
-        out = _exec_single(ph, store, dag, bound, scan, cache, parts, warn)
+        if keepable:
+            resolving = ResolvedTask(tuple(served), tuple(gone), cache.epoch, 8 * max(1, len(slots)))
+        out = _exec_single(ph, store, dag, bound, scan, cache, parts, warn, resolving)
     except Exception as e:  # noqa: BLE001 — as above, for all of them
-        lg = _ev.on(_ev.WARN)
-        if lg is not None:
-            lg.emit(_ev.WARN, "copr", "batch_fallback", regions=len(parts), cause=f"{type(e).__name__}: {e}")
-        leave(kept)
-        return None
-    det = _ed.current_cop()
+        return _batch_failed(cache, key, kept, e, leave)
+    if resolving is not None and resolving.epoch == cache.epoch:
+        cache.resolve(key, resolving)
+        if not _DEVICE_LRU.holds(resolving.keys):
+            cache.unresolve(key, resolving)  # the LRU let go of an array while the task was on its way
     if det is not None:
         det.regions = len(parts)
+    return out
+
+
+def _batch_failed(cache, key: tuple, kept: list, e: Exception, leave) -> None:
+    """A batch that fails as a whole: its resolved task goes, every region
+    meets the fault again in a task of its own."""
+    cache.unresolve(key)
+    lg = _ev.on(_ev.WARN)
+    if lg is not None:
+        lg.emit(_ev.WARN, "copr", "batch_fallback", regions=len(kept), cause=f"{type(e).__name__}: {e}")
+    leave(kept)
+    return None
+
+
+def _run_resolved(ph: _Phases, dag: dagpb.DAGRequest, scan, cache, key: tuple, task: ResolvedTask, batch: list, warn, leave):
+    """A batch task answered from its resolved task: the regions that left
+    leave again, the kept calls are sent as they are. What a task owes besides
+    its answer is paid as ``_batch_path`` + ``_exec_single`` pay it: every
+    region's read counted (the heatmap's), the arrays' use (one touch for the
+    task), the sidecar's device-cache hits by the arrays reused. The results of
+    a region that overflowed its group cap come twice, the re-run's last: the
+    last stands."""
+    if task.left:
+        leave([batch[at] for at, _ in task.left])
+    det = _ed.current_cop()
+    try:
+        ph.to("inputs")
+        cache.note_served(task)
+        _DEVICE_LRU.touch(task)
+        if det is not None:
+            det.dev_cache_hits += len(task.keys)
+        _metrics.DEVICE_CACHE.inc(len(task.keys), result="hit")
+        results: list = [None] * len(task.kept)
+        for (i, kernel), got in zip(task.answered, _run_all(ph, task.calls)):
+            results[i] = (*got, kernel)
+        out = _decode(ph, results, dag, cache, scan, warn)
+    except Exception as e:  # noqa: BLE001 — as a batch that fails on its first way
+        return _batch_failed(cache, key, [batch[at] for at, _, _ in task.kept], e, leave)
+    if det is not None:
+        det.regions = len(task.kept)
     return out
 
 
@@ -666,9 +783,9 @@ def _grown_cap(agg_cap: int, ngroups: int, ceiling: int) -> int:
     return min(max(agg_cap * 4, bucket_size(ngroups)), ceiling)
 
 
-def _single_device_inputs(store, scan, cache, entry, region, n_pad):
+def _single_device_inputs(store, scan, cache, entry, region, n_pad, keys: list | None = None):
     """(handles_dev, cols_dev) for the single-kernel path, via the same LRU
-    identities as repeat queries."""
+    identities as repeat queries; the identities asked for go on ``keys``."""
     epoch = cache.epoch
     cacheable = entry.complete
     ver = entry.vtag_span(0, entry.n)
@@ -688,6 +805,10 @@ def _single_device_inputs(store, scan, cache, entry, region, n_pad):
                 return _narrowed(entry, cid, data), valid
 
             cols_dev.append(_device_put_col(ckey, mk, n_pad, cacheable))
+            if keys is not None:
+                keys.append(ckey)
+    if keys is not None:
+        keys.append(hkey)
     return handles_pair[0], cols_dev
 
 
@@ -711,7 +832,7 @@ def _map_counts(k: int, n_pad: int) -> list[int]:
     return counts
 
 
-def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=None) -> Chunk:
+def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=None, keep=None) -> Chunk:
     """Regions of at most one block each (or COMPLETE-mode aggs): one padded
     array a region. A task is one region, or the many of a batch
     (``_batch_path``; ``bound`` then holds for all of them). The regions that
@@ -726,13 +847,16 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
     a region read through its delta, an overflow's re-run, the odd region of
     another size — calls today's single-region program: there is no second
     path for it. Every call is dispatched before the ONE fetch; partials stay
-    one a region, and decode into one Chunk, region after region."""
+    one a region, and decode into one Chunk, region after region. ``keep``, a
+    batch's ``colcache.ResolvedTask``, takes what was sent and what it holds: the
+    calls of every round (an overflow's re-run after the call it overflowed in),
+    which result each answers, the device LRU's keys."""
     needs_agg = kernel_needs_agg(bound)
     ph.to("inputs")
     runs = []  # a region: [its kernel key (n_pad, full_scan, delta_cap, agg_cap, lanes), the arguments of its own]
     for entry, region, rarr, delta in parts:
         n_pad = bucket_size(max(entry.n, 1))
-        handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad)
+        handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad, None if keep is None else keep.keys)
         dcap = 0
         dargs = ()
         if delta is not None:
@@ -764,6 +888,9 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
         ph.to("inputs")
         calls = [(kernel, _call_args(kernel, [runs[i][1] for i in live], key), live) for kernel, key, live in sends]
         answered = [(i, kernel) for kernel, _, live in calls for i in live]
+        if keep is not None:
+            keep.calls += calls
+            keep.answered += answered
         over = []
         for (i, kernel), got in zip(answered, _run_all(ph, calls)):
             ngroups = int(got[0][0, 1])

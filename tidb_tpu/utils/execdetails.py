@@ -57,7 +57,7 @@ class CopExecDetails:
         "host_ms", "compile_ms", "h2d_bytes", "d2h_bytes", "dev_cache_hits",
         "dev_cache_misses", "engine", "degraded", "retries", "backoff_ms",
         "resplits", "delta_rows", "delta_read", "merges", "keys_scanned", "bytes_scanned",
-        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions", "programs",
+        "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions", "programs", "resolved",
     )
 
     def __init__(self, region_id: int = -1, store: str = ""):
@@ -69,6 +69,10 @@ class CopExecDetails:
         # program for the regions that share a padded shape (tpu_engine._exec_single),
         # so far fewer than ``regions``; 0 = the host engine answered
         self.programs = 0
+        # a batch task and its resolved task (tpu_engine._batch_path): "hit" = the kept
+        # program calls were sent again, "miss" = none kept, "stale" = one kept that
+        # no longer held; "" = not a batch task
+        self.resolved = ""
         self.store = store  # "" = embedded (local) store
         self.queue_ms = 0.0  # send-queue wait before a worker picked it up
         self.wire_ms = 0.0  # RPC wall minus store-side processing (remote)
@@ -140,6 +144,8 @@ class CopExecDetails:
             out["sb"] = self.bytes_scanned
         if self.programs:
             out["pg"] = self.programs
+        if self.resolved:
+            out["rv"] = self.resolved
         for key, attr in _PHASE_PB:
             v = getattr(self, attr)
             if v:
@@ -170,6 +176,8 @@ class CopExecDetails:
         self.keys_scanned += int(pb.get("sk", 0))
         self.bytes_scanned += int(pb.get("sb", 0))
         self.programs += int(pb.get("pg", 0))
+        if pb.get("rv"):
+            self.resolved = pb["rv"]
         for key, attr in _PHASE_PB:
             if key in pb:
                 setattr(self, attr, getattr(self, attr) + float(pb[key]))
@@ -189,7 +197,7 @@ class CopTasksSummary:
         "h2d_bytes", "d2h_bytes", "dev_cache_hits", "dev_cache_misses",
         "engines", "degraded", "retries", "backoff_ms", "resplits",
         "delta_rows", "delta_read", "merges", "keys_scanned", "bytes_scanned",
-        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions", "programs",
+        "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions", "programs", "resolved",
     )
 
     def __init__(self):
@@ -219,6 +227,7 @@ class CopTasksSummary:
         self.phases_ms = [0.0] * len(PHASES)
         self.regions = 0  # regions the tasks served: above ``num`` where tasks were batches
         self.programs = 0  # device program calls the tasks sent: below ``regions`` where a batch mapped them
+        self.resolved: dict[str, int] = {}  # batch tasks by how they met their resolved task (hit / miss / stale)
 
     @property
     def num(self) -> int:
@@ -249,6 +258,8 @@ class CopTasksSummary:
         self.bytes_scanned += d.bytes_scanned
         self.regions += d.regions
         self.programs += d.programs
+        if d.resolved:
+            self.resolved[d.resolved] = self.resolved.get(d.resolved, 0) + 1
         for i, (_key, attr) in enumerate(_PHASE_PB):
             self.phases_ms[i] += getattr(d, attr)
         if d.proc_ms >= self.max_proc_ms:
@@ -277,6 +288,9 @@ class CopTasksSummary:
             f"regions: {self.regions}",
             f"programs: {self.programs}",
         ]
+        if self.resolved:
+            # batch tasks: "hit" = the program calls kept from the statement before were sent as they were
+            parts.append("resolved: " + " ".join(k if v == 1 else f"{k}×{v}" for k, v in sorted(self.resolved.items())))
         if self.queue_ms:
             parts.append(f"queue: {self.queue_ms / n:.1f}ms")  # avg send-queue wait
         if self.wire_ms:

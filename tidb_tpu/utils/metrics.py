@@ -241,6 +241,11 @@ COP_PROGRAMS = REGISTRY.counter(
     "Device program calls sent by cop tasks: mapped = one call answering many regions of a batch task, single = one region",
     ("form",),
 )
+COP_TASK_RESOLVED = REGISTRY.counter(
+    "tidb_tpu_cop_task_resolved_total",
+    "Batch cop tasks by how they met their resolved task: hit = the kept program calls re-sent, miss = none kept, stale = one kept that no longer held",
+    ("how",),
+)
 STORE_FAILOVER = REGISTRY.counter(
     "tidb_tpu_store_failover_total",
     "Sharded-fleet reads/authority calls served by a non-primary replica",
