@@ -1,0 +1,10 @@
+"""wire + session + planner: time in `tidb:result.rows`: `chunk.rows()` and the `Result`. Per analytic statement
+of the traced window (`harness/span_tree.py`)."""
+from harness import span_tree
+
+UNIT = "ms"
+
+
+def read(ctx):
+    tree = span_tree.of_run(ctx)
+    return None if tree is None else tree.sum_ms("result.rows")

@@ -199,11 +199,8 @@ class PlacementClient:
         moved). Returns True iff the cached map changed. Below quorum the
         stale cache is kept (False) — routing on the last known map beats
         refusing reads the fleet can still serve."""
-        from tidb_tpu.utils import metrics as _m
-
         reads, _last = self._sweep(lambda st: st.placement_read(None))
         if len(reads) < self.quorum:
-            _m.PLACEMENT_REFRESH.inc(outcome="below_quorum")
             return False
         best: dict[int, tuple[int, int]] = {}
         for _, recs in reads:
@@ -221,7 +218,6 @@ class PlacementClient:
                     except ConnectionError:
                         pass
             changed |= self._adopt(tid, e, s)
-        _m.PLACEMENT_REFRESH.inc(outcome="changed" if changed else "clean")
         return changed
 
     def propose(self, table_id: int, shard: int, epoch: int) -> bool:

@@ -204,7 +204,7 @@ class ResourceGroupManager:
 
     def charge(self, name: str, usage: ResourceUsage) -> None:
         """Fold one statement's finalized usage into the group's cumulative
-        accumulator + the group-labeled registry counters (metering only —
+        accumulator + the group-labeled registry counter (metering only —
         the token bucket is consumed separately by the session)."""
         with self._mu:
             g = self._groups.get(name)
@@ -214,7 +214,6 @@ class ResourceGroupManager:
         from tidb_tpu.utils import metrics as _m
 
         _m.RU_CONSUMED.inc(usage.ru, group=name)
-        _m.RU_STATEMENTS.inc(group=name)
 
     def record_runaway(self, group: str, action: str, sql: str) -> None:
         with self._mu:

@@ -72,6 +72,7 @@ class PacketIO:
     def __init__(self, sock):
         self.sock = sock
         self.seq = 0
+        self.sent = 0  # bytes written so far (`bytes_out` of a `conn.command` span is a difference of two)
 
     def read(self) -> bytes:
         hdr = self._recvn(4)
@@ -90,6 +91,7 @@ class PacketIO:
             off += len(part)
             if off >= len(payload) and len(part) != 0xFFFFFF:
                 break
+        self.sent += len(out)
         self.sock.sendall(bytes(out))
 
     def reset_seq(self) -> None:

@@ -12,6 +12,15 @@ import time
 from typing import Optional
 
 from tidb_tpu.server import protocol as p
+from tidb_tpu.utils import tracing as _tracing
+
+# `cmd` on a `conn.command` span
+_CMD_NAMES = {
+    p.COM_PING: "ping", p.COM_INIT_DB: "init_db", p.COM_QUERY: "query",
+    p.COM_STMT_PREPARE: "stmt_prepare", p.COM_STMT_EXECUTE: "stmt_execute",
+    p.COM_STMT_CLOSE: "stmt_close", p.COM_STMT_SEND_LONG_DATA: "stmt_send_long_data",
+    p.COM_STMT_FETCH: "stmt_fetch", p.COM_STMT_RESET: "stmt_reset",
+}
 
 
 def _nonce() -> bytes:
@@ -150,33 +159,21 @@ class ClientConn:
                 cmd, data = pkt[0], pkt[1:]
                 if cmd == p.COM_QUIT:
                     return
-                if cmd == p.COM_PING:
-                    io.write(p.ok_packet())
-                elif cmd == p.COM_INIT_DB:
-                    self._run_sql(io, f"USE `{data.decode()}`")
-                elif cmd == p.COM_QUERY:
-                    self._run_sql(io, data.decode("utf-8"))
-                elif cmd == p.COM_STMT_PREPARE:
-                    self._stmt_prepare(io, data.decode("utf-8"))
-                elif cmd == p.COM_STMT_EXECUTE:
-                    self._stmt_execute(io, data)
-                elif cmd == p.COM_STMT_CLOSE:
-                    sid = struct.unpack_from("<I", data, 0)[0]
-                    self.cursors.pop(sid, None)
-                    st = self.stmts.pop(sid, None)
-                    if st is not None:
-                        self.session.prepared.pop(st[0], None)
-                    # COM_STMT_CLOSE sends no response (protocol)
-                elif cmd == p.COM_STMT_SEND_LONG_DATA:
-                    pass  # protocol: no response; long data unsupported → the
-                    # execute fails cleanly on the missing parameter
-                elif cmd == p.COM_STMT_FETCH:
-                    self._stmt_fetch(io, data)
-                elif cmd == p.COM_STMT_RESET:
-                    self.cursors.pop(struct.unpack_from("<I", data, 0)[0], None)
-                    io.write(p.ok_packet())
-                else:
-                    io.write(p.err_packet(1047, f"Unknown command {cmd}", "08S01"))
+                # last byte of the command in → last byte of its response
+                # out. The read above is under no span: a connection's wait
+                # for its client is the gap between two of these
+                with _tracing.region(
+                    "conn.command", conn=self.conn_id, cmd=_CMD_NAMES.get(cmd, "unknown")
+                ) as c_span:
+                    seen, sent = self.session.stmt_id, io.sent
+                    rows = self._command(io, cmd, data)
+                    if c_span is not None:
+                        meta = {"bytes_out": io.sent - sent}
+                        if rows is not None:
+                            meta["rows"] = rows
+                        if self.session.stmt_id is not seen:  # it ran a statement through Session.execute
+                            meta["stmt"] = self.session.stmt_id
+                        c_span.note(**meta)
         finally:
             if self.authed:  # rejected/aborted handshakes never "connected"
                 self.server._conn_event("disconnected", self)
@@ -185,6 +182,38 @@ class ClientConn:
                 self.sock.close()
             except OSError:
                 pass
+
+    def _command(self, io: p.PacketIO, cmd: int, data: bytes) -> Optional[int]:
+        """One command, its response written; the rows it sent, where it
+        sends rows."""
+        if cmd == p.COM_PING:
+            io.write(p.ok_packet())
+        elif cmd == p.COM_INIT_DB:
+            return self._run_sql(io, f"USE `{data.decode()}`")
+        elif cmd == p.COM_QUERY:
+            return self._run_sql(io, data.decode("utf-8"))
+        elif cmd == p.COM_STMT_PREPARE:
+            self._stmt_prepare(io, data.decode("utf-8"))
+        elif cmd == p.COM_STMT_EXECUTE:
+            return self._stmt_execute(io, data)
+        elif cmd == p.COM_STMT_CLOSE:
+            sid = struct.unpack_from("<I", data, 0)[0]
+            self.cursors.pop(sid, None)
+            st = self.stmts.pop(sid, None)
+            if st is not None:
+                self.session.prepared.pop(st[0], None)
+            # COM_STMT_CLOSE sends no response (protocol)
+        elif cmd == p.COM_STMT_SEND_LONG_DATA:
+            pass  # protocol: no response; long data unsupported → the
+            # execute fails cleanly on the missing parameter
+        elif cmd == p.COM_STMT_FETCH:
+            return self._stmt_fetch(io, data)
+        elif cmd == p.COM_STMT_RESET:
+            self.cursors.pop(struct.unpack_from("<I", data, 0)[0], None)
+            io.write(p.ok_packet())
+        else:
+            io.write(p.err_packet(1047, f"Unknown command {cmd}", "08S01"))
+        return None
 
     # -- binary prepared protocol (ref: conn.go:1281-1428 COM_STMT_*) --------
     def _stmt_prepare(self, io: p.PacketIO, sql: str) -> None:
@@ -217,12 +246,12 @@ class ClientConn:
                 io.write(p.column_def(str(cname), tc, ln, dec))
             io.write(p.eof_packet())
 
-    def _stmt_execute(self, io: p.PacketIO, data: bytes) -> None:
+    def _stmt_execute(self, io: p.PacketIO, data: bytes) -> Optional[int]:
         sid = struct.unpack_from("<I", data, 0)[0]
         st = self.stmts.get(sid)
         if st is None:
             io.write(p.err_packet(1243, f"Unknown prepared statement handler ({sid})", "HY000"))
-            return
+            return None
         name, n_params, prev_types = st
         cursor_flags = data[4] if len(data) > 4 else 0
         # MySQL closes any open cursor on re-execute: a stale one would feed
@@ -235,33 +264,35 @@ class ClientConn:
             res = self.session.execute_prepared(name, vals)
         except Exception as e:
             io.write(p.err_packet(1105, str(e)))
-            return
+            return None
         finally:
             self.current_sql = None
         wc = min(len(self.session.warnings), 0xFFFF)
         if not res.columns:
             io.write(p.ok_packet(affected=res.affected, last_insert_id=res.last_insert_id, warnings=wc))
-            return
-        ftypes = getattr(res, "ftypes", None)
-        io.write(p.lenc_int(len(res.columns)))
-        for i, cname in enumerate(res.columns):
-            if ftypes is not None and i < len(ftypes) and ftypes[i] is not None:
-                tc, ln, dec = p.type_for(ftypes[i])
-            else:
-                tc, ln, dec = p.T_VAR_STRING, 255, 0
-            io.write(p.column_def(str(cname), tc, ln, dec))
-        if cursor_flags & p.CURSOR_TYPE_READ_ONLY:
-            # cursor mode (ref: conn_stmt.go): park the result server-side;
-            # the client drains it in COM_STMT_FETCH batches
-            self.cursors[sid] = [list(res.rows), ftypes]
-            io.write(p.eof_packet(status=2 | p.SERVER_STATUS_CURSOR_EXISTS, warnings=wc))
-            return
-        io.write(p.eof_packet())
-        for row in res.rows:
-            io.write(p.binary_row(row, ftypes))
-        io.write(p.eof_packet(warnings=wc))
+            return None
+        with _tracing.region("conn.write"):
+            ftypes = getattr(res, "ftypes", None)
+            io.write(p.lenc_int(len(res.columns)))
+            for i, cname in enumerate(res.columns):
+                if ftypes is not None and i < len(ftypes) and ftypes[i] is not None:
+                    tc, ln, dec = p.type_for(ftypes[i])
+                else:
+                    tc, ln, dec = p.T_VAR_STRING, 255, 0
+                io.write(p.column_def(str(cname), tc, ln, dec))
+            if cursor_flags & p.CURSOR_TYPE_READ_ONLY:
+                # cursor mode (ref: conn_stmt.go): park the result server-side;
+                # the client drains it in COM_STMT_FETCH batches
+                self.cursors[sid] = [list(res.rows), ftypes]
+                io.write(p.eof_packet(status=2 | p.SERVER_STATUS_CURSOR_EXISTS, warnings=wc))
+                return 0
+            io.write(p.eof_packet())
+            for row in res.rows:
+                io.write(p.binary_row(row, ftypes))
+            io.write(p.eof_packet(warnings=wc))
+        return len(res.rows)
 
-    def _stmt_fetch(self, io: p.PacketIO, data: bytes) -> None:
+    def _stmt_fetch(self, io: p.PacketIO, data: bytes) -> Optional[int]:
         """COM_STMT_FETCH: stream the next n rows of an open cursor (ref:
         conn_stmt.go handleStmtFetch; EOF carries LAST_ROW_SENT once
         drained)."""
@@ -269,7 +300,7 @@ class ClientConn:
         cur = self.cursors.get(sid)
         if cur is None:
             io.write(p.err_packet(1243, f"Unknown cursor for statement ({sid})", "HY000"))
-            return
+            return None
         rows, ftypes = cur
         batch, cur[0] = rows[:nrows], rows[nrows:]
         for row in batch:
@@ -279,38 +310,42 @@ class ClientConn:
         else:
             self.cursors.pop(sid, None)
             io.write(p.eof_packet(status=2 | p.SERVER_STATUS_LAST_ROW_SENT))
+        return len(batch)
 
-    def _run_sql(self, io: p.PacketIO, sql: str) -> None:
+    def _run_sql(self, io: p.PacketIO, sql: str) -> Optional[int]:
         self.current_sql = sql
         try:
             res = self.session.execute(sql)
         except Exception as e:
             io.write(p.err_packet(1105, str(e)))
-            return
+            return None
         finally:
             self.current_sql = None
         wc = min(len(self.session.warnings), 0xFFFF)
         if not res.columns:
             io.write(p.ok_packet(affected=res.affected, last_insert_id=res.last_insert_id, warnings=wc))
-            return
-        out = [p.lenc_int(len(res.columns))]
-        ftypes = getattr(res, "ftypes", None)
-        for i, name in enumerate(res.columns):
-            if ftypes is not None and i < len(ftypes) and ftypes[i] is not None:
-                tc, ln, dec = p.type_for(ftypes[i])
-            else:
-                tc, ln, dec = p.T_VAR_STRING, 255, 0
-            out.append(p.column_def(str(name), tc, ln, dec))
-        out.append(p.eof_packet())
-        for row in res.rows:
-            rb = bytearray()
-            for v in row:
-                tv = p.text_value(v)
-                rb += b"\xfb" if tv is None else p.lenc_str(tv)
-            out.append(bytes(rb))
-        out.append(p.eof_packet(warnings=wc))
-        for pkt in out:
-            io.write(pkt)
+            return None
+        # the statement's binding ended with Session.execute: its id is handed on
+        with _tracing.region("conn.write", stmt=self.session.stmt_id):
+            out = [p.lenc_int(len(res.columns))]
+            ftypes = getattr(res, "ftypes", None)
+            for i, name in enumerate(res.columns):
+                if ftypes is not None and i < len(ftypes) and ftypes[i] is not None:
+                    tc, ln, dec = p.type_for(ftypes[i])
+                else:
+                    tc, ln, dec = p.T_VAR_STRING, 255, 0
+                out.append(p.column_def(str(name), tc, ln, dec))
+            out.append(p.eof_packet())
+            for row in res.rows:
+                rb = bytearray()
+                for v in row:
+                    tv = p.text_value(v)
+                    rb += b"\xfb" if tv is None else p.lenc_str(tv)
+                out.append(bytes(rb))
+            out.append(p.eof_packet(warnings=wc))
+            for pkt in out:
+                io.write(pkt)
+        return len(res.rows)
 
 
 class Server:

@@ -295,6 +295,7 @@ class Session:
         # (the reading statement already cleared self.warnings)
         self._prev_warnings: list[tuple] = []
         self._stmt_count = 0
+        self.stmt_id: Optional[str] = None  # of the statement `execute` ran last (or is running)
         self.conn_id = 0  # the wire server sets its connection's id
 
     def append_warning(self, level: str, code: int, msg: str) -> None:
@@ -581,11 +582,12 @@ class Session:
         # pool threads included (utils/tracing; CopClient.send hands it on)
         self._stmt_count += 1
         who = f"c{self.conn_id}" if self.conn_id else f"s{id(self):x}"  # an embedded session has no connection
-        prev_bound = _tracing.bind(None, f"{who}.{self._stmt_count}")
+        self.stmt_id = f"{who}.{self._stmt_count}"
+        prev_bound = _tracing.bind(None, self.stmt_id)
         s_span = self.span("statement")
-        s_span.__enter__()
+        s_region = s_span.__enter__()
         try:
-            return self._execute_bound(sql, t0)
+            return self._execute_bound(sql, t0, s_region)
         finally:
             s_span.__exit__(None, None, None)
             _tracing.bind(*prev_bound)
@@ -595,13 +597,15 @@ class Session:
                     self.tracer = None
                 self._deposit_trace(tr, _time.perf_counter() - t0, sql)
 
-    def _execute_bound(self, sql: str, t0: float) -> Result:
-        """``execute`` once the statement's id and root span are in place."""
+    def _execute_bound(self, sql: str, t0: float, s_region) -> Result:
+        """``execute`` once the statement's id and root span (``s_region``;
+        None when nothing records) are in place."""
         import time as _time
 
         from tidb_tpu.utils import metrics as _m
 
         entry: Optional[_CachedStmt] = None
+        how = "session"  # `ast` on the statement's span: which lane gave the text its AST
         cached = self._stmt_cache.get(sql)
         if cached is not None:
             # lease first: a catalog reload here bumps schema_version, which
@@ -626,6 +630,7 @@ class Session:
                 self._db.ensure_schema_lease()
                 if ie.epoch == (self._db.bindings_ver,):
                     _m.INSTANCE_PLAN_CACHE.inc(result="ast_hit")
+                    how = "instance"
                     inst_entry = ie
                     entry = _CachedStmt(ie.stmt, ie.stype, self._stmt_epoch(), ie.exec_sql)
                     entry.digest = ie.digest
@@ -638,12 +643,15 @@ class Session:
         if entry is not None:
             stmt, stype, exec_sql = entry.stmt, entry.stype, entry.exec_sql
         else:
+            how = "parse"
             try:
                 with self.span("parse"):
                     stmt = parse(sql)
             except Exception as exc:
                 # failed parses still reach the audit trail (probing attempts)
                 _m.STMT_TOTAL.inc(type="ParseError")
+                if s_region is not None:
+                    s_region.note(type="ParseError", ast=how)
                 self._audit_stmt(sql, "error", _time.perf_counter() - t0, str(exc))
                 if self._sampled_tracer is not None:
                     # nothing executed — a parse-error trace is noise
@@ -682,6 +690,8 @@ class Session:
         # (previously computed up to three times per statement); the memo
         # writes through to the INSTANCE entry too, so the whole fleet of
         # short-lived sessions sharing one AST computes the digest once
+        if s_region is not None:
+            s_region.note(type=stype, ast=how)
         digest_cache = [entry.digest if entry is not None else None]
 
         def sql_digest() -> str:
@@ -721,49 +731,51 @@ class Session:
             )
         try:
             res = self._execute_stmt(stmt, sql_text=exec_sql)
-            if not self._explicit and self._txn is not None:
-                self._finish_txn(commit=True)
-            dt = _time.perf_counter() - t0
-            _m.STMT_TOTAL.inc(type=stype)
-            _m.QUERY_DURATION.observe(dt)
-            pd = ""
-            if self._last_plan is not None:
-                from tidb_tpu.utils.execdetails import plan_digest as _plan_digest
+            # what the statement pays after its answer is ready (ROADMAP C7)
+            with self.span("stmt.finish"):
+                if not self._explicit and self._txn is not None:
+                    self._finish_txn(commit=True)
+                dt = _time.perf_counter() - t0
+                _m.STMT_TOTAL.inc(type=stype)
+                _m.QUERY_DURATION.observe(dt)
+                pd = ""
+                if self._last_plan is not None:
+                    from tidb_tpu.utils.execdetails import plan_digest as _plan_digest
 
-                # memoized on the plan object — cached plans pay this once
-                pd = _plan_digest(self._last_plan)
-            # workload attribution: fold the statement's sidecars + write
-            # accounting into a measured ResourceUsage → RUs (metering only;
-            # ref: the resource-control RU model + RunawayChecker at
-            # adapter.go:553)
-            gname = str(self.vars.get("tidb_resource_group", "default"))
-            g = self._db.resource_groups.get(gname)
-            usage = self._assemble_usage(
-                dt, (_time.thread_time() - t0_cpu) * 1000.0,
-                len(res.rows) or res.affected,
-            )
-            ru = usage.ru
-            self._db.stmt_summary.record(
-                exec_sql, dt, len(res.rows) or res.affected, f"{self.user}@{self.host}",
-                float(self.vars.get("tidb_slow_log_threshold", 300)) / 1000.0,
-                digest_val=sql_digest(),
-                plan_digest=pd,
-                cop=self.exec_summary,
-                # slow-log → reservoir pivot: the sampled trace's id rides
-                # the structured SlowEntry
-                trace_id=(self._sampled_tracer.trace_id if self._sampled_tracer is not None else ""),
-                mem_max=self._last_mem_peak,
-                ru=ru,
-                resource_group=(g.name if g is not None else gname),
-            )
-            if topsql is not None and ru:
-                topsql.note_ru(sql_digest().split("|")[0], ru)
-            if g is not None:
-                g.consume(ru)
-                self._db.resource_groups.charge(g.name, usage)
-                if g.exec_elapsed_s and dt > g.exec_elapsed_s and not self._runaway_fired:
-                    self._db.resource_groups.record_runaway(g.name, g.action, exec_sql[:256])
-            self._audit_stmt(exec_sql, "ok", dt)
+                    # memoized on the plan object — cached plans pay this once
+                    pd = _plan_digest(self._last_plan)
+                # workload attribution: fold the statement's sidecars + write
+                # accounting into a measured ResourceUsage → RUs (metering only;
+                # ref: the resource-control RU model + RunawayChecker at
+                # adapter.go:553)
+                gname = str(self.vars.get("tidb_resource_group", "default"))
+                g = self._db.resource_groups.get(gname)
+                usage = self._assemble_usage(
+                    dt, (_time.thread_time() - t0_cpu) * 1000.0,
+                    len(res.rows) or res.affected,
+                )
+                ru = usage.ru
+                self._db.stmt_summary.record(
+                    exec_sql, dt, len(res.rows) or res.affected, f"{self.user}@{self.host}",
+                    float(self.vars.get("tidb_slow_log_threshold", 300)) / 1000.0,
+                    digest_val=sql_digest(),
+                    plan_digest=pd,
+                    cop=self.exec_summary,
+                    # slow-log → reservoir pivot: the sampled trace's id rides
+                    # the structured SlowEntry
+                    trace_id=(self._sampled_tracer.trace_id if self._sampled_tracer is not None else ""),
+                    mem_max=self._last_mem_peak,
+                    ru=ru,
+                    resource_group=(g.name if g is not None else gname),
+                )
+                if topsql is not None and ru:
+                    topsql.note_ru(sql_digest().split("|")[0], ru)
+                if g is not None:
+                    g.consume(ru)
+                    self._db.resource_groups.charge(g.name, usage)
+                    if g.exec_elapsed_s and dt > g.exec_elapsed_s and not self._runaway_fired:
+                        self._db.resource_groups.record_runaway(g.name, g.action, exec_sql[:256])
+                self._audit_stmt(exec_sql, "ok", dt)
             return res
         except Exception as exc:
             _m.STMT_TOTAL.inc(type=f"{stype}:error")
@@ -1437,7 +1449,8 @@ class Session:
 
             try:
                 with self.span("execute"):
-                    ex = build_executor(plan, self)
+                    with self.span("executor.build"):
+                        ex = build_executor(plan, self)
                     chunk = ex.execute()
             except MPPRetryExhausted as mpp_err:
                 # MPP gave up (device failures) → re-plan without MPP and run
@@ -1462,8 +1475,10 @@ class Session:
                 try:
                     with self.span("mpp-fallback"):
                         plan = self._plan_select(replan_stmt, cache_key=None)
-                        ex = build_executor(plan, self)
-                        chunk = ex.execute()
+                        with self.span("execute"):
+                            with self.span("executor.build"):
+                                ex = build_executor(plan, self)
+                            chunk = ex.execute()
                 finally:
                     self.vars["tidb_allow_mpp"] = prev
         finally:
@@ -1476,8 +1491,9 @@ class Session:
                 self._last_mem_peak = max(self._last_mem_peak, self.mem_tracker.max_consumed)
             self.mem_tracker = None
         self._last_plan = plan  # outermost select wins (inner selects ran already)
-        names = [oc.name for oc in plan.schema]
-        return Result(columns=names, rows=chunk.rows(), ftypes=[oc.ftype for oc in plan.schema])
+        with self.span("result.rows"):
+            names = [oc.name for oc in plan.schema]
+            return Result(columns=names, rows=chunk.rows(), ftypes=[oc.ftype for oc in plan.schema])
 
     def _resolve_as_of(self, stmt) -> Optional[int]:
         """Collect AS OF TIMESTAMP from the statement's table refs → TSO ts

@@ -413,11 +413,6 @@ PLACEMENT_EPOCH = REGISTRY.gauge(
     "Current placement epoch per table binding (monotone; never regresses)",
     ("table",),
 )
-PLACEMENT_REFRESH = REGISTRY.counter(
-    "tidb_tpu_placement_refresh_total",
-    "Placement map re-resolves (the boRegionMiss re-route signal)",
-    ("outcome",),
-)
 PLACEMENT_REROUTE = REGISTRY.counter(
     "tidb_tpu_placement_reroute_total",
     "Data verbs re-routed to a new owner after a placement epoch change",
@@ -442,16 +437,11 @@ META_CATCHUP = REGISTRY.counter(
     "Returning-replica anti-entropy replays (meta + election + placement)",
 )
 # workload attribution (resourcegroup/groups.py): per-group request units
-# and statement counts — the metering substrate admission control (ROADMAP
-# item 3) will act on. Labeled by resource group so metricshist keeps a
-# per-tenant consumption history.
+# — the metering substrate admission control (ROADMAP item 3) will act on.
+# Labeled by resource group so metricshist keeps a per-tenant consumption
+# history.
 RU_CONSUMED = REGISTRY.counter(
     "tidb_tpu_resource_group_ru_total",
     "Request units consumed per resource group (RRU + WRU, metering only)",
-    ("group",),
-)
-RU_STATEMENTS = REGISTRY.counter(
-    "tidb_tpu_resource_group_statement_total",
-    "Statements attributed per resource group",
     ("group",),
 )
