@@ -263,9 +263,12 @@ def _eqns(jaxpr) -> int:
     return n
 
 
-def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypatch):
+@pytest.mark.parametrize("gate", [None, 1])
+def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypatch, gate):
     """Compile cost must not grow with the region count: the body is traced
-    once, whatever ``m`` is."""
+    once, whatever ``m`` is. The three programs compared take one group-by
+    route (ISSUE 38: it goes by the rows of a call): the equality mask at
+    these rows, the dot with the gate (``_DOT_MIN_ROWS``) lowered to a row."""
     import jax
 
     db, s = served
@@ -282,14 +285,22 @@ def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypat
         (kernel, (slots, _, _)), = [c for c in sent if c[0].m > 1]
         dag, (n_pad, agg_cap), kw = next(b for b in bound if b[2]["m"] == kernel.m)
         sizes = {}
-        for m in (8, 48):
-            k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"], m=m)
-            args = (slots[:1] * m, np.zeros((m, dag_kernel.MAX_RANGES, 2), np.int64), np.zeros(m, np.int64))
-            sizes[m] = _eqns(jax.make_jaxpr(k.fn)(*args).jaxpr)
-        handles, cols = slots[0]
-        cols = tuple((d, d != d if v is None else v) for d, v in cols)  # the single-region program takes every validity
-        single = _eqns(jax.make_jaxpr(dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"]).fn)(
-            cols[0][0] if handles is None else handles, cols, np.zeros((dag_kernel.MAX_RANGES, 2), np.int64), np.int64(0)).jaxpr)
+        routes = set()
+        with monkeypatch.context() as mp:
+            if gate is not None:
+                mp.setattr(dag_kernel, "_DOT_MIN_ROWS", gate)
+            for m in (8, 48):
+                k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"], m=m)
+                args = (slots[:1] * m, np.zeros((m, dag_kernel.MAX_RANGES, 2), np.int64), np.zeros(m, np.int64))
+                sizes[m] = _eqns(jax.make_jaxpr(k.fn)(*args).jaxpr)
+                routes.add(k.route)
+            handles, cols = slots[0]
+            cols = tuple((d, d != d if v is None else v) for d, v in cols)  # the single-region program takes every validity
+            k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"])
+            single = _eqns(jax.make_jaxpr(k.fn)(
+                cols[0][0] if handles is None else handles, cols, np.zeros((dag_kernel.MAX_RANGES, 2), np.int64), np.int64(0)).jaxpr)
+            routes.add(k.route)
+        assert routes == {{"q1": "eqmask" if gate is None else "dot", "q6": None}[shape]}, routes
         assert abs(sizes[48] - sizes[8]) < 0.1 * sizes[8], sizes
         assert sizes[48] < 1.5 * single + 40, (sizes, single)  # the body once, the stacking, the packing: not a body a region
 

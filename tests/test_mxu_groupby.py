@@ -25,12 +25,18 @@ def np_reference(seg, pairs, B):
     return counts, sums
 
 
-def test_dot_exact_vs_oracle():
+@pytest.mark.parametrize("n,chunk", [(70_000, None), (65_536, None), (65_536, 1 << 14)],
+                         ids=["rows_not_blocks_of_128", "rows_in_blocks_of_128", "four_chunks"])
+def test_dot_exact_vs_oracle(n, chunk, monkeypatch):
     import jax
     import jax.numpy as jnp
 
+    from tidb_tpu.ops import mxu_groupby
+
+    if chunk is not None:
+        monkeypatch.setattr(mxu_groupby, "_CHUNK", chunk)
     rng = np.random.default_rng(1)
-    n, B = 70_000, 11
+    B = 11
     seg = jnp.asarray(rng.integers(0, B + 3, n).astype(np.int32))
     specs = [
         (rng.integers(-5000, 9_000_000, n), (-5000, 9_000_000)),

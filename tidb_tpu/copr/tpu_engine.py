@@ -946,9 +946,11 @@ def _run_all(ph, calls: list) -> list:
     and transfer only the live slice (_probe_slice_rows)."""
     import jax
 
-    ph.to("dispatch", kernel=calls[0][0].family, regions=len(calls), pad_slots=sum(kernel.m - len(live) for kernel, _, live in calls if kernel.m > 1))
+    routes = _agg_routes(calls)
+    ph.to("dispatch", kernel=calls[0][0].family, regions=len(calls), pad_slots=sum(kernel.m - len(live) for kernel, _, live in calls if kernel.m > 1),
+          **{"agg_" + r: k for r, k in routes.items()})
     packed = [kernel.fn(*args) for kernel, args, _ in calls]
-    _count_programs(calls)
+    _count_programs(calls, routes)
     ph.to("fetch")
     for i, (kernel, _, _) in enumerate(calls):
         if kernel.kind == "rows" and kernel.out_n > 65536 and kernel.m == 1:
@@ -969,17 +971,34 @@ def _run_all(ph, calls: list) -> list:
     return out
 
 
-def _count_programs(calls: list) -> None:
+def _agg_routes(calls: list) -> dict:
+    """``{route: regions}``: the regions the calls send to programs of each
+    group-by route (``CompiledKernel.route``: dot, eqmask, sort), padding
+    slots not counted; a scalar aggregate's program counts in none. A call
+    with ``live`` None answers one region."""
+    out: dict = {}
+    for kernel, _, live in calls:
+        if kernel.route is not None:
+            out[kernel.route] = out.get(kernel.route, 0) + (1 if live is None else len(live))
+    return out
+
+
+def _count_programs(calls: list, routes: dict) -> None:
     """Program calls a task sent: the sidecar's ``programs`` and the counter,
-    by form (``mapped``: one call, many regions)."""
+    by form (``mapped``: one call, many regions); and ``routes``
+    (``_agg_routes``), the regions they send to each group-by route."""
     mapped = sum(1 for kernel, _, _ in calls if kernel.m > 1)
     det = _ed.current_cop()
     if det is not None:
         det.programs += len(calls)
+        for r, k in routes.items():
+            det.agg_routes[r] = det.agg_routes.get(r, 0) + k
     if mapped:
         _metrics.COP_PROGRAMS.inc(mapped, form="mapped")
     if len(calls) > mapped:
         _metrics.COP_PROGRAMS.inc(len(calls) - mapped, form="single")
+    for r, k in routes.items():
+        _metrics.COP_AGG_ROUTE.inc(k, route=r)
 
 
 def _decode(ph, results: list, dag, cache, scan, warn) -> Chunk:
@@ -1043,8 +1062,10 @@ def _exec_blocks(ph, store, dag, bound, scan, cache, entry, region, rarr, warn=N
                 # unions into exactly the block owning its handle span, so rows
                 # never double-count and block outputs concat in handle order
                 args += (*dinp, _delta_counts(delta.n, dcuts[bi], dcuts[bi + 1]))
-            ph.to("dispatch", kernel=kernel.family)
-            _count_programs([(kernel, args, None)])
+            # the blocks of one region: its route counts once, at the first
+            routes = _agg_routes([(kernel, args, None)]) if bi == 0 else {}
+            ph.to("dispatch", kernel=kernel.family, **{"agg_" + r: k for r, k in routes.items()})
+            _count_programs([(kernel, args, None)], routes)
             return kernel.fn(*args)
 
         if limit_last:

@@ -47,6 +47,18 @@ _I64_MIN = np.iinfo(np.int64).min
 # dense path does B*n work per agg lane; past this many buckets the int8
 # MXU dot (≤ mxu_groupby.MAX_B) or the lex-sort path takes over
 _DENSE_EQMASK_MAX = 32
+# rows one program call reduces from which the dot pays its fixed cost even
+# for a small B (below it, and at most _DENSE_EQMASK_MAX buckets, the eqmask)
+_DOT_MIN_ROWS = 1 << 21
+
+
+def _dot_route_ok(bt: int, rows_a_call: int) -> bool:
+    """Whether a dense group-by of ``bt`` buckets that the dot can take
+    (magnitude proof, B cap) goes to it: past _DENSE_EQMASK_MAX buckets
+    always; at or below, once the rows ONE call reduces amortize the dot's
+    fixed cost. A mapped program reduces every region of the call — ``n_pad
+    * m`` rows, not one region's: 48 regions of 262,144 rows are 12.6M."""
+    return bt > _DENSE_EQMASK_MAX or rows_a_call >= _DOT_MIN_ROWS
 
 
 def _dense_b_total(doms) -> int:
@@ -114,6 +126,10 @@ class CompiledKernel:
     # regions one call answers: above 1 the MAPPED program (``get_kernel``),
     # whose buffers come back stacked ``(m, ...)``, one packed result a region
     m: int = 1
+    # how its grouped aggregation reduces, fixed at build: "dot" (the int8
+    # MXU dot), "eqmask" (the (B, n) equality mask), "sort"; None for a
+    # scalar aggregate or a DAG without one
+    route: str | None = None
 
     @property
     def lane_loc(self):  # per-output ("i"|"f", row index) into packed buffer(s)
@@ -600,14 +616,68 @@ def _build(
             ngroups,
         )
 
-    def _static_dot_route():
-        """Per-block fused routing gate: [scan, selection*, agg-last] DAGs
-        whose agg provably rides the int8 MXU dot can skip the nb-block
-        concatenation (a pure HBM copy of every lane) and accumulate one
-        (B, C) limb matrix per block instead. Mirrors the dynamic routing in
-        the agg branch — same domains, same magnitude proofs."""
+    def _agg_route(ex, pre):
+        """(route, doms) of one aggregation executor, decided from the DAG
+        and the shape alone: "dot" — the int8 MXU dot (ops/mxu_groupby),
+        WITH ROLLUP always; "eqmask" — the (B, n) equality-mask reduce on
+        the VPU; "sort" — the lex sort and segmented reductions; None for a
+        scalar aggregate. ``doms``: the group keys' dictionary domains on
+        the dense routes."""
         from tidb_tpu.expression.expr import ColumnRef as _CR
 
+        from tidb_tpu.ops.mxu_groupby import MAX_B as _DOT_MAX_B
+
+        group_exprs, aggs, _mode = pre
+        if not group_exprs:
+            return None, None
+        if getattr(ex, "rollup", False):
+            return "dot", None
+        # bit aggregates reduce with non-additive ops: only the sort path's
+        # segmented associative scan handles them
+        if any(pk in ("bit_and", "bit_or", "bit_xor") for a in aggs for pk in a.partial_kinds):
+            return "sort", None
+        # dense: every group key is a scan column with a known small domain
+        # (dictionary codes) → the bucket index is pure arithmetic, no
+        # O(n log n) sort; one extra bucket per key holds its NULLs
+        doms = []
+        for g in group_exprs:
+            if not (isinstance(g, _CR) and g.index < len(scan.domains) and scan.domains[g.index] > 0):
+                return "sort", None
+            doms.append(scan.domains[g.index])
+        # equality-mask reduce costs B*n per agg lane on the VPU (in emulated
+        # x64); the int8 dot_general rides the systolic array instead — XLA's
+        # native MXU mode, no row cap (chunked int64 accumulation), no
+        # block-multiple constraint. Route to it whenever the magnitude proof
+        # holds, B fits its materialized (B, n) one-hot, and the call is big
+        # enough to amortize its fixed cost (_dot_route_ok) — even for tiny
+        # B, where the eqmask was the round-2 default. The lex-sort path
+        # covers everything else, B > MAX_B included (a hand-tiled pallas
+        # kernel held 64 < B <= 512 until Mosaic compiled it to WRONG sums on
+        # a v5e under jax 0.9.0, 2026-09-26 — deleted rather than hidden)
+        bt = _dense_b_total(doms)
+        if (
+            bt <= min(agg_cap, _DOT_MAX_B)
+            and _mxu_aggs_ok(aggs, getattr(ex, "arg_bounds", ()))
+            and _dot_route_ok(bt, n_eff * m)
+        ):
+            return "dot", doms
+        if bt <= min(agg_cap, _DENSE_EQMASK_MAX):
+            return "eqmask", doms
+        return "sort", None
+
+    agg_routes = {
+        exi: _agg_route(ex, pre)
+        for exi, (ex, pre) in enumerate(zip(executors[1:], parsed))
+        if ex.tp in (dagpb.AGGREGATION, dagpb.STREAM_AGG)
+    }
+    route = next((r for r, _ in reversed(agg_routes.values())), None)
+
+    def _static_dot_route():
+        """Per-block fused routing gate: [scan, selection*, agg-last] DAGs
+        whose agg rides the int8 MXU dot (the route the agg branch takes,
+        ``agg_routes``) can skip the nb-block concatenation (a pure HBM copy
+        of every lane) and accumulate one (B, C) limb matrix per block
+        instead."""
         from tidb_tpu.ops.mxu_groupby import MAX_B as _DOT_MAX_B
 
         if nb <= 1 or not agg_is_last or D:
@@ -616,30 +686,20 @@ def _build(
             return None
         if any(ex.tp != dagpb.SELECTION for ex in executors[1:-1]):
             return None
-        group_exprs, aggs, _mode = parsed[-1]
-        if not group_exprs:
-            return None
-        if any(pk in ("bit_and", "bit_or", "bit_xor") for a in aggs for pk in a.partial_kinds):
-            return None
-        doms = []
-        for g in group_exprs:
-            if isinstance(g, _CR) and g.index < len(scan.domains) and scan.domains[g.index] > 0:
-                doms.append(scan.domains[g.index])
-            else:
-                return None
-        if not _mxu_aggs_ok(aggs, getattr(executors[-1], "arg_bounds", ())):
+        kind, doms = agg_routes[len(executors) - 2]
+        if kind != "dot":
             return None
         if getattr(executors[-1], "rollup", False):
             # rollup bucket space = sum over prefix-set windows
+            group_exprs, aggs, _mode = parsed[-1]
             layout = _rollup_layout(executors[-1], group_exprs)
-            if layout is None or layout["B_total"] > min(agg_cap, _DOT_MAX_B):
+            if (
+                layout is None
+                or layout["B_total"] > min(agg_cap, _DOT_MAX_B)
+                or not _mxu_aggs_ok(aggs, getattr(executors[-1], "arg_bounds", ()))
+            ):
                 return None
-            return ("rollup", doms)
-        bt = _dense_b_total(doms)
-        if bt > min(agg_cap, _DOT_MAX_B):
-            return None
-        if not (bt > _DENSE_EQMASK_MAX or n_total >= (1 << 21)):
-            return None
+            return ("rollup", None)
         return ("plain", doms)
 
     blockwise_doms = _static_dot_route()
@@ -858,50 +918,12 @@ def _build(
                     mask = mask & keep
             elif ex.tp in (dagpb.AGGREGATION, dagpb.STREAM_AGG):
                 group_exprs, aggs, mode = pre
-                # dense fast path: every group key is a scan column with a
-                # known small domain (dictionary codes) → bucket index is
-                # pure arithmetic, no O(n log n) sort. One extra bucket per
-                # key holds its NULLs.
-                dense_doms = None
-                mxu_doms = None
-                # bit aggregates reduce with non-additive ops: only the sort
-                # path's segmented associative scan handles them
+                agg_route, route_doms = agg_routes[exi]
+                mxu_doms = route_doms if agg_route == "dot" else None
+                dense_doms = route_doms if agg_route == "eqmask" else None
                 has_bit = any(
                     pk in ("bit_and", "bit_or", "bit_xor") for a in aggs for pk in a.partial_kinds
                 )
-                if group_exprs and not has_bit:
-                    doms = []
-                    for g in group_exprs:
-                        from tidb_tpu.expression.expr import ColumnRef as _CR
-
-                        if isinstance(g, _CR) and g.index < len(scan.domains) and scan.domains[g.index] > 0:
-                            doms.append(scan.domains[g.index])
-                        else:
-                            doms = None
-                            break
-                    # equality-mask reduce costs B*n per agg lane on the VPU
-                    # (in emulated x64); the int8 dot_general rides the
-                    # systolic array instead — XLA's native MXU mode, no row
-                    # cap (chunked int64 accumulation), no block-multiple
-                    # constraint. Route to it whenever the magnitude proof
-                    # holds, B fits its materialized (B, n) one-hot, and the
-                    # batch is big enough to amortize its fixed cost — even
-                    # for tiny B, where the eqmask was the round-2 default.
-                    # The lex-sort path covers everything else, B > MAX_B
-                    # included (a hand-tiled pallas kernel held 64 < B <= 512
-                    # until Mosaic compiled it to WRONG sums on a v5e under
-                    # jax 0.9.0, 2026-09-26 — deleted rather than hidden)
-                    if doms:
-                        from tidb_tpu.ops.mxu_groupby import MAX_B as _DOT_MAX_B
-
-                        bt = _dense_b_total(doms)
-                        dot_fits = bt <= min(agg_cap, _DOT_MAX_B) and _mxu_aggs_ok(
-                            aggs, getattr(ex, "arg_bounds", ())
-                        )
-                        if dot_fits and (bt > _DENSE_EQMASK_MAX or n >= (1 << 21)):
-                            mxu_doms = doms
-                        elif bt <= min(agg_cap, _DENSE_EQMASK_MAX):
-                            dense_doms = doms
 
                 gnar = getattr(ex, "group_narrow", [])
                 gvals = []
@@ -1494,7 +1516,9 @@ def _build(
     elif m > 1:
         def one_region(operands):
             handles, cols, ranges, nvalid = operands
-            cols = tuple((d, jnp.ones(n_pad, dtype=bool) if v is None else v) for d, v in cols)
+            # a lane stacked without its validity holds no NULL: it stays None
+            # (EvalBatch: all valid), so the expressions over it carry no
+            # validity either and share one weight column in the MXU dot
             with _Stages() as stage:
                 return _kernel_body(handles, cols, ranges, nvalid, None, stage)
 
@@ -1517,7 +1541,7 @@ def _build(
     family = kernel_family(dag, nb, D, m)
     kernel.__name__ = kernel.__qualname__ = family  # the XLA module is jit_<family>
     jitted = jax.jit(kernel)
-    return CompiledKernel(jitted, "agg" if agg_is_last else "rows", out_n, agg_cap, lanes_holder, family, m)
+    return CompiledKernel(jitted, "agg" if agg_is_last else "rows", out_n, agg_cap, lanes_holder, family, m, route)
 
 
 def _hier_top_k(jax, jnp, vals, K: int):

@@ -58,6 +58,7 @@ class CopExecDetails:
         "dev_cache_misses", "engine", "degraded", "retries", "backoff_ms",
         "resplits", "delta_rows", "delta_read", "merges", "keys_scanned", "bytes_scanned",
         "bind_ms", "inputs_ms", "dispatch_ms", "fetch_ms", "decode_ms", "regions", "programs", "resolved",
+        "agg_routes",
     )
 
     def __init__(self, region_id: int = -1, store: str = ""):
@@ -73,6 +74,9 @@ class CopExecDetails:
         # program calls were sent again, "miss" = none kept, "stale" = one kept that
         # no longer held; "" = not a batch task
         self.resolved = ""
+        # regions its program calls reduced by group-by route (dag_kernel's
+        # CompiledKernel.route: dot / eqmask / sort), padding slots not counted
+        self.agg_routes: dict[str, int] = {}
         self.store = store  # "" = embedded (local) store
         self.queue_ms = 0.0  # send-queue wait before a worker picked it up
         self.wire_ms = 0.0  # RPC wall minus store-side processing (remote)
@@ -146,6 +150,8 @@ class CopExecDetails:
             out["pg"] = self.programs
         if self.resolved:
             out["rv"] = self.resolved
+        if self.agg_routes:
+            out["ar"] = dict(self.agg_routes)
         for key, attr in _PHASE_PB:
             v = getattr(self, attr)
             if v:
@@ -178,6 +184,8 @@ class CopExecDetails:
         self.programs += int(pb.get("pg", 0))
         if pb.get("rv"):
             self.resolved = pb["rv"]
+        for r, k in pb.get("ar", {}).items():
+            self.agg_routes[r] = self.agg_routes.get(r, 0) + int(k)
         for key, attr in _PHASE_PB:
             if key in pb:
                 setattr(self, attr, getattr(self, attr) + float(pb[key]))
@@ -198,6 +206,7 @@ class CopTasksSummary:
         "engines", "degraded", "retries", "backoff_ms", "resplits",
         "delta_rows", "delta_read", "merges", "keys_scanned", "bytes_scanned",
         "max_proc_ms", "max_task_store", "max_task_region", "phases_ms", "regions", "programs", "resolved",
+        "agg_routes",
     )
 
     def __init__(self):
@@ -228,6 +237,7 @@ class CopTasksSummary:
         self.regions = 0  # regions the tasks served: above ``num`` where tasks were batches
         self.programs = 0  # device program calls the tasks sent: below ``regions`` where a batch mapped them
         self.resolved: dict[str, int] = {}  # batch tasks by how they met their resolved task (hit / miss / stale)
+        self.agg_routes: dict[str, int] = {}  # regions reduced by group-by route (dot / eqmask / sort)
 
     @property
     def num(self) -> int:
@@ -260,6 +270,8 @@ class CopTasksSummary:
         self.programs += d.programs
         if d.resolved:
             self.resolved[d.resolved] = self.resolved.get(d.resolved, 0) + 1
+        for r, k in d.agg_routes.items():
+            self.agg_routes[r] = self.agg_routes.get(r, 0) + k
         for i, (_key, attr) in enumerate(_PHASE_PB):
             self.phases_ms[i] += getattr(d, attr)
         if d.proc_ms >= self.max_proc_ms:
@@ -291,6 +303,9 @@ class CopTasksSummary:
         if self.resolved:
             # batch tasks: "hit" = the program calls kept from the statement before were sent as they were
             parts.append("resolved: " + " ".join(k if v == 1 else f"{k}×{v}" for k, v in sorted(self.resolved.items())))
+        if self.agg_routes:
+            # regions by the route their group-by took: the int8 MXU dot, the equality mask, the sort
+            parts.append("agg_route: " + " ".join(f"{k}×{v}" for k, v in sorted(self.agg_routes.items())))
         if self.queue_ms:
             parts.append(f"queue: {self.queue_ms / n:.1f}ms")  # avg send-queue wait
         if self.wire_ms:

@@ -241,6 +241,11 @@ COP_PROGRAMS = REGISTRY.counter(
     "Device program calls sent by cop tasks: mapped = one call answering many regions of a batch task, single = one region",
     ("form",),
 )
+COP_AGG_ROUTE = REGISTRY.counter(
+    "tidb_tpu_cop_agg_route_total",
+    "Regions cop program calls reduced by group-by route: dot = the int8 MXU dot, eqmask = the equality-mask reduce, sort = the lex sort (padding slots and scalar aggregates not counted)",
+    ("route",),
+)
 COP_TASK_RESOLVED = REGISTRY.counter(
     "tidb_tpu_cop_task_resolved_total",
     "Batch cop tasks by how they met their resolved task: hit = the kept program calls re-sent, miss = none kept, stale = one kept that no longer held",
