@@ -18,7 +18,7 @@ Anything else stays on the host Volcano path.
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -69,6 +69,18 @@ _MPP_DEV_CACHE: dict = {}
 # sessions insert outside _MESH_EXEC_LOCK — iteration-during-insert raises
 # RuntimeError. Never held across a compile or an upload.
 _MPP_CACHE_MU = _threading.Lock()
+
+
+def _pool_bytes() -> int:
+    """Device bytes of the lanes ``_MPP_DEV_CACHE`` holds, each array once:
+    pools (per column data + validity, and the live lane) and whole readers."""
+    held = {}
+    for ent in list(_MPP_DEV_CACHE.values()):
+        lanes = [ent["live"], *(a for col in list(ent["cols"].values()) for a in col[:2])] if isinstance(ent, dict) else ent[0]
+        for a in lanes:
+            held[id(a)] = int(a.nbytes)
+    return sum(held.values())
+
 
 # per-shard straggler observation channel: the fragment program's shard
 # probes (mpp.build_dist_pipeline shard_probe) report back through this ONE
@@ -1470,6 +1482,85 @@ def _stage_parts_of(sub: SubplanReader):
 # ---------------------------------------------------------------------------
 
 
+class _Parts(NamedTuple):
+    """A plain reader's rows as the column cache holds them: ``n`` rows,
+    and per column its (data, validity) arrays, a region's each, in row order."""
+
+    n: int
+    cols: list
+
+
+def _dealt_lanes(chunk, ndev: int):
+    """A side's input lanes, padded and dealt over ``ndev`` shards, made a
+    column at a time straight from its parts: ``chunk`` is a Chunk or
+    :class:`_Parts` (a region's cached arrays a part, never joined first).
+    Power-of-two per-shard padding (masked validity): input SHAPES bucket, so
+    same-shape queries at nearby sizes — and grow-and-retry attempts — trace
+    and compile ONE program. The rows are dealt evenly, in order: shard s
+    holds rows [s*q, (s+1)*q) and then its padding, so no shard computes on
+    padding alone while another is full (12.0M rows padded to 4 x 4.19M laid
+    end to end left the fourth shard empty).
+
+    Returns (rows, rows each shard holds, the live lane, ``columns``): a
+    column's entry makes its (data, 0 in NULL and padding slots; validity;
+    its value bounds, widened, None for a float lane; what each shard holds
+    of it, (lows, highs, in order), None for a float lane or on one shard)
+    when called, so that a caller puts it on the device and lets go of it
+    before the next is made: at SF10 a `lineitem` column is 537 MB padded,
+    where the chunk joined, dealt and masked held three copies."""
+    from tidb_tpu.ops.window_core import widen_bounds
+
+    if not isinstance(chunk, _Parts):
+        n = len(chunk)
+        chunk = _Parts(n, [[(c.data[:n], c.validity[:n])] for c in chunk.columns])
+    n = chunk.n
+    q = (n + ndev - 1) // ndev
+    per = _pow2(max(q, 8))
+    cuts = [(min(sh * q, n), min((sh + 1) * q, n)) for sh in range(ndev)]
+
+    def deal(arrays, dtype):
+        out = np.zeros(per * ndev, dtype=dtype)
+        at = 0  # the row number of the array's first row
+        for a in arrays:
+            for sh, (lo, hi) in enumerate(cuts):
+                a_lo, a_hi = max(lo, at), min(hi, at + len(a))
+                if a_lo < a_hi:
+                    out[sh * per + a_lo - lo : sh * per + a_hi - lo] = a[a_lo - at : a_hi - at]
+            at += len(a)
+        return out
+
+    def column(i):
+        parts = chunk.cols[i]
+        kind = np.result_type(*(d.dtype for d, _ in parts))  # as joining them would
+        valid = deal((v for _, v in parts), bool)
+        data = deal((d if v.all() else np.where(v, d, 0) for d, v in parts), np.result_type(kind, 0))
+        if np.issubdtype(kind, np.floating):
+            return data, valid, None, None
+        # per-column value bounds power the packed narrow-lane sorts in the
+        # fragment program (mpp._pack_keys)
+        lows, highs = [], []
+        for d, v in parts:
+            live = d if v.all() else d[v]
+            if live.size:
+                lows.append(int(live.min()))
+                highs.append(int(live.max()))
+        bounds = widen_bounds([(min(lows), max(highs)) if lows else (0, 0)])[0]
+        if ndev == 1:
+            return data, valid, bounds, None  # one shard: nothing is ever placed by them
+        lows, highs, ordered = [], [], True
+        for sh, (lo, hi) in enumerate(cuts):
+            part, ok = data[sh * per : sh * per + hi - lo], valid[sh * per : sh * per + hi - lo]
+            if not ok.all():
+                part, ordered = part[ok], False  # a NULL's slot holds 0
+            lows.append(int(part.min()) if part.size else 0)
+            highs.append(int(part.max()) if part.size else -1)
+            ordered = ordered and bool((part[1:] >= part[:-1]).all())
+        return data, valid, bounds, (lows, highs, ordered)
+
+    columns = [lambda i=i: column(i) for i in range(len(chunk.cols))]
+    return n, [hi - lo for lo, hi in cuts], deal([np.ones(n, dtype=bool)], bool), columns
+
+
 class MPPGatherExec:
     """Materialize shard inputs, jit the fragment pipeline over the mesh,
     merge the replicated partials (or gathered heads) into the result chunk."""
@@ -1485,11 +1576,13 @@ class MPPGatherExec:
         session read ts. Pre-aggregated readers (agg pushed below the join)
         execute AS-IS through the coprocessor — scan, selection, and the
         partial agg all run on the reader's engine (device block path) and
-        only the collapsed rows reach the exchange. Plain readers assemble
-        columns STRAIGHT from the columnar cache (ref: the in-fragment
-        tableScan, cophandler/mpp_exec.go:136) — no Volcano tree, no
-        per-region chunk copies, no dictionary re-encoding; their conditions
-        evaluate inside the fragment program on device."""
+        only the collapsed rows reach the exchange; they, subplan build
+        sides and readers under a dirty transaction come back as a Chunk.
+        Plain readers hand over the columnar cache's own arrays, region by
+        region (a :class:`_Parts`; ref: the in-fragment tableScan,
+        cophandler/mpp_exec.go:136) — no Volcano tree, no joined copy, no
+        dictionary re-encoding; their conditions evaluate inside the
+        fragment program on device."""
         import numpy as np
 
         from tidb_tpu.executor.executors import TableReaderExec
@@ -1543,7 +1636,6 @@ class MPPGatherExec:
         from tidb_tpu.copr.colcache import cache_for
         from tidb_tpu.kv import tablecodec
         from tidb_tpu.kv.rowcodec import RowSchema
-        from tidb_tpu.utils.chunk import Chunk, Column
 
         store = self.session.store
         cache = cache_for(store)
@@ -1566,27 +1658,12 @@ class MPPGatherExec:
                         for s in want
                     ]
                 )
-        cols = []
-        for ci, oc in enumerate(reader.schema):
-            if len(parts) == 1:
-                data, valid = parts[0][ci]
-            elif parts:
-                data = np.concatenate([p[ci][0] for p in parts])
-                valid = np.concatenate([p[ci][1] for p in parts])
-            else:
-                dt = (
-                    np.float64
-                    if oc.ftype.kind == TypeKind.FLOAT
-                    else (np.int32 if oc.ftype.kind == TypeKind.STRING else np.int64)
-                )
-                data, valid = np.zeros(0, dt), np.zeros(0, bool)
-            dic = (
-                cache.dictionary(t.id, oc.slot)
-                if oc.ftype.kind == TypeKind.STRING and oc.slot >= 0
-                else None
-            )
-            cols.append(Column(data, valid, oc.ftype, dic))
-        return Chunk(cols)
+        if not parts:
+            kinds = {TypeKind.FLOAT: np.float64, TypeKind.STRING: np.int32}
+            parts = [[(np.zeros(0, kinds.get(oc.ftype.kind, np.int64)), np.zeros(0, bool)) for oc in reader.schema]]
+        # the regions' arrays as they are, column by column: _dealt_lanes
+        # copies each once, into its padded lane
+        return _Parts(sum(len(p[0][1]) for p in parts), [list(col) for col in zip(*parts)])
 
     def _bind_conditions(self, reader: PhysTableReader) -> list[Expression]:
         """String constants → dictionary codes (device legalization)."""
@@ -1902,6 +1979,7 @@ class MPPGatherExec:
             keeps_rows,
             probe_paths,
         )
+        from tidb_tpu.utils import metrics as _met
 
         p = self.plan
         ndev = mesh.devices.size
@@ -1952,58 +2030,6 @@ class MPPGatherExec:
                         _cache.unify_dictionaries(ta, sa, tb, sb)
         conds, operands = _lift_literals([self._bind_conditions(r) for r in p.readers])
         agg = p.agg
-
-        def pad_side(chunk):
-            from tidb_tpu.ops.window_core import widen_bounds
-
-            n = len(chunk)
-            # power-of-two per-shard padding (masked validity): input SHAPES
-            # bucket, so same-shape queries at nearby sizes — and grow-and-
-            # retry attempts — trace and compile ONE program. The rows are
-            # dealt evenly, in order: shard s holds rows [s*q, (s+1)*q) and
-            # then its padding, so no shard computes on padding alone while
-            # another is full (12.0M rows padded to 4 x 4.19M laid end to end
-            # left the fourth shard empty)
-            q = (n + ndev - 1) // ndev
-            per = _pow2(max(q, 8))
-            tot = per * ndev
-            cuts = [(min(sh * q, n), min((sh + 1) * q, n)) for sh in range(ndev)]
-
-            def deal(a):
-                out = np.zeros(tot, dtype=a.dtype)
-                for sh, (lo, hi) in enumerate(cuts):
-                    out[sh * per : sh * per + hi - lo] = a[lo:hi]
-                return out
-
-            arrays = []
-            bounds = []
-            by_shard = []  # per column: what each shard holds of it, (lows, highs, in order) — None for a float lane
-            for c in chunk.columns:
-                v = deal(c.validity[:n])
-                arrays.append(np.where(v, deal(c.data[:n]), 0))
-                arrays.append(v)
-                # per-column value bounds power the packed narrow-lane sorts
-                # in the fragment program (mpp._pack_keys)
-                if np.issubdtype(c.data.dtype, np.floating):
-                    bounds.append(None)
-                    by_shard.append(None)
-                    continue
-                lv = c.data[: n][c.validity[: n]]
-                bounds.append((int(lv.min()), int(lv.max())) if lv.size else (0, 0))
-                if ndev == 1:
-                    by_shard.append(None)  # one shard: nothing is ever placed by them
-                    continue
-                lows, highs, ordered = [], [], True
-                for lo, hi in cuts:
-                    part, ok = c.data[lo:hi], c.validity[lo:hi]
-                    if not ok.all():
-                        part, ordered = part[ok], False  # a NULL's slot holds 0
-                    lows.append(int(part.min()) if part.size else 0)
-                    highs.append(int(part.max()) if part.size else -1)
-                    ordered = ordered and bool((part[1:] >= part[:-1]).all())
-                by_shard.append((lows, highs, ordered))
-            arrays.append(deal(np.ones(n, dtype=bool)))
-            return arrays, n, widen_bounds(bounds), {"rows": [hi - lo for lo, hi in cuts], "cols": by_shard}
 
         def pooled(pool, want):
             lanes = [x for s in want for x in pool["cols"][s][:2]]
@@ -2092,29 +2118,35 @@ class MPPGatherExec:
                 want = [oc.slot for oc in reader.schema]
                 if pool is not None and all(s in pool["cols"] for s in want):
                     return pooled(pool, want)
-            arrays, n, bounds, held = pad_side(self._reader_arrays(reader))
+            n, rows, live, columns = _dealt_lanes(self._reader_arrays(reader), ndev)
             if ckey is not None:
                 if pool is None:
-                    pool = {"n": n, "live": put(arrays[-1]), "cols": {}, "rows": held["rows"]}
+                    pool = {"n": n, "live": put(live), "cols": {}, "rows": rows}
                     with _MPP_CACHE_MU:
                         # a racing gather may have installed the pool first:
                         # adopt the winner so both share one resident copy
                         pool = _MPP_DEV_CACHE.setdefault(ckey, pool)
                 for i, s in enumerate(want):
-                    ent = pool["cols"].get(s)
-                    if ent is None:
-                        # upload ONLY the columns the pool lacks — the
-                        # overlap with earlier queries stays resident
-                        ent = (put(arrays[2 * i]), put(arrays[2 * i + 1]), bounds[i], held["cols"][i])
-                        pool["cols"][s] = ent
+                    if s not in pool["cols"]:
+                        # make and upload ONLY the columns the pool lacks —
+                        # the overlap with earlier queries stays resident
+                        data, valid, bounds, held = columns[i]()
+                        pool["cols"][s] = (put(data), put(valid), bounds, held)
                 dev = pooled(pool, want)
             else:
-                dev = ([put(a) for a in arrays], n, bounds, held)
+                lanes, bounds, held = [], [], []
+                for make in columns:
+                    data, valid, b, h = make()
+                    lanes += [put(data), put(valid)]
+                    bounds.append(b)
+                    held.append(h)
+                dev = (lanes + [put(live)], n, bounds, {"rows": rows, "cols": held})
             with _MPP_CACHE_MU:
                 if key is not None:
                     _MPP_DEV_CACHE[key] = dev
                 while len(_MPP_DEV_CACHE) > 32:
                     _MPP_DEV_CACHE.pop(next(iter(_MPP_DEV_CACHE)))
+                _met.MPP_LANE_BYTES.set(_pool_bytes())
             return dev
 
         # A STAGED reader materializes its stage readers' RAW lanes (per-
@@ -2131,6 +2163,7 @@ class MPPGatherExec:
         ph.note(
             rows_valid=sum(n for _, n, _, _ in flat_sides),
             rows_padded=sum(int(lanes[-1].shape[0]) for lanes, _, _, _ in flat_sides),
+            lane_bytes=sum(int(a.nbytes) for lanes, _, _, _ in flat_sides for a in lanes),
             h2d=h2d[0],
             cache="miss" if h2d[0] else "hit",
             ndev=ndev,
@@ -2635,8 +2668,6 @@ class MPPGatherExec:
                 # the cached executable is compiled for these very shapes
                 tuple((a.shape, str(a.dtype)) for a in all_lanes),
             )
-            from tidb_tpu.utils import metrics as _met
-
             cached = _MPP_FN_CACHE.get(fn_key)
             if cached is None:
                 _met.MPP_PROGRAM_CACHE.inc(result="miss")
@@ -2668,7 +2699,7 @@ class MPPGatherExec:
                 # what the collectives move between chips a run, by kind, as COMPILED
                 # (lanes nothing reads are gone from it)
                 xchg_bytes = compiled_exchange_bytes(fn.as_text(), ndev) if ndev > 1 else {}
-                ph.note(cache="miss", compile_us=int((_t.perf_counter() - t_build) * 1e6), exchange=exchanges.replace(",", "+"))
+                ph.note(cache="miss", compile_us=int((_t.perf_counter() - t_build) * 1e6), exchange=exchanges.replace(",", "+"), group_cap=group_cap)
                 # the sink is baked into the compiled program's closures: a
                 # cache hit must attribute warn counts via the ORIGINAL sink
                 with _MPP_CACHE_MU:
@@ -2677,7 +2708,7 @@ class MPPGatherExec:
                         _MPP_FN_CACHE.pop(next(iter(_MPP_FN_CACHE)))
             else:
                 _met.MPP_PROGRAM_CACHE.inc(result="hit")
-                ph.note(cache="hit", exchange=exchanges.replace(",", "+"))
+                ph.note(cache="hit", exchange=exchanges.replace(",", "+"), group_cap=group_cap)
                 fn, warn_sink, xchg_bytes = cached
             ph.to("dispatch", label=f"mpp-pipeline[{ndev}dev]", kernel=family)
             with _MESH_EXEC_LOCK:
@@ -2770,7 +2801,8 @@ class MPPGatherExec:
     def _initial_group_cap(self, n_left_rows: int) -> int:
         """Static per-shard group capacity: NDV-product estimate with a
         ×2 margin when ANALYZE stats exist, else a conservative bound on the
-        probe row count. Undersizing is safe — overflow is detected and the
+        probe row count; either at most :func:`first_group_slots` of the
+        probe's rows. Undersizing is safe — overflow is detected and the
         coordinator retries bigger."""
         keys = list(self.plan.agg.group_by)
         # the distinct argument multiplies the stage-1 (g, x) slot count
@@ -2792,9 +2824,10 @@ class MPPGatherExec:
                 if cs is not None:
                     ndv, have = cs.ndv, True
             est *= ndv if ndv else 64
+        top = first_group_slots(n_left_rows)
         if have:
-            return max(_pow2(min(2 * est, 1 << 16)), 64)
-        return max(_pow2(min(n_left_rows + 1, 1 << 16)), 256)
+            return max(_pow2(min(2 * est, top)), 64)
+        return max(_pow2(min(n_left_rows + 1, top)), 256)
 
     def _rows_chunk(self, arrs):
         """Gathered TopN/limit head lanes → rows chunk (live-filtered); the
@@ -2871,6 +2904,22 @@ class MPPGatherExec:
             cols.append(Column(kdata.astype(dt), kvalid, g.ftype, dic))
         chunk = Chunk(cols)
         return merge_partials(chunk, agg.aggs, len(agg.group_by))
+
+
+# probe rows a group slot of a gather's first program may stand for, past the
+# floor of 2^16 slots: twice the density of Q3's groups (one in ~520 probe
+# rows at SF2 and at SF10), so that its ~115k groups at SF10 fit the first
+# program's 2^18 slots where a fixed 2^16 overflowed into a second program
+# every statement. Slots are not free: finding each group's rows searches the
+# probe's group numbers a slot (2^18 slots over 2^26 rows: ~133 ms of a 675 ms
+# program on one v5e)
+GROUP_SLOT_ROWS = 256
+
+
+def first_group_slots(probe_rows: int) -> int:
+    """The most group slots a gather's first program is built with before
+    any overflow (ANALYZE's estimate or the probe's rows decide below it)."""
+    return max(1 << 16, probe_rows // GROUP_SLOT_ROWS)
 
 
 def _pow2(n: int) -> int:

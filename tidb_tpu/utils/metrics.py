@@ -377,6 +377,13 @@ MPP_HYBRID = REGISTRY.counter(
     "tidb_tpu_mpp_hybrid_total",
     "MPP gathers executed on the hybrid shards-x-devices path",
 )
+# device bytes of the input lanes the MPP gathers keep resident between
+# statements (parallel/gather._MPP_DEV_CACHE: per-column pools and whole
+# readers, each array once, every chip of a mesh summed)
+MPP_LANE_BYTES = REGISTRY.gauge(
+    "tidb_tpu_mpp_lane_bytes",
+    "Device bytes resident in the MPP gathers' input lane pool",
+)
 # bytes of INTERMEDIATE fragment results that crossed the host boundary
 # (a subplan build side materialized through the Volcano executor and
 # re-uploaded) — the staged on-mesh pipeline exists to keep this at ZERO;
