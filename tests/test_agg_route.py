@@ -69,7 +69,7 @@ def fresh(served):
 
 
 def _capture(db, s, text, monkeypatch):
-    """(bound DAG, agg_cap, full_scan, one region's call slot) of the mapped call the statement sends."""
+    """(bound DAG, agg_cap, full_scan, the stacked lanes) of the mapped call the statement sends."""
     sent, bound = [], []
     real_args, real_get = tpu_engine._call_args, tpu_engine.get_kernel
     monkeypatch.setattr(tpu_engine, "_call_args", lambda kernel, *a: sent.append((kernel, real_args(kernel, *a))) or sent[-1][1])
@@ -77,9 +77,9 @@ def _capture(db, s, text, monkeypatch):
     _forget(db)
     s.query(text)
     monkeypatch.undo()
-    (kernel, (slots, _, _)), = [c for c in sent if c[0].m > 1]
+    (kernel, (lanes, _, _)), = [c for c in sent if c[0].m > 1]
     dag, (_, agg_cap), kw = next(b for b in bound if b[2]["m"] == kernel.m)
-    return dag, agg_cap, kw["full_scan"], slots[0]
+    return dag, agg_cap, kw["full_scan"], lanes
 
 
 def _primitives(jaxpr, out=None) -> set:
@@ -91,15 +91,15 @@ def _primitives(jaxpr, out=None) -> set:
     return out
 
 
-def _program(dag, agg_cap, full_scan, slot, n_pad, m):
+def _program(dag, agg_cap, full_scan, lanes, n_pad, m):
     """(kernel, the primitives of its jaxpr) at ``n_pad`` rows a region and
     ``m`` regions a call: traced on shapes alone, nothing compiled or run."""
-    spec = lambda a: None if a is None else jax.ShapeDtypeStruct((n_pad,), a.dtype)  # noqa: E731
-    handles, cols = slot
+    spec = lambda a, *lead: None if a is None else jax.ShapeDtypeStruct((*lead, n_pad), a.dtype)  # noqa: E731
+    handles, cols = lanes
     k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=full_scan, m=m)
     if m > 1:
-        one = (spec(handles), tuple((spec(d), spec(v)) for d, v in cols))
-        args = ((one,) * m, jax.ShapeDtypeStruct((m, dag_kernel.MAX_RANGES, 2), np.int64), jax.ShapeDtypeStruct((m,), np.int64))
+        stacked = (spec(handles, m), tuple((spec(d, m), spec(v, m)) for d, v in cols))
+        args = (stacked, jax.ShapeDtypeStruct((m, dag_kernel.MAX_RANGES, 2), np.int64), jax.ShapeDtypeStruct((m,), np.int64))
     else:
         lanes = tuple((spec(d), jax.ShapeDtypeStruct((n_pad,), np.bool_)) for d, _ in cols)
         args = (spec(handles) or lanes[0][0], lanes, jax.ShapeDtypeStruct((dag_kernel.MAX_RANGES, 2), np.int64), jax.ShapeDtypeStruct((), np.int64))
@@ -121,16 +121,16 @@ def _program(dag, agg_cap, full_scan, slot, n_pad, m):
 ])
 def test_q1_takes_the_dot_by_rows_a_call(served, monkeypatch, n_pad, m, route):
     db, s = served
-    dag, agg_cap, full_scan, slot = _capture(db, s, Q1_TEXT, monkeypatch)
-    k, prims = _program(dag, agg_cap, full_scan, slot, n_pad, m)
+    dag, agg_cap, full_scan, lanes = _capture(db, s, Q1_TEXT, monkeypatch)
+    k, prims = _program(dag, agg_cap, full_scan, lanes, n_pad, m)
     assert k.route == route == ("dot" if n_pad * m >= dag_kernel._DOT_MIN_ROWS else "eqmask")
     assert ("dot_general" in prims) == (route == "dot"), sorted(prims)
 
 
 def test_a_scalar_aggregate_has_no_route(served, monkeypatch):
     db, s = served
-    dag, agg_cap, full_scan, slot = _capture(db, s, SCALAR, monkeypatch)
-    k, prims = _program(dag, agg_cap, full_scan, slot, 262144, 48)
+    dag, agg_cap, full_scan, lanes = _capture(db, s, SCALAR, monkeypatch)
+    k, prims = _program(dag, agg_cap, full_scan, lanes, 262144, 48)
     assert k.route is None and "dot_general" not in prims
 
 
@@ -154,7 +154,7 @@ def test_mapped_dot_partials_equal_each_regions_own_and_the_hosts(fresh, monkeyp
     regions = list(db.store.pd.regions_in_ranges(req.ranges))
     calls = []
     real = tpu_engine._call_args
-    monkeypatch.setattr(tpu_engine, "_call_args", lambda kernel, regs, key: calls.append((kernel, len(regs))) or real(kernel, regs, key))
+    monkeypatch.setattr(tpu_engine, "_call_args", lambda kernel, regs, *a: calls.append((kernel, len(regs))) or real(kernel, regs, *a))
     _forget(db)  # resolved anew: through _exec_single and _call_args
     (res,) = list(CopClient(db.store).send(req))
     # ten regions of one kernel key: one call of 16 slots, six of them padding (nvalid 0)

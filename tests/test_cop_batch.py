@@ -282,7 +282,7 @@ def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypat
         del sent[:], bound[:]
         _forget(db)
         s.query(SHAPES[shape])
-        (kernel, (slots, _, _)), = [c for c in sent if c[0].m > 1]
+        (kernel, (lanes, _, _)), = [c for c in sent if c[0].m > 1]
         dag, (n_pad, agg_cap), kw = next(b for b in bound if b[2]["m"] == kernel.m)
         sizes = {}
         routes = set()
@@ -291,10 +291,11 @@ def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypat
                 mp.setattr(dag_kernel, "_DOT_MIN_ROWS", gate)
             for m in (8, 48):
                 k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"], m=m)
-                args = (slots[:1] * m, np.zeros((m, dag_kernel.MAX_RANGES, 2), np.int64), np.zeros(m, np.int64))
+                stacked = jax.tree.map(lambda a: jax.ShapeDtypeStruct((m, n_pad), a.dtype), lanes)
+                args = (stacked, np.zeros((m, dag_kernel.MAX_RANGES, 2), np.int64), np.zeros(m, np.int64))
                 sizes[m] = _eqns(jax.make_jaxpr(k.fn)(*args).jaxpr)
                 routes.add(k.route)
-            handles, cols = slots[0]
+            handles, cols = jax.tree.map(lambda a: a[0], lanes)
             cols = tuple((d, d != d if v is None else v) for d, v in cols)  # the single-region program takes every validity
             k = dag_kernel._build(dag, n_pad, agg_cap, full_scan=kw["full_scan"])
             single = _eqns(jax.make_jaxpr(k.fn)(
@@ -302,7 +303,7 @@ def test_mapped_program_is_no_larger_for_48_regions_than_for_8(served, monkeypat
             routes.add(k.route)
         assert routes == {{"q1": "eqmask" if gate is None else "dot", "q6": None}[shape]}, routes
         assert abs(sizes[48] - sizes[8]) < 0.1 * sizes[8], sizes
-        assert sizes[48] < 1.5 * single + 40, (sizes, single)  # the body once, the stacking, the packing: not a body a region
+        assert sizes[48] < 1.5 * single + 40, (sizes, single)  # the body once and the packing: not a body a region
 
 
 # -- a region that is not clean leaves ------------------------------------------

@@ -215,8 +215,8 @@ class ResolvedTask:
     — the program calls as ``_run_all`` takes them, kernels and device
     arguments; ``answered`` — the ``(region's place among kept, kernel)`` each
     of their results is, in order; ``keys`` — the device LRU's keys of the
-    arrays the calls hold. Immutable once published: any number of tasks read
-    one at once."""
+    arrays the calls hold, the lane stacks of its mapped calls among them.
+    Immutable once published: any number of tasks read one at once."""
 
     __slots__ = ("kept", "left", "epoch", "width", "regions", "calls", "answered", "keys", "__weakref__")
 

@@ -31,6 +31,7 @@ recompile with the next power-of-two cap and re-run (bounded doubling).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time as _time
 import weakref
@@ -112,8 +113,14 @@ def _n_blocks(n: int) -> int:
     return -(-n // _BLOCK)
 
 
+# the first element of a lane stack's key in the device LRU (``_lane_stack``)
+_STACKED = "stack"
+
+
 class _DeviceLRU:
-    """HBM-bounded LRU of device-resident column (data, valid) pairs.
+    """HBM-bounded LRU of device-resident column (data, valid) pairs, and of
+    the lane stacks of mapped calls (``_lane_stack``: ``(_STACKED, part,
+    members)``, ``members`` the keys of the arrays a stack copies).
 
     Ref: the coprocessor cache (copr/coprocessor_cache.go:32) crossed with
     TiFlash's delta-tree page cache — capacity-bounded, recency-evicted.
@@ -142,13 +149,17 @@ class _DeviceLRU:
             return hit[0]
 
     def put(self, key, pair, nbytes: int):
+        """Keep ``pair`` under ``key`` and return what is kept: the entry
+        already there if two tasks built the same one at once (the same
+        arrays either way), so both read one copy and the budget counts one."""
         evicted = []
         with self._mu:
             if self._touched:
                 self._settle()
-            old = self._entries.pop(key, None)
+            old = self._entries.get(key)
             if old is not None:
-                self.total -= old[1]
+                self._entries.move_to_end(key)
+                return old[0]
             self._entries[key] = (pair, nbytes)
             self.total += nbytes
             while self.total > self.budget and len(self._entries) > 1:
@@ -159,20 +170,22 @@ class _DeviceLRU:
                 self.total -= nb
                 evicted.append(k)
         _unresolve_evicted(evicted)
+        return pair
 
     def evict_superseded(self, ident, ver_epoch):
         """Drop stale epochs/versions of the same column — each write bumps
-        data_version and stale device arrays would leak HBM forever. Sibling
-        blocks of the *current* (version, epoch) stay resident."""
+        data_version and stale device arrays would leak HBM forever — and the
+        lane stacks that copy one (resident or not, it is never read again).
+        Sibling blocks of the *current* (version, epoch) stay resident."""
+        n = len(ident)
+
+        def stale(k):
+            return k[:n] == ident and k[n : n + 2] != ver_epoch
+
         with self._mu:
-            evicted = [
-                k
-                for k in self._entries
-                if k[: len(ident)] == ident and k[len(ident) : len(ident) + 2] != ver_epoch
-            ]
+            evicted = [k for k in self._entries if stale(k) or (k[0] == _STACKED and any(stale(m) for m in k[2]))]
             for k in evicted:
-                self.total -= self._entries[k][1]
-                del self._entries[k]
+                self.total -= self._entries.pop(k)[1]
         _unresolve_evicted(evicted)
 
     def touch(self, holder) -> None:
@@ -198,15 +211,17 @@ class _DeviceLRU:
 
 
 def _unresolve_evicted(keys: list) -> None:
-    """A resolved batch task holds its regions' device arrays beside the LRU,
-    which owns them: whatever the LRU lets go of takes the tasks that hold an
-    array of the same region with it, or they would pin its HBM behind the
-    budget's back. Outside the LRU's lock."""
+    """A resolved batch task holds its regions' device arrays and lane stacks
+    beside the LRU, which owns them: whatever the LRU lets go of takes the
+    tasks that hold an array of the same region (a stack: of any region it
+    copies) with it, or they would pin its HBM behind the budget's back.
+    Outside the LRU's lock."""
     if not keys:
         return
     gone: dict = {}
-    for k in keys:
-        gone.setdefault(k[0], set()).add((k[1], k[2]))
+    for key in keys:
+        for k in key[2] if key[0] == _STACKED else (key,):
+            gone.setdefault(k[0], set()).add((k[1], k[2]))
     for store, cache in caches():
         regions = gone.get(getattr(store, "nonce", None))
         if regions:
@@ -289,9 +304,39 @@ def _device_put_col(key, make_pair, n_pad: int, cacheable: bool = True):
         # epoch, shape-suffix) — unit is a block index, "s" (single-array), or
         # "d" (delta operand). Superseded-version eviction is per UNIT, so a
         # merge that carries clean blocks replaces only the dirty siblings.
-        _DEVICE_LRU.put(key, out, pd.nbytes + pv.nbytes)
+        out = _DEVICE_LRU.put(key, out, pd.nbytes + pv.nbytes)
         _DEVICE_LRU.evict_superseded(key[:5], key[5:7])
     return out
+
+
+@functools.cache
+def _stacker():
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(lambda *lane: jnp.stack(lane))
+
+
+def _lane_stack(part: str, members: tuple, arrays: list):
+    """One lane of a mapped call: ``arrays``, the slots' own in slot order,
+    stacked ``(m, n_pad)`` on the device by one small jitted stack, and kept in
+    the device LRU under ``members`` (the arrays' own LRU keys in slot order:
+    region, version, dictionary epoch and padded shape) and ``part`` (``d``
+    data, ``v`` validity). A member written since has a new key, so a stack of
+    its old arrays is never read again (``evict_superseded`` drops it); what
+    reads the same arrays in the same slots reads one stack, whatever the DAG:
+    a template's parameter sets, Q1 and Q6 over the same regions. Returns
+    ``(stack, its key, whether it was built here)``."""
+    key = (_STACKED, part, members)
+    hit = _DEVICE_LRU.get(key)
+    if hit is not None:
+        det = _ed.current_cop()
+        if det is not None:
+            det.dev_cache_hits += 1
+        _metrics.DEVICE_CACHE.inc(result="hit")
+        return hit, key, False
+    stack = _stacker()(*arrays)
+    return _DEVICE_LRU.put(key, stack, stack.nbytes), key, True
 
 
 def _narrowed(entry, column_id: int, data: np.ndarray) -> np.ndarray:
@@ -696,6 +741,7 @@ def _run_resolved(ph: _Phases, dag: dagpb.DAGRequest, scan, cache, key: tuple, t
         ph.to("inputs")
         cache.note_served(task)
         _DEVICE_LRU.touch(task)
+        _count_stacks(ph, kept=sum(1 for kernel, _, _ in task.calls if kernel.m > 1), built=0)
         if det is not None:
             det.dev_cache_hits += len(task.keys)
         _metrics.DEVICE_CACHE.inc(len(task.keys), result="hit")
@@ -784,8 +830,9 @@ def _grown_cap(agg_cap: int, ngroups: int, ceiling: int) -> int:
 
 
 def _single_device_inputs(store, scan, cache, entry, region, n_pad, keys: list | None = None):
-    """(handles_dev, cols_dev) for the single-kernel path, via the same LRU
-    identities as repeat queries; the identities asked for go on ``keys``."""
+    """(handles_dev, cols_dev, their LRU keys: the handles', then a lane's
+    each) for the single-kernel path, via the same LRU identities as repeat
+    queries; the identities asked for also go on ``keys``."""
     epoch = cache.epoch
     cacheable = entry.complete
     ver = entry.vtag_span(0, entry.n)
@@ -793,10 +840,11 @@ def _single_device_inputs(store, scan, cache, entry, region, n_pad, keys: list |
     handles_pair = _device_put_col(
         hkey, lambda: (entry.handles, np.ones(entry.n, bool)), n_pad, cacheable
     )
-    cols_dev = []
+    cols_dev, col_keys = [], []
     for c in scan.columns:
         if c.is_handle:
             cols_dev.append(handles_pair)
+            col_keys.append(hkey)
         else:
             ckey = (store.nonce, region.region_id, scan.table_id, c.column_id, "s", ver, epoch, n_pad)
 
@@ -805,11 +853,12 @@ def _single_device_inputs(store, scan, cache, entry, region, n_pad, keys: list |
                 return _narrowed(entry, cid, data), valid
 
             cols_dev.append(_device_put_col(ckey, mk, n_pad, cacheable))
+            col_keys.append(ckey)
             if keys is not None:
                 keys.append(ckey)
     if keys is not None:
         keys.append(hkey)
-    return handles_pair[0], cols_dev
+    return handles_pair[0], cols_dev, (hkey, tuple(col_keys))
 
 
 def _map_counts(k: int, n_pad: int) -> list[int]:
@@ -817,7 +866,7 @@ def _map_counts(k: int, n_pad: int) -> list[int]:
     of each call, padded up the ladder — ``[48]`` for 46, 47 or 48 regions,
     ``[64, 64, 64, 48]`` for 240 of 262,144 padded rows, ``[1]`` for one. The
     ladder is the multiples of ``_MAP_STEP`` up to ``_MAP_ROWS`` padded rows a
-    call (a mapped program stacks its regions in HBM while it runs): a region
+    call (the bound of a lane stack a mapped call reads, ``_lane_stack``): a region
     split or merge moves a group along it a step at most, so it rarely means a
     compile on the query path, and a padding slot costs a region's device
     time: at most 7 in a call. A remainder of one, and regions too large for
@@ -840,23 +889,27 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
     lane's dtype and whether it holds a NULL: what must be equal to stack them
     — are a GROUP, answered by one call of one MAPPED program
     (``dag_kernel.get_kernel``'s ``m``: the single-region kernel run once a
-    region along a leading axis) and one stacked result; ``_map_counts`` pads
-    the count up a short ladder, a padding slot being a resident region's
-    arrays again with ``nvalid`` 0 (no H2D, no new HBM), its result dropped
-    before the decode. A group of one region — every task that is not a batch,
-    a region read through its delta, an overflow's re-run, the odd region of
-    another size — calls today's single-region program: there is no second
-    path for it. Every call is dispatched before the ONE fetch; partials stay
+    region along a leading axis) over the group's lanes stacked and kept on
+    the device (``_call_args``, ``_lane_stack``), and one stacked result;
+    ``_map_counts`` pads the count up a short ladder, a padding slot being a
+    resident region's rows again with ``nvalid`` 0 (no H2D), its result
+    dropped before the decode. A group of one region — every task that is not
+    a batch, a region read through its delta, an overflow's re-run, the odd
+    region of another size — calls today's single-region program: there is no
+    second path for it. Every call is dispatched before the ONE fetch; partials stay
     one a region, and decode into one Chunk, region after region. ``keep``, a
     batch's ``colcache.ResolvedTask``, takes what was sent and what it holds: the
     calls of every round (an overflow's re-run after the call it overflowed in),
-    which result each answers, the device LRU's keys."""
+    which result each answers, the device LRU's keys (the lane stacks' too).
+    The ``inputs`` span says of the mapped calls how many read only stacks
+    kept from before (``stacks_kept``) and how many built one
+    (``stacks_built``)."""
     needs_agg = kernel_needs_agg(bound)
     ph.to("inputs")
     runs = []  # a region: [its kernel key (n_pad, full_scan, delta_cap, agg_cap, lanes), the arguments of its own]
     for entry, region, rarr, delta in parts:
         n_pad = bucket_size(max(entry.n, 1))
-        handles_dev, cols_dev = _single_device_inputs(store, scan, cache, entry, region, n_pad, None if keep is None else keep.keys)
+        handles_dev, cols_dev, lane_keys = _single_device_inputs(store, scan, cache, entry, region, n_pad, None if keep is None else keep.keys)
         dcap = 0
         dargs = ()
         if delta is not None:
@@ -868,7 +921,7 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
         # a lane with no NULL in it (the handle's never has one) is stacked without its validity
         lanes = tuple((d.dtype, c.is_handle or entry.all_valid(c.column_id)) for c, (d, _) in zip(scan.columns, cols_dev))
         key = (n_pad, _covers_all(rarr, entry, delta), dcap, agg_cap, lanes)
-        runs.append([key, (handles_dev, tuple(cols_dev), rarr, entry.n, dargs)])
+        runs.append([key, (handles_dev, tuple(cols_dev), rarr, entry.n, dargs, lane_keys)])
     results: list = [None] * len(runs)
     todo = list(range(len(runs)))
     while todo:
@@ -886,7 +939,16 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
                 sends.append((get_kernel(bound, n_pad, agg_cap, full_scan=fs, delta_cap=dcap, m=m), key, members[at : at + m]))
                 at += m
         ph.to("inputs")
-        calls = [(kernel, _call_args(kernel, [runs[i][1] for i in live], key), live) for kernel, key, live in sends]
+        calls, kept, built = [], 0, 0  # and the mapped calls that read only kept stacks / built one
+        for kernel, key, live in sends:
+            read: list = []
+            calls.append((kernel, _call_args(kernel, [runs[i][1] for i in live], key, read), live))
+            if kernel.m > 1:
+                fresh = any(b for _, b in read)
+                built, kept = built + fresh, kept + (not fresh)
+                if keep is not None:
+                    keep.keys += [k for k, _ in read]
+        _count_stacks(ph, kept=kept, built=built)
         answered = [(i, kernel) for kernel, _, live in calls for i in live]
         if keep is not None:
             keep.calls += calls
@@ -908,27 +970,39 @@ def _exec_single(ph, store, dag, bound, scan, cache, parts: list[_Part], warn=No
     return _decode(ph, results, dag, cache, scan, warn)
 
 
-def _call_args(kernel, regions: list, key: tuple) -> tuple:
+def _call_args(kernel, regions: list, key: tuple, read: list) -> tuple:
     """The arguments of one program call over ``regions`` (each ``(handles,
-    cols, rarr, n, delta args)``) of the kernel key ``key``: a region's own for
-    the single-region program; for a mapped one the regions' arrays slot by
-    slot — less those it need not read: the handles of a full scan, the
-    validity of a lane without a NULL — its slots past the last region filled
-    with the first region's arrays and ``nvalid`` 0. Ranges and counts are
-    device-resident, cached by value."""
+    cols, rarr, n, delta args, lane keys)``) of the kernel key ``key``: a
+    region's own for the single-region program; for a mapped one ``(lanes,
+    ranges, nvalid)``, the lanes as the single-region program takes them, each
+    array the regions' stacked ``(m, n_pad)`` in slot order (``_lane_stack``:
+    kept on the device, built once) — ``None`` where the program need not read
+    one: the handles of a full scan, the validity of a lane without a NULL —
+    its slots past the last region the first region's rows with ``nvalid`` 0.
+    Each stack read goes on ``read`` as ``(its LRU key, whether it was built
+    here)``. Ranges and counts are device-resident, cached by value."""
     import jax.numpy as jnp
 
     if kernel.m == 1:
-        ((handles, cols, rarr, n, dargs),) = regions
+        ((handles, cols, rarr, n, dargs, _),) = regions
         return (handles, cols, _device_ranges(rarr), _device_nvalid(n), *dargs)
     _, full_scan, _, _, lanes = key
     slots = regions + regions[:1] * (kernel.m - len(regions))
     counts = tuple(r[3] for r in regions) + (0,) * (kernel.m - len(regions))
+
+    def stack(part, members, arrays):
+        got, skey, built = _lane_stack(part, tuple(members), list(arrays))
+        read.append((skey, built))
+        return got
+
+    hkeys = [r[5][0] for r in slots]
+    cols = []
+    for c, (_, no_null) in enumerate(lanes):
+        ckeys = [r[5][1][c] for r in slots]
+        d = stack("d", ckeys, (r[1][c][0] for r in slots))
+        cols.append((d, None if no_null else stack("v", ckeys, (r[1][c][1] for r in slots))))
     return (
-        tuple(
-            (None if full_scan else handles, tuple((d, None if no_null else v) for (d, v), (_, no_null) in zip(cols, lanes)))
-            for handles, cols, *_ in slots
-        ),
+        (None if full_scan else stack("d", hkeys, (r[0] for r in slots)), tuple(cols)),
         _device_ranges(np.stack([r[2] for r in slots])),
         _misc_cached(_NVALID_DEV, ("m", counts), lambda: jnp.asarray(np.array(counts, dtype=np.int64))),
     )
@@ -981,6 +1055,19 @@ def _agg_routes(calls: list) -> dict:
         if kernel.route is not None:
             out[kernel.route] = out.get(kernel.route, 0) + (1 if live is None else len(live))
     return out
+
+
+def _count_stacks(ph, kept: int, built: int) -> None:
+    """Mapped calls of a task by where their lanes came from: ``kept``, stacks
+    only kept from before; ``built``, at least one stack built for this task.
+    On the ``inputs`` span and the counter; a task without a mapped call says
+    nothing."""
+    if kept or built:
+        ph.note(stacks_kept=kept, stacks_built=built)
+    if kept:
+        _metrics.COP_LANE_STACKS.inc(kept, how="kept")
+    if built:
+        _metrics.COP_LANE_STACKS.inc(built, how="built")
 
 
 def _count_programs(calls: list, routes: dict) -> None:

@@ -112,7 +112,7 @@ class _DeviceWarnSink:
 
 @dataclass
 class CompiledKernel:
-    fn: Callable  # (handles, cols, ranges, nvalid) -> packed buffer(s); mapped (m > 1): (slots, ranges, nvalid)
+    fn: Callable  # (handles, cols, ranges, nvalid) -> packed buffer(s); mapped (m > 1): (lanes, ranges, nvalid)
     kind: str  # "rows" | "agg"
     out_n: int  # static output row capacity
     agg_cap: int
@@ -241,21 +241,21 @@ def get_kernel(
 
     ``m``: above 1 the MAPPED program, one call for ``m`` regions of one
     padded shape (the batch cop task, ``tpu_engine._exec_single``):
-    ``fn(slots, ranges, nvalid)``. ``slots`` is what ``m`` single-region calls
-    take, region by region — ``((handles, ((data, valid), ...)), ...)``, the
-    regions' own device-LRU arrays: nothing is kept stacked in HBM — with
-    ``None`` where the program need not read an array: the handles of a full
-    scan, the validity of a lane that holds no NULL in any of the regions (the
-    caller's proof; the program takes such a lane as valid throughout, the
-    rows past ``nvalid`` being masked as ever). Beside them ``(m, MAX_RANGES,
-    2)`` ranges and ``(m,)`` ``nvalid``. The program stacks each lane ``(m,
-    n_pad)`` (a temporary while it runs) and maps the single-region body over
-    the leading region axis (``jax.vmap``: the body is traced once, so what
-    grows with ``m`` is the stack alone: one copy a lane and slot). Out come
-    the packed buffers stacked ``(m, ...)``: one partial result a region, the
-    rows ``m`` calls of the ``m=1`` program give. A slot with ``nvalid`` 0
-    scans nothing (padding up the engine's ladder of counts). No delta
-    operand, no blocks: a region that needs either is a task of its own."""
+    ``fn(lanes, ranges, nvalid)``. ``lanes`` is ``(handles, ((data, valid),
+    ...))`` as the single-region program takes them, each array stacked ``(m,
+    n_pad)``, one row a region — the stacks the device LRU keeps beside the
+    regions' own arrays (``tpu_engine._lane_stack``) — with ``None`` where the
+    program need not read an array: the handles of a full scan, the validity
+    of a lane that holds no NULL in any of the regions (the caller's proof;
+    the program takes such a lane as valid throughout, the rows past
+    ``nvalid`` being masked as ever). Beside them ``(m, MAX_RANGES, 2)``
+    ranges and ``(m,)`` ``nvalid``. The program maps the single-region body
+    over the leading region axis of its arguments as given (``jax.vmap``: the
+    body is traced once, and nothing is copied into a stack while it runs).
+    Out come the packed buffers stacked ``(m, ...)``: one partial result a
+    region, the rows ``m`` calls of the ``m=1`` program give. A slot with
+    ``nvalid`` 0 scans nothing (padding up the engine's ladder of counts). No
+    delta operand, no blocks: a region that needs either is a task of its own."""
     key = (dag.fingerprint(), n_pad, agg_cap, nb, full_scan, delta_cap, m)
     with _CACHE_MU:
         k = _COMPILE_CACHE.get(key)
@@ -1522,17 +1522,10 @@ def _build(
             with _Stages() as stage:
                 return _kernel_body(handles, cols, ranges, nvalid, None, stage)
 
-        def kernel(slots, ranges, nvalid):
-            # the m regions' arrays → one (m, n_pad) array a lane (None stays
-            # None): ONE concatenate whatever m is, no Python loop around the body
-            hs, lanes = jax.tree.map(lambda *lane: jax.lax.concatenate(lane, 0).reshape(m, n_pad), *slots)
-            # the stack is a copy either way; behind a barrier the compiler does
-            # not try the m-operand concatenates inside every consumer's fusion
-            # (compile 11.4 s → 3.9 s for Q1 at 48 x 262,144 rows, a v5e described in the sandbox)
-            hs, lanes = jax.lax.optimization_barrier((hs, lanes))
+        def kernel(lanes, ranges, nvalid):
             # vmap, not lax.map: measured on the v5e at 48 x 262,144 rows, Q1's
             # 13.8 ms against 24.8 (a loop of 48 runs of the body) and 13.7 as 46 calls
-            return jax.vmap(one_region)((hs, lanes, ranges, nvalid))
+            return jax.vmap(one_region)((*lanes, ranges, nvalid))
     else:
         def kernel(handles, cols, ranges, nvalid):
             with _Stages() as stage:

@@ -40,6 +40,9 @@ CACHE_HELPERS = {
     "tidb_tpu/ops/dag_kernel.py": {"_build"},  # keyed by _COMPILE_CACHE in get_kernel
     "tidb_tpu/ops/window_kernel.py": {"_build"},  # keyed by _CACHE in get_window_fn
     "tidb_tpu/parallel/mpp.py": {"build_dist_pipeline"},  # keyed by _MPP_FN_CACHE
+    # ONE jit (functools.cache); jax keys its compiles by the lanes it stacks:
+    # a count on _map_counts' ladder, the padded rows, the dtype
+    "tidb_tpu/copr/tpu_engine.py": {"_stacker"},
 }
 
 _JIT_NAMES = {"jax.jit", "jit", "shard_map", "jax.shard_map"}
@@ -99,8 +102,8 @@ def _func_stack(tree: ast.Module):
 In ops/, parallel/, and copr/tpu_engine.py every jax.jit / shard_map
 call site must sit inside one of the recognized program-cache builders
 (dag_kernel._build via get_kernel's _COMPILE_CACHE, window_kernel._build
-via get_window_fn, and mpp.build_dist_pipeline via gather's
-_MPP_FN_CACHE). A jit call anywhere else compiles per call site invocation
+via get_window_fn, mpp.build_dist_pipeline via gather's _MPP_FN_CACHE, and
+tpu_engine._stacker, the one jitted lane stack of mapped calls). A jit call anywhere else compiles per call site invocation
 — the PR 10 compile-bomb class, where one uncached fragment shape walled
 tier-1 for 27+ minutes and every same-shape query re-paid a full XLA mesh
 compile. Fix: route the program through an existing cached builder, or

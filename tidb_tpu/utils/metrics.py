@@ -246,6 +246,11 @@ COP_AGG_ROUTE = REGISTRY.counter(
     "Regions cop program calls reduced by group-by route: dot = the int8 MXU dot, eqmask = the equality-mask reduce, sort = the lex sort (padding slots and scalar aggregates not counted)",
     ("route",),
 )
+COP_LANE_STACKS = REGISTRY.counter(
+    "tidb_tpu_cop_lane_stacks_total",
+    "Mapped cop program calls by where their lanes came from: kept = every (m, rows) lane stack kept on the device from before, built = at least one stack built for the call",
+    ("how",),
+)
 COP_TASK_RESOLVED = REGISTRY.counter(
     "tidb_tpu_cop_task_resolved_total",
     "Batch cop tasks by how they met their resolved task: hit = the kept program calls re-sent, miss = none kept, stale = one kept that no longer held",
